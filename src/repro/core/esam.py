@@ -53,6 +53,7 @@ class ESAM:
         # Set by finalize():
         self._ids_np: Optional[List[np.ndarray]] = None
         self._topo: Optional[np.ndarray] = None
+        self._ids_frozen: List[np.ndarray] = []   # last finalize's arrays
 
     # ------------------------------------------------------------------ #
     # construction
@@ -198,8 +199,22 @@ class ESAM:
 
     def finalize(self) -> None:
         """Freeze ID lists to NumPy and compute a topological order of the
-        transition DAG (needed by the reverse-topo index build)."""
-        self._ids_np = [np.asarray(x, dtype=np.int64) for x in self.ids]
+        transition DAG (needed by the reverse-topo index build).  ID lists
+        only ever grow by appends, so each state's last array is reused
+        and only the appended tail is converted: an online insert costs
+        the ids it added, not all Σ|V_state| ids again."""
+        prev = self._ids_frozen
+
+        def frozen(u: int, ids: List[int]) -> np.ndarray:
+            if u >= len(prev):
+                return np.asarray(ids, dtype=np.int64)
+            a = prev[u]
+            if len(a) == len(ids):
+                return a
+            return np.concatenate([a, np.asarray(ids[len(a):], np.int64)])
+
+        self._ids_np = [frozen(u, x) for u, x in enumerate(self.ids)]
+        self._ids_frozen = self._ids_np
         self._topo = self._topological_order()
 
     def _topological_order(self) -> np.ndarray:
@@ -292,6 +307,7 @@ class ESAM:
         self.total_symbols = int(arrays["total_symbols"][0])
         self._ids_np = None
         self._topo = None
+        self._ids_frozen = []
         return self
 
 

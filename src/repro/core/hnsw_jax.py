@@ -48,7 +48,7 @@ def hnsw_search(vectors: jax.Array, ids: jax.Array, level0: jax.Array,
         if metric == "l2":
             diff = v - q[None, :]
             return jnp.sum(diff * diff, axis=-1)
-        return -(v @ q)
+        return -jnp.dot(v, q, precision=jax.lax.Precision.HIGHEST)
 
     # --- initial candidate list -------------------------------------------
     cand_s = jnp.full((ef,), -1, jnp.int32).at[0].set(entry.astype(jnp.int32))
@@ -132,7 +132,7 @@ def hnsw_search_filtered(vectors: jax.Array, ids: jax.Array,
         if metric == "l2":
             diff = v - q[None, :]
             return jnp.sum(diff * diff, axis=-1)
-        return -(v @ q)
+        return -jnp.dot(v, q, precision=jax.lax.Precision.HIGHEST)
 
     def allowed_of(slots: jax.Array) -> jax.Array:
         return allowed[ids[jnp.clip(slots, 0, n - 1)]]

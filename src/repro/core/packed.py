@@ -1417,14 +1417,16 @@ class PackedRuntime:
         numpy) so the over-fetch loop re-ranks without recomputing or
         shipping the whole matrix."""
         if self.backend == "jax":
+            import jax
             import jax.numpy as jnp
             x = jnp.asarray(qmat)
             y = self._device_rows(np.asarray(cand))
+            xy = jnp.matmul(x, y.T, precision=jax.lax.Precision.HIGHEST)
             if self.metric == "l2":
                 d = (jnp.sum(x * x, 1, keepdims=True) + jnp.sum(y * y, 1)
-                     - 2.0 * x @ y.T)
+                     - 2.0 * xy)
                 return jnp.maximum(d, 0.0)
-            return -(x @ y.T)
+            return -xy
         from ..kernels import ops
         x = np.asarray(qmat, dtype=np.float32)
         y = np.asarray(self.vectors[cand], dtype=np.float32)

@@ -41,7 +41,6 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 f32 = jnp.float32
@@ -92,11 +91,11 @@ def sharded_topk(mesh: Mesh, queries: jax.Array, base: jax.Array, k: int,
         return ops.merge_topk_allgather(vals, gidx, axis, k)
 
     mask_arg = (valid_mask[:, None] if valid_mask is not None else None)
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(), P(axis, None),
-                             (P(axis, None) if valid_mask is not None
-                              else None)),
-                   out_specs=(P(), P()), check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(), P(axis, None),
+                                 (P(axis, None) if valid_mask is not None
+                                  else None)),
+                       out_specs=(P(), P()), check_vma=False)
     return fn(queries, base, mask_arg)
 
 
@@ -422,12 +421,12 @@ def _sweep_fn(mesh: Mesh, axis: str, n_desc: int, k: int, metric: str,
             shard_id * local_n + cand[jnp.clip(idx, 0, nc - 1)], -1)
         return ops.merge_topk_allgather(vals, gid, axis, k)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(), P(axis, None), P(axis, None), P(),
                   P(axis, None), P(), P(axis, None), P(axis),
                   P(axis, None)),
-        out_specs=(P(), P()), check_rep=False)
+        out_specs=(P(), P()), check_vma=False)
     return jax.jit(fn)
 
 
@@ -476,6 +475,7 @@ def _sweep_fn_sq8(mesh: Mesh, axis: str, n_desc: int, k: int, kq: int,
         rows = vecs[cand[idxc]]                       # (Q, kqe, d) fp32
         qf = q.astype(f32)
         xy = jnp.einsum("qd,qkd->qk", qf, rows,
+                        precision=jax.lax.Precision.HIGHEST,
                         preferred_element_type=f32)
         c2 = jnp.sum(rows * rows, axis=-1)
         x2r = jnp.sum(qf * qf, axis=-1, keepdims=True)
@@ -516,13 +516,13 @@ def _sweep_fn_sq8(mesh: Mesh, axis: str, n_desc: int, k: int, kq: int,
         bad = jax.lax.psum(jnp.sum((~cert).astype(jnp.int32)), axis)
         return mv, mi, bad
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(), P(axis, None), P(axis, None), P(),
                   P(axis, None), P(), P(axis, None), P(axis, None),
                   P(axis, None), P(axis, None), P(axis, None), P(axis),
                   P(axis, None)),
-        out_specs=(P(), P(), P()), check_rep=False)
+        out_specs=(P(), P(), P()), check_vma=False)
     return jax.jit(fn)
 
 

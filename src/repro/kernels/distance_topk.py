@@ -34,6 +34,67 @@ from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_Q = 128
 BLOCK_N = 128
+# The running top-k carry and the kernels' output blocks are one full
+# lane row wide, whatever k is: k ≤ LANES, columns past k stay (+inf, -1).
+LANES = 128
+
+
+def fold_topk(val_scr, idx_scr, dist, base, k: int) -> None:
+    """Fold a (bq, bn) distance tile into the running top-k held in the
+    (bq, LANES) scratch refs ``val_scr`` / ``idx_scr`` (ascending, with
+    (+inf, -1) past k).  Tile column c carries candidate index
+    ``base + c``.
+
+    k rounds of: the row minimum over carry and tile, the lowest position
+    holding it (every carry position comes before every tile position,
+    as in ``concat([carry, tile])``), a lane-masked write into column i
+    of the new carry, and masking that winner to +inf.  Only lane
+    reductions, compares and selects, which Mosaic lowers; ``lax.top_k``
+    and ``take_along_axis`` inside a kernel body it does not.  Ties go to
+    the lower position exactly as ``lax.top_k`` over the concatenation
+    breaks them, and a slot filled with +inf gets index -1 — so the
+    result is the one the former ``top_k`` fold returned."""
+    cv = val_scr[...]
+    ci = idx_scr[...]
+    c_pos = jax.lax.broadcasted_iota(jnp.int32, cv.shape, 1)
+    t_pos = jax.lax.broadcasted_iota(jnp.int32, dist.shape, 1)
+    none = cv.shape[1] + dist.shape[1]          # above every position
+
+    def one(i, carry):
+        cv, tv, out_v, out_i = carry
+        m = jnp.minimum(jnp.min(cv, axis=1, keepdims=True),
+                        jnp.min(tv, axis=1, keepdims=True))     # (bq, 1)
+        pc = jnp.min(jnp.where(cv == m, c_pos, none), axis=1, keepdims=True)
+        pt = jnp.min(jnp.where(tv == m, t_pos, none), axis=1, keepdims=True)
+        from_c = pc < none
+        ic = jnp.sum(jnp.where(c_pos == pc, ci, 0), axis=1, keepdims=True)
+        win = jnp.where(from_c, ic, base + pt)
+        win = jnp.where(m < jnp.inf, win, -1)
+        col = c_pos == i
+        out_v = jnp.where(col, m, out_v)
+        out_i = jnp.where(col, win, out_i)
+        cv = jnp.where(from_c & (c_pos == pc), jnp.inf, cv)
+        tv = jnp.where(~from_c & (t_pos == pt), jnp.inf, tv)
+        return cv, tv, out_v, out_i
+
+    _, _, out_v, out_i = jax.lax.fori_loop(
+        0, k, one, (cv, dist, jnp.full_like(cv, jnp.inf),
+                    jnp.full_like(ci, -1)))
+    val_scr[...] = out_v
+    idx_scr[...] = out_i
+
+
+def topk_outputs(q: int, block_q: int) -> dict:
+    """``pallas_call`` out_specs, out_shape and scratch_shapes shared by
+    the streaming top-k kernels: a (block_q, LANES) running carry in VMEM
+    and (q, LANES) value/index outputs, which the caller cuts to k."""
+    spec = pl.BlockSpec((block_q, LANES), lambda i, j: (i, 0))
+    return dict(
+        out_specs=[spec, spec],
+        out_shape=[jax.ShapeDtypeStruct((q, LANES), jnp.float32),
+                   jax.ShapeDtypeStruct((q, LANES), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((block_q, LANES), jnp.float32),
+                        pltpu.VMEM((block_q, LANES), jnp.int32)])
 
 
 def _dist_tile(x, y, metric: str, accum: str):
@@ -41,15 +102,20 @@ def _dist_tile(x, y, metric: str, accum: str):
     rounds the operands to bf16 before the MXU contraction (half the
     VMEM, double the MXU rate) but keeps the accumulator and the norm
     epilogue in f32 — the bf16-accumulation contract DESIGN.md §6
-    specifies and the tolerance tests bound."""
+    specifies and the tolerance tests bound.  ``accum="f32"`` contracts
+    at full fp32 precision (``HIGHEST``), not the TPU's one-pass bf16
+    default, so exact search stays exact on the chip."""
     if accum == "bf16":
         x = x.astype(jnp.bfloat16)
         y = y.astype(jnp.bfloat16)
+        precision = None
     else:
         x = x.astype(jnp.float32)
         y = y.astype(jnp.float32)
+        precision = jax.lax.Precision.HIGHEST
     xy = jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        x, y, (((1,), (1,)), ((), ())), precision=precision,
+        preferred_element_type=jnp.float32)
     if metric == "l2":
         xf = x.astype(jnp.float32)
         yf = y.astype(jnp.float32)
@@ -78,14 +144,7 @@ def _topk_kernel(x_ref, y_ref, val_out_ref, idx_out_ref,
     if valid_n < n_blocks * block_n:
         dist = jnp.where(col_idx < valid_n, dist, jnp.inf)
 
-    # --- fold tile into running top-k --------------------------------------
-    # Concatenate (bq, k) carry with (bq, bn) tile, keep k smallest.  top_k
-    # selects the largest, so negate.
-    all_vals = jnp.concatenate([val_scr[...], dist], axis=1)
-    all_idx = jnp.concatenate([idx_scr[...], col_idx], axis=1)
-    neg_top, pos = jax.lax.top_k(-all_vals, k)
-    val_scr[...] = -neg_top
-    idx_scr[...] = jnp.take_along_axis(all_idx, pos, axis=1)
+    fold_topk(val_scr, idx_scr, dist, base, k)
 
     # --- emit on the last tile of the row ----------------------------------
     @pl.when(j == n_blocks - 1)
@@ -117,12 +176,7 @@ def _topk_seg_kernel(x_ref, y_ref, qseg_ref, cseg_ref, val_out_ref,
         match = match & (col_idx < valid_n)
     dist = jnp.where(match, dist, jnp.inf)
 
-    all_vals = jnp.concatenate([val_scr[...], dist], axis=1)
-    all_idx = jnp.concatenate(
-        [idx_scr[...], jnp.where(match, col_idx, -1)], axis=1)
-    neg_top, pos = jax.lax.top_k(-all_vals, k)
-    val_scr[...] = -neg_top
-    idx_scr[...] = jnp.take_along_axis(all_idx, pos, axis=1)
+    fold_topk(val_scr, idx_scr, dist, base, k)
 
     @pl.when(j == n_blocks - 1)
     def _emit():
@@ -138,7 +192,7 @@ def _seg_pallas_call(x, y, qseg, cseg, k, *, metric, block_q, block_n,
     q, d = x.shape
     n, d2 = y.shape
     assert d == d2 and q % block_q == 0 and n % block_n == 0
-    assert k <= block_n, (k, block_n)
+    assert k <= LANES, k
     assert qseg.shape == (q, 1) and cseg.shape == (1, n)
     if valid_n is None:
         valid_n = n
@@ -147,7 +201,7 @@ def _seg_pallas_call(x, y, qseg, cseg, k, *, metric, block_q, block_n,
     kernel = functools.partial(_topk_seg_kernel, metric=metric, k=k,
                                block_n=block_n, n_blocks=n_blocks,
                                valid_n=valid_n, accum=accum)
-    return pl.pallas_call(
+    vals, idx = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -156,20 +210,10 @@ def _seg_pallas_call(x, y, qseg, cseg, k, *, metric, block_q, block_n,
             pl.BlockSpec((block_q, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((1, block_n), lambda i, j: (0, j)),
         ],
-        out_specs=[
-            pl.BlockSpec((block_q, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_q, k), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((q, k), jnp.float32),
-            jax.ShapeDtypeStruct((q, k), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, k), jnp.float32),
-            pltpu.VMEM((block_q, k), jnp.int32),
-        ],
         interpret=interpret,
+        **topk_outputs(q, block_q),
     )(x, y, qseg, cseg)
+    return vals[:, :k], idx[:, :k]
 
 
 @functools.partial(jax.jit, static_argnames=("k", "metric", "block_q",
@@ -344,6 +388,7 @@ def segmented_dense_topk(x: jax.Array, y: jax.Array, qseg: jax.Array,
     yf = y.astype(jnp.float32)
     xy = jax.lax.dot_general(
         xf, yf, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
     if metric == "l2":
         x2 = jnp.sum(xf * xf, axis=-1, keepdims=True)
@@ -376,14 +421,14 @@ def distance_topk(x: jax.Array, y: jax.Array, k: int, *, metric: str = "l2",
     """Exact top-k over the base set.  x: (Q, d), y: (N, d).
 
     Returns (values, indices) of shape (Q, k); distances ascending.
-    Q % block_q == 0, N % block_n == 0, k <= block_n (ops.py pads).
+    Q % block_q == 0, N % block_n == 0, k <= LANES (ops.py pads).
     ``valid_n``: logical base count; rows >= valid_n are padding and are
     masked to +inf in-kernel.
     """
     q, d = x.shape
     n, d2 = y.shape
     assert d == d2 and q % block_q == 0 and n % block_n == 0
-    assert k <= block_n, (k, block_n)
+    assert k <= LANES, k
     if valid_n is None:
         valid_n = n
     n_blocks = n // block_n
@@ -391,24 +436,14 @@ def distance_topk(x: jax.Array, y: jax.Array, k: int, *, metric: str = "l2",
     kernel = functools.partial(_topk_kernel, metric=metric, k=k,
                                block_n=block_n, n_blocks=n_blocks,
                                valid_n=valid_n, accum=accum)
-    return pl.pallas_call(
+    vals, idx = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_q, d), lambda i, j: (i, 0)),
             pl.BlockSpec((block_n, d), lambda i, j: (j, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((block_q, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_q, k), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((q, k), jnp.float32),
-            jax.ShapeDtypeStruct((q, k), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, k), jnp.float32),   # running top-k values
-            pltpu.VMEM((block_q, k), jnp.int32),     # running top-k indices
-        ],
         interpret=interpret,
+        **topk_outputs(q, block_q),
     )(x, y)
+    return vals[:, :k], idx[:, :k]

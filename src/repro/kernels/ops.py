@@ -15,8 +15,8 @@ Responsibilities:
   * account every launch and (re)trace in module-level counters
     (``launch_stats``) that ``VectorMaton.maintenance_stats`` and the
     benchmark gate read;
-  * select interpret mode automatically off-TPU (this container is CPU-only;
-    interpret=True executes the kernel body in Python for validation);
+  * select interpret mode automatically off-TPU (interpret=True executes
+    the kernel body in Python for validation; on a TPU it compiles);
   * expose a NumPy fast path used by the CPU benchmark harness so the paper's
     QPS experiments aren't bottlenecked by interpret-mode overhead — the
     Pallas path is the TPU deployment path and is what tests validate.
@@ -83,6 +83,16 @@ def launch_stats() -> Dict[str, int]:
     return out
 
 
+def launch_keys() -> Dict[str, list]:
+    """Distinct shape-bucket keys seen since the last reset, per launch
+    kind — the scan kinds end in the top-k core (``"pallas"``/``"xla"``)
+    that ran."""
+    out: Dict[str, list] = {}
+    for kind, key in sorted(_launch_keys, key=repr):
+        out.setdefault(kind, []).append(key)
+    return out
+
+
 def reset_launch_stats() -> None:
     _launch_counters.clear()
     _launch_keys.clear()
@@ -100,10 +110,7 @@ def jit_cache_sizes() -> Dict[str, int]:
             ("hnsw_search_fused_filtered",
              hnsw_jax.hnsw_search_fused_filtered),
     ]:
-        try:
-            out[name] = int(fn._cache_size())
-        except AttributeError:  # pragma: no cover - older jax
-            out[name] = -1
+        out[name] = int(fn._cache_size())
     return out
 
 
@@ -150,7 +157,7 @@ def topk(x: jax.Array, y: jax.Array, k: int, *, metric: str = "l2",
     if interpret is None:
         interpret = default_interpret()
     q, n = x.shape[0], y.shape[0]
-    kp = _round_up(k, 8)  # scratch lane alignment
+    kp = _round_up(k, 8)  # nearby k share one compiled kernel
     if kp > _LANE:
         raise ValueError(f"k={k} exceeds kernel max {_LANE}")
     bq, bn = select_tiles(q, n, x.shape[1], k=kp,
@@ -317,6 +324,7 @@ def _topk_dense_xla(x, y, k: int, metric: str):
     xf = x.astype(jnp.float32)
     yf = y.astype(jnp.float32)
     xy = jax.lax.dot_general(xf, yf, (((1,), (1,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
                              preferred_element_type=jnp.float32)
     if metric == "l2":
         x2 = jnp.sum(xf * xf, axis=-1, keepdims=True)
@@ -495,5 +503,6 @@ __all__ = ["pairwise_sqdist", "topk", "topk_segmented",
            "segmented_dense_topk", "topk_segmented_numpy", "topk_numpy",
            "merge_topk_device", "merge_topk_allgather", "bucket",
            "default_interpret", "default_impl", "select_tiles",
-           "launch_stats", "reset_launch_stats", "record_launch",
+           "launch_stats", "launch_keys", "reset_launch_stats",
+           "record_launch",
            "jit_cache_sizes", "ref"]

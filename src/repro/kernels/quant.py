@@ -37,8 +37,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
+from .distance_topk import (LANES, expand_descriptors, fold_topk,
+                            topk_outputs)
 from .tuning import (SQ8_DIM_CAP, default_impl, default_interpret,
                      select_tiles)
 
@@ -107,11 +108,7 @@ def _qtopk_kernel(xq_ref, sx_ref, x2_ref, yq_ref, sy_ref, y2_ref,
     if valid_n < n_blocks * block_n:
         dist = jnp.where(col < valid_n, dist, jnp.inf)
 
-    all_vals = jnp.concatenate([val_scr[...], dist], axis=1)
-    all_idx = jnp.concatenate([idx_scr[...], col], axis=1)
-    neg_top, pos = jax.lax.top_k(-all_vals, k)
-    val_scr[...] = -neg_top
-    idx_scr[...] = jnp.take_along_axis(all_idx, pos, axis=1)
+    fold_topk(val_scr, idx_scr, dist, base, k)
 
     @pl.when(j == n_blocks - 1)
     def _emit():
@@ -126,13 +123,13 @@ def quantized_topk(xq, sx, x2, yq, sy, y2, k: int, *,
                    interpret: bool = False, valid_n: int | None = None):
     q, d = xq.shape
     n = yq.shape[0]
-    assert q % block_q == 0 and n % block_n == 0 and k <= block_n
+    assert q % block_q == 0 and n % block_n == 0 and k <= LANES
     if valid_n is None:
         valid_n = n
     n_blocks = n // block_n
     kernel = functools.partial(_qtopk_kernel, k=k, block_n=block_n,
                                n_blocks=n_blocks, valid_n=valid_n)
-    return pl.pallas_call(
+    vals, idx = pl.pallas_call(
         kernel,
         grid=(q // block_q, n_blocks),
         in_specs=[
@@ -143,20 +140,10 @@ def quantized_topk(xq, sx, x2, yq, sy, y2, k: int, *,
             pl.BlockSpec((block_n, 1), lambda i, j: (j, 0)),
             pl.BlockSpec((block_n, 1), lambda i, j: (j, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((block_q, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_q, k), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((q, k), f32),
-            jax.ShapeDtypeStruct((q, k), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, k), f32),
-            pltpu.VMEM((block_q, k), jnp.int32),
-        ],
         interpret=interpret,
+        **topk_outputs(q, block_q),
     )(xq, sx, x2, yq, sy, y2)
+    return vals[:, :k], idx[:, :k]
 
 
 def _qtopk_seg_kernel(xq_ref, sx_ref, x2_ref, yq_ref, sy_ref, y2_ref,
@@ -189,12 +176,7 @@ def _qtopk_seg_kernel(xq_ref, sx_ref, x2_ref, yq_ref, sy_ref, y2_ref,
         match = match & (col < valid_n)
     dist = jnp.where(match, dist, jnp.inf)
 
-    all_vals = jnp.concatenate([val_scr[...], dist], axis=1)
-    all_idx = jnp.concatenate(
-        [idx_scr[...], jnp.where(match, col, -1)], axis=1)
-    neg_top, pos = jax.lax.top_k(-all_vals, k)
-    val_scr[...] = -neg_top
-    idx_scr[...] = jnp.take_along_axis(all_idx, pos, axis=1)
+    fold_topk(val_scr, idx_scr, dist, base, k)
 
     @pl.when(j == n_blocks - 1)
     def _emit():
@@ -211,13 +193,13 @@ def _quantized_topk_segmented(xq, sx, x2, yq, sy, y2, qseg, cseg, k: int, *,
     qseg: (Q, 1) owner per query row, cseg: (1, N) owner per candidate."""
     q, d = xq.shape
     n = yq.shape[0]
-    assert q % block_q == 0 and n % block_n == 0 and k <= block_n
+    assert q % block_q == 0 and n % block_n == 0 and k <= LANES
     if valid_n is None:
         valid_n = n
     n_blocks = n // block_n
     kernel = functools.partial(_qtopk_seg_kernel, k=k, block_n=block_n,
                                n_blocks=n_blocks, valid_n=valid_n)
-    return pl.pallas_call(
+    vals, idx = pl.pallas_call(
         kernel,
         grid=(q // block_q, n_blocks),
         in_specs=[
@@ -230,20 +212,10 @@ def _quantized_topk_segmented(xq, sx, x2, yq, sy, y2, qseg, cseg, k: int, *,
             pl.BlockSpec((block_q, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((1, block_n), lambda i, j: (0, j)),
         ],
-        out_specs=[
-            pl.BlockSpec((block_q, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_q, k), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((q, k), f32),
-            jax.ShapeDtypeStruct((q, k), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, k), f32),
-            pltpu.VMEM((block_q, k), jnp.int32),
-        ],
         interpret=interpret,
+        **topk_outputs(q, block_q),
     )(xq, sx, x2, yq, sy, y2, qseg, cseg)
+    return vals[:, :k], idx[:, :k]
 
 
 def _sq8_dense_segmented(xq, sx, x2, yq, sy, y2, qseg_vec, cseg, k: int):
@@ -293,8 +265,6 @@ def _sq8_topk_descriptors(vectors, vq, vsc, vsq, vl1, base_ids, deleted, x,
     global ids, and a per-query bool that is True iff the result provably
     equals the fp32 scan's (see module docstring); the executor escalates
     batches with any False row."""
-    from .distance_topk import expand_descriptors
-
     # --- assemble the flat candidate layout against the int8 table -----
     if n_desc:
         dcand, down = expand_descriptors(base_ids, starts, lens, owners,
@@ -359,6 +329,7 @@ def _sq8_topk_descriptors(vectors, vq, vsc, vsq, vl1, base_ids, deleted, x,
     # same GEMM-form distance as the fp32 kernels, so certified results
     # are numerically interchangeable with the fp32 scan's
     xy = jnp.einsum("qd,qkd->qk", xf, candf,
+                    precision=jax.lax.Precision.HIGHEST,
                     preferred_element_type=f32)
     c2 = jnp.sum(candf * candf, axis=-1)
     x2r = jnp.sum(xf * xf, axis=-1, keepdims=True)

@@ -14,7 +14,7 @@ the query axis, while the per-step working set
     block_q·d·itemsize  (query tile)
   + block_n·d·itemsize  (candidate tile)
   + block_q·block_n·4   (distance tile, f32)
-  + block_q·(block_n + 2k)·8  (top-k fold concat: values + indices)
+  + block_q·(block_n + 2k)·8  (top-k fold: tile copy, positions, carry)
 
 fits half the ~16 MiB per-core VMEM — the other half is headroom for
 the pipeline's double buffering.  Growth never exceeds what the logical
@@ -25,7 +25,9 @@ regions), never violates divisibility of the padded extent.
 Interpret mode (``default_interpret``): Pallas compiles only on TPU; on
 CPU the kernels run in interpret mode as the validation path, and the
 XLA-compiled jnp twins (``ops.topk_xla`` etc.) are the throughput path.
-``REPRO_INTERPRET=1|0`` overrides the autodetect either way.
+Off the TPU ``REPRO_INTERPRET=1|0`` overrides the autodetect either way;
+on a TPU the kernels always compile, and asking for interpret mode there
+is an error.
 """
 
 from __future__ import annotations
@@ -49,17 +51,23 @@ _FALSE = ("0", "false", "no", "off")
 def default_interpret() -> bool:
     """One interpret-mode policy for every kernel entry point.
 
-    ``REPRO_INTERPRET`` env override wins (``1``/``true`` forces
-    interpret, ``0``/``false`` forces compiled); otherwise interpret
-    everywhere but TPU, where Pallas lowers natively.
+    On a TPU, where Pallas lowers natively, False — and ``REPRO_INTERPRET``
+    set to a true value raises instead of quietly running the kernels in
+    the interpreter on the chip.  Elsewhere the env override wins
+    (``1``/``true`` forces interpret, ``0``/``false`` forces compiled),
+    and interpret mode is the default.
     """
+    import jax
     env = os.environ.get("REPRO_INTERPRET", "").strip().lower()
-    if env in _TRUE:
-        return True
+    if jax.default_backend() == "tpu":
+        if env in _TRUE:
+            raise RuntimeError(
+                f"REPRO_INTERPRET={env!r} asks for Pallas interpret mode on "
+                "a TPU, where the kernels compile; unset it")
+        return False
     if env in _FALSE:
         return False
-    import jax
-    return jax.default_backend() != "tpu"
+    return True
 
 
 def default_impl() -> str:
@@ -79,7 +87,7 @@ def default_impl() -> str:
 def _working_set(bq: int, bn: int, d: int, itemsize: int, k: int) -> int:
     return ((bq + bn) * d * itemsize      # operand tiles
             + bq * bn * 4                 # distance tile (f32)
-            + bq * (bn + 2 * max(k, 1)) * 8)   # top-k fold concat
+            + bq * (bn + 2 * max(k, 1)) * 8)   # top-k fold working set
 
 
 def select_tiles(q: int, n: int, d: int, *, itemsize: int = 4, k: int = 0,

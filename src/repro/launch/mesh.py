@@ -16,17 +16,27 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with Auto axes.  JAX 0.9 makes Explicit axes the
+    default, and an eager op on an array sharded over an Explicit mesh
+    (the sharded executor's tombstone scatter, ``.at[].set``) then fails
+    outside a ``jax.set_mesh`` context."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
     """Small mesh over whatever devices exist (tests / examples)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def dp_axes(mesh: jax.sharding.Mesh) -> Tuple[str, ...]:

@@ -16,6 +16,7 @@ from ..core.baselines import ground_truth, recall
 from ..core.vectormaton import VectorMatonConfig
 from ..data.corpora import make_corpus, sample_patterns
 from ..serve.engine import Request, RetrievalEngine
+from .compile_cache import place_compile_cache
 
 
 def main() -> None:
@@ -31,12 +32,16 @@ def main() -> None:
     ap.add_argument("--checkpoint", default=None)
     args = ap.parse_args()
 
+    import jax
+    print(f"[serve] compile cache {place_compile_cache()}; serving on "
+          f"{jax.devices()[0].platform} ({jax.devices()[0].device_kind})")
     vecs, seqs = make_corpus(args.corpus, scale=args.scale)
     print(f"[serve] corpus {args.corpus}: n={len(seqs)} "
           f"total_len={sum(len(s) for s in seqs)} dim={vecs.shape[1]}")
     t0 = time.time()
     eng = RetrievalEngine(vecs, seqs,
-                          VectorMatonConfig(T=args.T, M=16, ef_con=100),
+                          VectorMatonConfig(T=args.T, M=16, ef_con=100,
+                                            backend="jax"),
                           workers=args.workers)
     print(f"[serve] index built in {time.time()-t0:.1f}s; "
           f"stats={eng.index.stats()}")

@@ -220,6 +220,45 @@ def test_sharded_plan_descriptor_churn_exact():
     """)
 
 
+def test_sync_tombstones_delete_on_sharded_mesh():
+    """A delete reaches the row-sharded tombstone bitmap through
+    ``sync_tombstones`` — one eager scatter into an array sharded over
+    the serving mesh — and the sweep's answers equal brute force over
+    the live set."""
+    _run_in_child("""
+        from jax.sharding import PartitionSpec as P
+        from repro.core.vectormaton import VectorMaton, VectorMatonConfig
+        from repro.distributed.sharded_search import sharded_plan_topk
+        from repro.launch.mesh import make_host_mesh
+
+        mesh = make_host_mesh(data=8, model=1)
+        rng = np.random.default_rng(3)
+        n, dim, preds = 300, 8, ["a", "ab"]
+        seqs = ["".join(rng.choice(list("ab"), size=6)) for _ in range(n)]
+        vecs = rng.standard_normal((n, dim)).astype(np.float32)
+        vm = VectorMaton(vecs, seqs, VectorMatonConfig(T=10 ** 9))
+        rt = vm.snapshot()
+        sh = rt.to_device_sharded(mesh)
+        q = rng.standard_normal((len(preds), dim)).astype(np.float32)
+        plan = vm.plan(preds, rt)
+        before = sharded_plan_topk(mesh, None, rt, q, plan, 5)
+        gone = {int(before[0][1][0]), int(before[1][1][1]), n - 1}
+        for g in gone:
+            vm.delete(g)
+        sh.sync_tombstones(rt.deleted)
+        assert set(np.nonzero(np.asarray(sh.deleted))[0].tolist()) == gone
+        assert sh.deleted.sharding.spec == P("data")
+        after = sharded_plan_topk(mesh, None, rt, q, plan, 5)
+        for r, p in enumerate(preds):
+            ids = np.asarray([j for j, s in enumerate(seqs)
+                              if p in s and j not in gone])
+            dd = ((q[r][None] - vecs[ids]) ** 2).sum(-1)
+            want = ids[np.argsort(dd, kind="stable")[:5]].tolist()
+            assert after[r][1].tolist() == want, (p, after[r][1], want)
+        print("sync_tombstones ok")
+    """)
+
+
 def test_sharded_engine_matches_single_chip():
     """RetrievalEngine(mesh=...) routes waves through the sharded
     executor; answers match the single-chip engine exactly on a raw-only

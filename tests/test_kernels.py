@@ -160,3 +160,60 @@ def test_topk_numpy_matches_kernel():
     nv, ni = ops.topk_numpy(x, y, 11)
     v, i = ops.topk(jnp.asarray(x), jnp.asarray(y), 11)
     np.testing.assert_allclose(nv, np.asarray(v), atol=2e-4, rtol=1e-4)
+
+
+# --------------------------------------------------------------------- #
+# the in-kernel running top-k fold
+# --------------------------------------------------------------------- #
+
+def _top_k_fold_ref(dist, k, block_n, tile_ids):
+    """The fold the kernels used to run, tile after tile: ``lax.top_k``
+    over ``concat([carry, tile])`` (ties to the lower position)."""
+    q = dist.shape[0]
+    cv = jnp.full((q, k), jnp.inf, jnp.float32)
+    ci = jnp.full((q, k), -1, jnp.int32)
+    for j in range(dist.shape[1] // block_n):
+        sl = slice(j * block_n, (j + 1) * block_n)
+        all_v = jnp.concatenate([cv, jnp.asarray(dist[:, sl])], axis=1)
+        all_i = jnp.concatenate([ci, jnp.asarray(tile_ids[:, sl])], axis=1)
+        neg, pos = jax.lax.top_k(-all_v, k)
+        cv, ci = -neg, jnp.take_along_axis(all_i, pos, axis=1)
+    return np.asarray(cv), np.asarray(ci)
+
+
+@pytest.mark.parametrize("k", [1, 5, 13, 40, 128])
+@pytest.mark.parametrize("segmented", [False, True],
+                         ids=["plain", "segmented"])
+def test_fold_matches_lax_top_k_with_ties(segmented, k):
+    """0/1 vectors give a handful of distinct distances, so nearly every
+    pick is a tie; k off the multiples of 8, up to the full 128 lanes,
+    and (segmented) rows with fewer than k candidates."""
+    from repro.kernels.distance_topk import (distance_topk,
+                                             distance_topk_segmented)
+    rng = np.random.default_rng(k)
+    q, n, d, block_n = 8, 512, 4, 128
+    x = rng.integers(0, 2, (q, d)).astype(np.float32)
+    y = rng.integers(0, 2, (n, d)).astype(np.float32)
+    dist = ((x * x).sum(1, keepdims=True) + (y * y).sum(1)[None, :]
+            - 2.0 * x @ y.T)
+    cols = np.broadcast_to(np.arange(n, dtype=np.int32), (q, n))
+    if segmented:
+        qseg = rng.integers(0, 3, (q, 1)).astype(np.int32)
+        cseg = rng.integers(0, 3, (1, n)).astype(np.int32)
+        cseg[0, :400] = 7                     # rows 0..2 match < 112 cands
+        match = qseg == cseg
+        v, i = distance_topk_segmented(
+            jnp.asarray(x), jnp.asarray(y), jnp.asarray(qseg),
+            jnp.asarray(cseg), k, block_q=q, block_n=block_n,
+            interpret=True)
+        rv, ri = _top_k_fold_ref(np.where(match, dist, np.inf), k, block_n,
+                                 np.where(match, cols, -1))
+    else:
+        valid_n = n - 37                      # padded tail rows never win
+        v, i = distance_topk(jnp.asarray(x), jnp.asarray(y), k,
+                             block_q=q, block_n=block_n, interpret=True,
+                             valid_n=valid_n)
+        rv, ri = _top_k_fold_ref(np.where(cols < valid_n, dist, np.inf), k,
+                                 block_n, cols)
+    assert np.array_equal(np.asarray(i), ri)
+    assert np.array_equal(np.asarray(v), rv)

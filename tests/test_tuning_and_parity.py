@@ -79,13 +79,43 @@ def test_select_tiles_divisor_constraint():
 # --------------------------------------------------------------------- #
 
 def test_default_interpret_env_override(monkeypatch):
-    monkeypatch.setenv("REPRO_INTERPRET", "1")
-    assert tuning.default_interpret() is True
-    monkeypatch.setenv("REPRO_INTERPRET", "false")
-    assert tuning.default_interpret() is False
-    monkeypatch.delenv("REPRO_INTERPRET")
+    monkeypatch.delenv("REPRO_INTERPRET", raising=False)
     if not ON_TPU:
         assert tuning.default_interpret() is True
+        monkeypatch.setenv("REPRO_INTERPRET", "1")
+        assert tuning.default_interpret() is True
+        monkeypatch.setenv("REPRO_INTERPRET", "false")
+        assert tuning.default_interpret() is False
+    # on a TPU the kernels always compile; asking for the interpreter
+    # there is an error, not a silent slow path
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("REPRO_INTERPRET", "0")
+    assert tuning.default_interpret() is False
+    monkeypatch.delenv("REPRO_INTERPRET")
+    assert tuning.default_interpret() is False
+    monkeypatch.setenv("REPRO_INTERPRET", "1")
+    with pytest.raises(RuntimeError, match="interpret mode on a TPU"):
+        tuning.default_interpret()
+
+
+def test_compile_cache_follows_env_else_checkout(monkeypatch, tmp_path):
+    """Entry points keep JAX's compile cache where
+    ``JAX_COMPILATION_CACHE_DIR`` says, else at the fixed, git-ignored
+    ``<checkout>/.jax_cache``."""
+    from repro.launch.compile_cache import CHECKOUT, place_compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert place_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert place_compile_cache() == str(CHECKOUT / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(
+            CHECKOUT / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+    assert (CHECKOUT / "chip_smoke.py").is_file()
+    assert ".jax_cache/" in (CHECKOUT / ".gitignore").read_text().split()
 
 
 def test_default_impl_env_override(monkeypatch):
