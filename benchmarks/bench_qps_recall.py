@@ -181,9 +181,11 @@ def run_device_smoke(profile: bool = False, seed: int = 0) -> dict:
         print(f"#   {'launches/batch':>20}: "
               f"{stats['launches'] / 20:10.2f}")
         ms = vm_g.maintenance_stats()
-        print("# wave timing breakdown (cumulative ms; launch is "
-              "trace+dispatch, merge absorbs the device sync):")
+        print("# wave timing breakdown (cumulative ms; launch and "
+              "merge_launch are trace+dispatch, fetch_sync the wait on "
+              "the device, merge the host merge):")
         for key in ("time_plan_ms", "time_upload_ms", "time_launch_ms",
+                    "time_merge_launch_ms", "time_fetch_sync_ms",
                     "time_merge_ms"):
             print(f"#   {key:>20}: {ms.get(key, 0.0):10.2f} ms")
             out[key] = float(ms.get(key, 0.0))

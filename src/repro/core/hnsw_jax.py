@@ -242,13 +242,14 @@ def hnsw_search_fused(vectors, ids, level0, entry, gidx, queries, *, k, ef,
     graph state in the bucket — the per-state launch loop this replaces
     cost one trace + one dispatch per (state, filter) combination.
     """
-    _check_beam_capacity(k, ef)
+    with jax.named_scope("vm/beam"):
+        _check_beam_capacity(k, ef)
 
-    def one(g, q):
-        return hnsw_search(vectors, ids[g], level0[g], entry[g], q, k=k,
-                           ef=ef, max_iter=max_iter, metric=metric)
+        def one(g, q):
+            return hnsw_search(vectors, ids[g], level0[g], entry[g], q, k=k,
+                               ef=ef, max_iter=max_iter, metric=metric)
 
-    return jax.vmap(one)(gidx, queries)
+        return jax.vmap(one)(gidx, queries)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "ef", "max_iter",
@@ -260,11 +261,12 @@ def hnsw_search_fused_filtered(vectors, ids, level0, entry, masks, midx,
     ``gidx[p]`` under candidate bitmap ``masks[midx[p]]`` ((Mn, V) bool
     over global ids — one row per DISTINCT mask, so conjunction sources
     sharing a bitmap ship it once per batch, not once per pair)."""
-    _check_beam_capacity(k, ef)
+    with jax.named_scope("vm/beam"):
+        _check_beam_capacity(k, ef)
 
-    def one(g, m, q):
-        return hnsw_search_filtered(vectors, ids[g], level0[g], entry[g],
-                                    q, masks[m], k=k, ef=ef,
-                                    max_iter=max_iter, metric=metric)
+        def one(g, m, q):
+            return hnsw_search_filtered(vectors, ids[g], level0[g], entry[g],
+                                        q, masks[m], k=k, ef=ef,
+                                        max_iter=max_iter, metric=metric)
 
-    return jax.vmap(one)(gidx, midx, queries)
+        return jax.vmap(one)(gidx, midx, queries)

@@ -73,6 +73,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..serve.telemetry import span
 from .predicate import CompiledPredicate, CompiledSource
 
 KIND_NONE = -1
@@ -282,6 +283,7 @@ class PendingExecution:
     dev_only: List[int] = field(default_factory=list)
     merged: Optional[Tuple[object, object]] = None   # (md, mi) on device
     fetched: bool = False
+    wave: int = -1                      # the pipeline's wave id (spans)
 
 
 class PackedRuntime:
@@ -354,7 +356,10 @@ class PackedRuntime:
             "query_bytes": 0, "descriptor_bytes": 0, "row_bytes": 0,
             "mask_bytes": 0, "shard_batches": 0, "shard_mask_bytes": 0,
             "shard_descriptor_bytes": 0, "shard_tail_bytes": 0,
-            "shard_query_bytes": 0}
+            "shard_query_bytes": 0,
+            # query-row x candidate-row pairs the scan grids evaluate, and
+            # the pairs of real query rows with their own candidates
+            "scan_pairs_computed": 0, "scan_pairs_needed": 0}
         # SQ8 scan-path accounting: every batch is either certified
         # (provably equal to the fp32 scan) or escalated to it; fallbacks
         # count batches the eligibility gate routed to fp32 outright
@@ -375,10 +380,11 @@ class PackedRuntime:
         self.SQ8_MAX_STREAK = 3
         # cumulative per-wave wall-clock (ms), surfaced by
         # maintenance_stats as time_*_ms.  Device dispatch is async, so
-        # launch_ms is trace+dispatch cost and merge_ms absorbs the sync.
+        # launch_ms and merge_launch_ms are trace+dispatch cost; the
+        # device wait is fetch_sync_ms alone, merge_ms the host merge.
         self.wave_times: Dict[str, float] = {
             "plan_ms": 0.0, "upload_ms": 0.0, "launch_ms": 0.0,
-            "merge_ms": 0.0}
+            "merge_launch_ms": 0.0, "fetch_sync_ms": 0.0, "merge_ms": 0.0}
 
     # ------------------------------------------------------------------ #
     # construction
@@ -744,7 +750,7 @@ class PackedRuntime:
                                         ef_search=ef_search))
 
     def dispatch(self, queries: np.ndarray, plan: QueryPlan, k: int,
-                 ef_search: int = 64) -> PendingExecution:
+                 ef_search: int = 64, wave: int = -1) -> PendingExecution:
         """Launch every device stage of the plan WITHOUT syncing on the
         results (DESIGN.md §7): staleness checks, the segmented scan
         launch, the fused beam launches, residual verification (host
@@ -778,7 +784,7 @@ class PackedRuntime:
             [] for _ in range(plan.n_requests)]      # (launch idx, row)
         pending = PendingExecution(plan=plan, k=k, out=out,
                                    launches=launches, dev_parts=dev_parts,
-                                   parts=parts)
+                                   parts=parts, wave=wave)
         if not plan.entries:
             pending.fetched = True
             return pending
@@ -788,10 +794,10 @@ class PackedRuntime:
             self.traffic["batches"] += 1
             if self.quantize == "sq8":
                 self._execute_scan_sq8(queries, scan_items, k, launches,
-                                       dev_parts)
+                                       dev_parts, wave)
             else:
                 self._execute_scan_device(queries, scan_items, k, launches,
-                                          dev_parts)
+                                          dev_parts, wave)
             t0 = time.perf_counter()
             self._execute_graphs_device(queries, graph_shared, graph_filtered,
                                         k, ef_search, launches, dev_parts)
@@ -805,15 +811,17 @@ class PackedRuntime:
         # device-merge half that can be DISPATCHED now: requests whose
         # parts are all launch rows fold on device; the (R, k) result
         # stays a device future until fetch
-        t0 = time.perf_counter()
         n = plan.n_requests
         if launches and self.device_merge:
             pending.dev_only = [r for r in range(n)
                                 if dev_parts[r] and not parts[r]]
         if pending.dev_only:
-            pending.merged = self._merge_device_launch(
-                pending.dev_only, launches, dev_parts, k)
-        self.wave_times["merge_ms"] += (time.perf_counter() - t0) * 1e3
+            with span("launch_merge", wave=wave):
+                t0 = time.perf_counter()
+                pending.merged = self._merge_device_launch(
+                    pending.dev_only, launches, dev_parts, k)
+                self.wave_times["merge_launch_ms"] += (
+                    (time.perf_counter() - t0) * 1e3)
         return pending
 
     def fetch(self, pending: PendingExecution
@@ -825,9 +833,17 @@ class PackedRuntime:
         already executing."""
         if pending.fetched:
             return pending.out
-        t0 = time.perf_counter()
-        self._merge_fetch(pending)
-        self.wave_times["merge_ms"] += (time.perf_counter() - t0) * 1e3
+        if pending.launches:
+            import jax
+            with span("sync", wave=pending.wave):
+                t0 = time.perf_counter()
+                jax.block_until_ready((pending.merged, pending.launches))
+                self.wave_times["fetch_sync_ms"] += (
+                    (time.perf_counter() - t0) * 1e3)
+        with span("merge_host", wave=pending.wave):
+            t0 = time.perf_counter()
+            self._merge_fetch(pending)
+            self.wave_times["merge_ms"] += (time.perf_counter() - t0) * 1e3
         pending.fetched = True
         return pending.out
 
@@ -1118,30 +1134,43 @@ class PackedRuntime:
                 np.asarray(downers, np.int32), tres_i, tres_ow,
                 tship_i, tship_ow, rows)
 
+    def _count_scan_pairs(self, flat, launches: int = 1) -> None:
+        """Adds the pairs of ``launches`` descriptor launches of one
+        assembled batch to ``scan_pairs_*``."""
+        from ..kernels import ops
+        _, q_owner, _, dlens, downers, _, tres_ow, _, tship_ow, _ = flat
+        computed, needed = ops.scan_pairs(q_owner, dlens, downers, tres_ow,
+                                          tship_ow)
+        self.traffic["scan_pairs_computed"] += launches * computed
+        self.traffic["scan_pairs_needed"] += launches * needed
+
     def _execute_scan_device(self, queries, scan_items, k, launches,
-                             dev_parts) -> None:
+                             dev_parts, wave: int = -1) -> None:
         """ONE descriptor-driven segmented Pallas launch for every
         brute-forced candidate set in the batch — chain raw segments,
         OR-union scans, masked conjunction scans alike.  Entries with
         several sources expand into one query row per (request, source)
         pair; outputs stay on device for the merge fold."""
         from ..kernels import ops
-        t0 = time.perf_counter()
-        flat = self._assemble_scan_batch(queries, scan_items)
-        self.wave_times["upload_ms"] += (time.perf_counter() - t0) * 1e3
+        with span("assemble", wave=wave):
+            t0 = time.perf_counter()
+            flat = self._assemble_scan_batch(queries, scan_items)
+            self.wave_times["upload_ms"] += (time.perf_counter() - t0) * 1e3
         if flat is None:
             return
         (q_rows, q_owner, dstarts, dlens, downers, tres_i, tres_ow,
          tship_i, tship_ow, rows) = flat
         dev = self.to_device()
-        t0 = time.perf_counter()
-        v, g = ops.topk_segmented_desc(
-            dev["vectors"], dev["base_ids"], dev["deleted"],
-            queries[q_rows], q_owner, dstarts, dlens, downers,
-            tres_i, tres_ow, tship_i, rows, tship_ow, k,
-            metric=self.metric, accum=self.accum)
-        dt = time.perf_counter() - t0
-        self.wave_times["launch_ms"] += dt * 1e3
+        with span("launch_scan", wave=wave):
+            t0 = time.perf_counter()
+            v, g = ops.topk_segmented_desc(
+                dev["vectors"], dev["base_ids"], dev["deleted"],
+                queries[q_rows], q_owner, dstarts, dlens, downers,
+                tres_i, tres_ow, tship_i, rows, tship_ow, k,
+                metric=self.metric, accum=self.accum)
+            dt = time.perf_counter() - t0
+            self.wave_times["launch_ms"] += dt * 1e3
+        self._count_scan_pairs(flat)
         self._observe("scan", self._scan_units(scan_items), dt)
         li = len(launches)
         launches.append((v, g))
@@ -1149,7 +1178,7 @@ class PackedRuntime:
             dev_parts[r].append((li, row))
 
     def _execute_scan_sq8(self, queries, scan_items, k, launches,
-                          dev_parts) -> None:
+                          dev_parts, wave: int = -1) -> None:
         """Default SQ8 scan path (``VectorMatonConfig.quantize='sq8'``):
         the whole batch's candidate sets run ONE segmented int8 launch
         against the resident quantized table, an fp32 rerank of the
@@ -1173,50 +1202,55 @@ class PackedRuntime:
                 self._sq8_warned = True
             self.sq8_stats["fallbacks"] += 1
             self._execute_scan_device(queries, scan_items, k, launches,
-                                      dev_parts)
+                                      dev_parts, wave)
             return
         if self.sq8_escalate and self._sq8_bad_streak >= self.SQ8_MAX_STREAK:
             # the certificate keeps failing on this workload: int8 scan
             # plus escalation is pure overhead, so serve fp32 directly
             self.sq8_stats["fallbacks"] += 1
             self._execute_scan_device(queries, scan_items, k, launches,
-                                      dev_parts)
+                                      dev_parts, wave)
             return
         overfetch = max(1, min(4, 128 // max(k, 1)))
-        t0 = time.perf_counter()
-        flat = self._assemble_scan_batch(queries, scan_items)
-        self.wave_times["upload_ms"] += (time.perf_counter() - t0) * 1e3
+        with span("assemble", wave=wave):
+            t0 = time.perf_counter()
+            flat = self._assemble_scan_batch(queries, scan_items)
+            self.wave_times["upload_ms"] += (time.perf_counter() - t0) * 1e3
         if flat is None:
             return
         (q_rows, q_owner, dstarts, dlens, downers, tres_i, tres_ow,
          tship_i, tship_ow, rows) = flat
         dev = self.to_device()
         self.sq8_stats["batches"] += 1
-        t0 = time.perf_counter()
-        v, g, cert = topk_sq8_segmented_desc(
-            dev["vectors"], dev["quant"], dev["base_ids"], dev["deleted"],
-            queries[q_rows], q_owner, dstarts, dlens, downers,
-            tres_i, tres_ow, tship_i, rows, tship_ow, k,
-            overfetch=overfetch)
-        if not self.sq8_escalate:
-            # approximate operating point: trust the rerank, never read
-            # the certificate back (no device sync on the hot path)
-            pass
-        elif bool(np.asarray(cert).all()):         # device sync
-            self.sq8_stats["certified"] += 1
-            self._sq8_bad_streak = 0
-        else:
-            # quantization noise could have pushed a true top-k candidate
-            # out of the over-fetched set: redo the whole batch exactly
-            v, g = ops.topk_segmented_desc(
-                dev["vectors"], dev["base_ids"], dev["deleted"],
-                queries[q_rows], q_owner, dstarts, dlens, downers,
-                tres_i, tres_ow, tship_i, rows, tship_ow, k,
-                metric=self.metric, accum=self.accum)
-            self.sq8_stats["escalations"] += 1
-            self._sq8_bad_streak += 1
-        dt = time.perf_counter() - t0
-        self.wave_times["launch_ms"] += dt * 1e3
+        with span("launch_scan", wave=wave):
+            t0 = time.perf_counter()
+            v, g, cert = topk_sq8_segmented_desc(
+                dev["vectors"], dev["quant"], dev["base_ids"],
+                dev["deleted"], queries[q_rows], q_owner, dstarts, dlens,
+                downers, tres_i, tres_ow, tship_i, rows, tship_ow, k,
+                overfetch=overfetch)
+            escalated = False
+            if not self.sq8_escalate:
+                # approximate operating point: trust the rerank, never read
+                # the certificate back (no device sync on the hot path)
+                pass
+            elif bool(np.asarray(cert).all()):         # device sync
+                self.sq8_stats["certified"] += 1
+                self._sq8_bad_streak = 0
+            else:
+                # quantization noise could have pushed a true top-k candidate
+                # out of the over-fetched set: redo the whole batch exactly
+                v, g = ops.topk_segmented_desc(
+                    dev["vectors"], dev["base_ids"], dev["deleted"],
+                    queries[q_rows], q_owner, dstarts, dlens, downers,
+                    tres_i, tres_ow, tship_i, rows, tship_ow, k,
+                    metric=self.metric, accum=self.accum)
+                escalated = True
+                self.sq8_stats["escalations"] += 1
+                self._sq8_bad_streak += 1
+            dt = time.perf_counter() - t0
+            self.wave_times["launch_ms"] += dt * 1e3
+        self._count_scan_pairs(flat, launches=2 if escalated else 1)
         self._observe("scan", self._scan_units(scan_items), dt)
         li = len(launches)
         launches.append((v, g))

@@ -316,21 +316,22 @@ def distance_topk_descriptors(vectors: jax.Array, base_ids: jax.Array,
     gathers, and gid mapping are shared, so the two differ only in the
     top-k schedule.
     """
-    y, cseg, gid_flat = assemble_flat_candidates(
-        vectors, base_ids, deleted, starts, lens, owners, tail_res_ids,
-        tail_res_owners, tail_ship_ids, tail_ship_owners, tail_ship_rows,
-        n_desc)
-    n = int(y.shape[0])
-    if impl == "xla":
-        vals, idx = segmented_dense_topk(x, y, qseg[:, 0], cseg, k,
-                                         metric=metric)
-    else:
-        vals, idx = _seg_pallas_call(
-            x, y, qseg, cseg.reshape(1, n), k, metric=metric,
-            block_q=block_q, block_n=block_n, interpret=interpret,
-            valid_n=n, accum=accum)
-    gids = jnp.where(idx >= 0, gid_flat[jnp.clip(idx, 0, n - 1)], -1)
-    return vals, gids
+    with jax.named_scope("vm/scan"):
+        y, cseg, gid_flat = assemble_flat_candidates(
+            vectors, base_ids, deleted, starts, lens, owners, tail_res_ids,
+            tail_res_owners, tail_ship_ids, tail_ship_owners, tail_ship_rows,
+            n_desc)
+        n = int(y.shape[0])
+        if impl == "xla":
+            vals, idx = segmented_dense_topk(x, y, qseg[:, 0], cseg, k,
+                                             metric=metric)
+        else:
+            vals, idx = _seg_pallas_call(
+                x, y, qseg, cseg.reshape(1, n), k, metric=metric,
+                block_q=block_q, block_n=block_n, interpret=interpret,
+                valid_n=n, accum=accum)
+        gids = jnp.where(idx >= 0, gid_flat[jnp.clip(idx, 0, n - 1)], -1)
+        return vals, gids
 
 
 def assemble_flat_candidates(vectors, base_ids, deleted, starts, lens,

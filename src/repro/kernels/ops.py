@@ -270,6 +270,34 @@ def topk_segmented_desc(vectors: jax.Array, base_ids: jax.Array,
     return jnp.where(bad, jnp.inf, vals), jnp.where(bad, -1, gids)
 
 
+def descriptor_extents(q: int, desc_lens: np.ndarray, n_res: int,
+                       n_ship: int) -> Tuple[int, int, int, int]:
+    """Bucketed extents ``(qp, n_desc, tr, ts)`` of a descriptor launch:
+    query rows, then the descriptor region, the resident tail and the
+    shipped tail of the flat candidate layout."""
+    nd_real = int(desc_lens.sum()) if len(desc_lens) else 0
+    return bucket(q), bucket(nd_real), bucket(n_res), bucket(n_ship)
+
+
+def scan_pairs(qseg: np.ndarray, desc_lens: np.ndarray,
+               desc_owners: np.ndarray, tail_res_owners: np.ndarray,
+               tail_ship_owners: np.ndarray) -> Tuple[int, int]:
+    """(pairs computed, pairs needed) of one descriptor launch, fp32 or
+    SQ8.  The kernels' grid evaluates every padded query row against
+    every padded candidate slot: the owner mask only discards a pair's
+    distance, and no tile is skipped.  A real query row needs the
+    candidates its owner's descriptors and tails name (tombstones
+    inside a frozen segment included: the host does not look inside
+    segments)."""
+    qp, n_desc, tr, ts = descriptor_extents(
+        len(qseg), desc_lens, len(tail_res_owners), len(tail_ship_owners))
+    m = int(qseg.max()) + 1 if len(qseg) else 0
+    rows = (np.bincount(desc_owners, weights=desc_lens, minlength=m)[:m]
+            + np.bincount(tail_res_owners, minlength=m)[:m]
+            + np.bincount(tail_ship_owners, minlength=m)[:m])
+    return qp * (n_desc + tr + ts), int(rows[qseg].sum())
+
+
 def pad_descriptor_batch(x, qseg, desc_starts, desc_lens, desc_owners,
                          tail_res_ids, tail_res_owners, tail_ship_ids,
                          tail_ship_rows, tail_ship_owners):
@@ -279,13 +307,12 @@ def pad_descriptor_batch(x, qseg, desc_starts, desc_lens, desc_owners,
     tail_ship_ids, tail_ship_owners, tail_ship_rows)`` and the shape
     bucket key ``(qp, n_desc, tr, ts, dp, d)``."""
     q, d = x.shape
-    qp = bucket(q)
+    qp, n_desc, tr, ts = descriptor_extents(q, desc_lens, len(tail_res_ids),
+                                            len(tail_ship_ids))
     xp = np.zeros((qp, d), np.float32)
     xp[:q] = x
     qsp = np.full((qp, 1), -1, np.int32)
     qsp[:q, 0] = qseg
-    nd_real = int(desc_lens.sum()) if len(desc_lens) else 0
-    n_desc = bucket(nd_real)
     dp = bucket(len(desc_starts), 8) if n_desc else 0
 
     def _pad1(a, n, fill):
@@ -293,8 +320,6 @@ def pad_descriptor_batch(x, qseg, desc_starts, desc_lens, desc_owners,
         out[:len(a)] = a
         return out
 
-    tr = bucket(len(tail_res_ids))
-    ts = bucket(len(tail_ship_ids))
     if n_desc + tr + ts == 0:
         raise ValueError("descriptor launch with no candidates")
     rows = np.zeros((ts, d), np.float32)
@@ -443,32 +468,33 @@ def merge_topk_device(big_d: jax.Array, big_i: jax.Array, sel: jax.Array,
     bit-for-bit; ``tests/test_device_exec.py`` asserts it on the churn
     oracle workload.
     """
-    r_n, s_n = sel.shape
-    d = big_d[sel].reshape(r_n, -1)
-    i = big_i[sel].reshape(r_n, -1)
-    dn = int(deleted.shape[0])
-    dead = (i >= 0) & (i < dn) & deleted[jnp.clip(i, 0, max(dn - 1, 0))]
-    bad = (i < 0) | dead | ~jnp.isfinite(d)
-    d = jnp.where(bad, jnp.inf, d)
-    iu = jnp.where(bad, _ID_SENTINEL, i)
+    with jax.named_scope("vm/merge"):
+        r_n, s_n = sel.shape
+        d = big_d[sel].reshape(r_n, -1)
+        i = big_i[sel].reshape(r_n, -1)
+        dn = int(deleted.shape[0])
+        dead = (i >= 0) & (i < dn) & deleted[jnp.clip(i, 0, max(dn - 1, 0))]
+        bad = (i < 0) | dead | ~jnp.isfinite(d)
+        d = jnp.where(bad, jnp.inf, d)
+        iu = jnp.where(bad, _ID_SENTINEL, i)
 
-    def one(drow, irow):
-        p1 = jnp.argsort(drow, stable=True)
-        ds, is_ = drow[p1], irow[p1]
-        p2 = jnp.argsort(is_, stable=True)        # ids grouped, d-order ties
-        idg = is_[p2]
-        first = jnp.concatenate(
-            [jnp.ones((1,), bool), idg[1:] != idg[:-1]])
-        first = first & (idg != _ID_SENTINEL)
-        keep = jnp.zeros_like(first).at[p2].set(first)   # back to d-order
-        rank = jnp.cumsum(keep) - 1
-        slot = jnp.where(keep & (rank < k), rank, k)
-        out_d = jnp.full((k + 1,), jnp.inf, jnp.float32).at[slot].set(ds)
-        out_i = jnp.full((k + 1,), -1, jnp.int32).at[slot].set(
-            jnp.where(is_ == _ID_SENTINEL, -1, is_))
-        return out_d[:k], out_i[:k]
+        def one(drow, irow):
+            p1 = jnp.argsort(drow, stable=True)
+            ds, is_ = drow[p1], irow[p1]
+            p2 = jnp.argsort(is_, stable=True)    # ids grouped, d-order ties
+            idg = is_[p2]
+            first = jnp.concatenate(
+                [jnp.ones((1,), bool), idg[1:] != idg[:-1]])
+            first = first & (idg != _ID_SENTINEL)
+            keep = jnp.zeros_like(first).at[p2].set(first)   # back to d-order
+            rank = jnp.cumsum(keep) - 1
+            slot = jnp.where(keep & (rank < k), rank, k)
+            out_d = jnp.full((k + 1,), jnp.inf, jnp.float32).at[slot].set(ds)
+            out_i = jnp.full((k + 1,), -1, jnp.int32).at[slot].set(
+                jnp.where(is_ == _ID_SENTINEL, -1, is_))
+            return out_d[:k], out_i[:k]
 
-    return jax.vmap(one)(d, iu)
+        return jax.vmap(one)(d, iu)
 
 
 def merge_topk_allgather(vals: jax.Array, gids: jax.Array, axis: str,

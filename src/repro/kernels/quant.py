@@ -265,97 +265,98 @@ def _sq8_topk_descriptors(vectors, vq, vsc, vsq, vl1, base_ids, deleted, x,
     global ids, and a per-query bool that is True iff the result provably
     equals the fp32 scan's (see module docstring); the executor escalates
     batches with any False row."""
-    # --- assemble the flat candidate layout against the int8 table -----
-    if n_desc:
-        dcand, down = expand_descriptors(base_ids, starts, lens, owners,
-                                         n_desc)
-    else:
-        dcand = jnp.empty((0,), jnp.int32)
-        down = jnp.empty((0,), jnp.int32)
-    cand_res = jnp.concatenate([dcand, tail_res_ids.astype(jnp.int32)])
-    own_res = jnp.concatenate([down, tail_res_owners.astype(jnp.int32)])
-    dn = int(deleted.shape[0])
-    if dn and cand_res.shape[0]:
-        dead = deleted[jnp.clip(cand_res, 0, dn - 1)]
-        own_res = jnp.where(dead, -3, own_res)
-    n_res = int(cand_res.shape[0])
-    ts = int(tail_ship_rows.shape[0])
+    with jax.named_scope("vm/sq8_scan"):
+        # --- assemble the flat candidate layout against the int8 table -----
+        if n_desc:
+            dcand, down = expand_descriptors(base_ids, starts, lens, owners,
+                                             n_desc)
+        else:
+            dcand = jnp.empty((0,), jnp.int32)
+            down = jnp.empty((0,), jnp.int32)
+        cand_res = jnp.concatenate([dcand, tail_res_ids.astype(jnp.int32)])
+        own_res = jnp.concatenate([down, tail_res_owners.astype(jnp.int32)])
+        dn = int(deleted.shape[0])
+        if dn and cand_res.shape[0]:
+            dead = deleted[jnp.clip(cand_res, 0, dn - 1)]
+            own_res = jnp.where(dead, -3, own_res)
+        n_res = int(cand_res.shape[0])
+        ts = int(tail_ship_rows.shape[0])
 
-    yq_p, sy_p, y2_p, l1_p = [], [], [], []
-    if n_res:
-        yq_p.append(vq[cand_res])
-        sy_p.append(vsc[cand_res])
-        y2_p.append(vsq[cand_res])
-        l1_p.append(vl1[cand_res])
-    if ts:
-        sq, ssc, ssq, sl1 = quantize_sq8_ext(tail_ship_rows)
-        yq_p.append(sq)
-        sy_p.append(ssc)
-        y2_p.append(ssq)
-        l1_p.append(sl1)
-    cat = (lambda p: jnp.concatenate(p, axis=0) if len(p) > 1 else p[0])
-    yq, sy, y2, yl1 = cat(yq_p), cat(sy_p), cat(y2_p), cat(l1_p)
-    cseg = jnp.concatenate([own_res, tail_ship_owners.astype(jnp.int32)])
-    gid_flat = jnp.concatenate([cand_res, tail_ship_ids.astype(jnp.int32)])
-    n = n_res + ts
-    qp, d = x.shape
+        yq_p, sy_p, y2_p, l1_p = [], [], [], []
+        if n_res:
+            yq_p.append(vq[cand_res])
+            sy_p.append(vsc[cand_res])
+            y2_p.append(vsq[cand_res])
+            l1_p.append(vl1[cand_res])
+        if ts:
+            sq, ssc, ssq, sl1 = quantize_sq8_ext(tail_ship_rows)
+            yq_p.append(sq)
+            sy_p.append(ssc)
+            y2_p.append(ssq)
+            l1_p.append(sl1)
+        cat = (lambda p: jnp.concatenate(p, axis=0) if len(p) > 1 else p[0])
+        yq, sy, y2, yl1 = cat(yq_p), cat(sy_p), cat(y2_p), cat(l1_p)
+        cseg = jnp.concatenate([own_res, tail_ship_owners.astype(jnp.int32)])
+        gid_flat = jnp.concatenate([cand_res, tail_ship_ids.astype(jnp.int32)])
+        n = n_res + ts
+        qp, d = x.shape
 
-    # --- int8 segmented scan: top-kq by quantized distance -------------
-    xq, sx, x2, xl1 = quantize_sq8_ext(x)
-    if impl == "xla":
-        vals_q, idx = _sq8_dense_segmented(xq, sx, x2, yq, sy, y2,
-                                           qseg[:, 0], cseg, kq)
-    else:
-        bq, bn = select_tiles(qp, n, d, itemsize=1, k=kq, divisor_n=n)
-        vals_q, idx = _quantized_topk_segmented(
-            xq, sx, x2, yq, sy, y2, qseg, cseg.reshape(1, n), kq,
-            block_q=min(bq, qp), block_n=bn, interpret=interpret,
-            valid_n=n)
+        # --- int8 segmented scan: top-kq by quantized distance -------------
+        xq, sx, x2, xl1 = quantize_sq8_ext(x)
+        if impl == "xla":
+            vals_q, idx = _sq8_dense_segmented(xq, sx, x2, yq, sy, y2,
+                                               qseg[:, 0], cseg, kq)
+        else:
+            bq, bn = select_tiles(qp, n, d, itemsize=1, k=kq, divisor_n=n)
+            vals_q, idx = _quantized_topk_segmented(
+                xq, sx, x2, yq, sy, y2, qseg, cseg.reshape(1, n), kq,
+                block_q=min(bq, qp), block_n=bn, interpret=interpret,
+                valid_n=n)
 
-    # --- exact fp32 rerank: gather only the (Q, kq, d) candidate rows --
-    idxc = jnp.clip(idx, 0, n - 1)
-    rowi = gid_flat[idxc]                    # resident gid == vectors row
-    if n_res and ts:
-        nv = max(int(vectors.shape[0]), 1)
-        from_res = vectors[jnp.clip(rowi, 0, nv - 1)]
-        from_ship = tail_ship_rows[jnp.clip(idxc - n_res, 0, ts - 1)]
-        cand = jnp.where((idxc < n_res)[..., None], from_res, from_ship)
-    elif ts:
-        cand = tail_ship_rows[idxc]
-    else:
-        cand = vectors[rowi]
-    xf = x.astype(f32)
-    candf = cand.astype(f32)
-    # same GEMM-form distance as the fp32 kernels, so certified results
-    # are numerically interchangeable with the fp32 scan's
-    xy = jnp.einsum("qd,qkd->qk", xf, candf,
-                    precision=jax.lax.Precision.HIGHEST,
-                    preferred_element_type=f32)
-    c2 = jnp.sum(candf * candf, axis=-1)
-    x2r = jnp.sum(xf * xf, axis=-1, keepdims=True)
-    d2 = jnp.maximum(x2r + c2 - 2.0 * xy, 0.0)
-    d2 = jnp.where(idx >= 0, d2, jnp.inf)
-    neg, pos = jax.lax.top_k(-d2, k)
-    fidx = jnp.take_along_axis(idx, pos, axis=1)
-    gids = jnp.where(fidx >= 0, gid_flat[jnp.clip(fidx, 0, n - 1)], -1)
-    vals = jnp.where(fidx >= 0, -neg, jnp.inf)
+        # --- exact fp32 rerank: gather only the (Q, kq, d) candidate rows --
+        idxc = jnp.clip(idx, 0, n - 1)
+        rowi = gid_flat[idxc]                    # resident gid == vectors row
+        if n_res and ts:
+            nv = max(int(vectors.shape[0]), 1)
+            from_res = vectors[jnp.clip(rowi, 0, nv - 1)]
+            from_ship = tail_ship_rows[jnp.clip(idxc - n_res, 0, ts - 1)]
+            cand = jnp.where((idxc < n_res)[..., None], from_res, from_ship)
+        elif ts:
+            cand = tail_ship_rows[idxc]
+        else:
+            cand = vectors[rowi]
+        xf = x.astype(f32)
+        candf = cand.astype(f32)
+        # same GEMM-form distance as the fp32 kernels, so certified results
+        # are numerically interchangeable with the fp32 scan's
+        xy = jnp.einsum("qd,qkd->qk", xf, candf,
+                        precision=jax.lax.Precision.HIGHEST,
+                        preferred_element_type=f32)
+        c2 = jnp.sum(candf * candf, axis=-1)
+        x2r = jnp.sum(xf * xf, axis=-1, keepdims=True)
+        d2 = jnp.maximum(x2r + c2 - 2.0 * xy, 0.0)
+        d2 = jnp.where(idx >= 0, d2, jnp.inf)
+        neg, pos = jax.lax.top_k(-d2, k)
+        fidx = jnp.take_along_axis(idx, pos, axis=1)
+        gids = jnp.where(fidx >= 0, gid_flat[jnp.clip(fidx, 0, n - 1)], -1)
+        vals = jnp.where(fidx >= 0, -neg, jnp.inf)
 
-    # --- certificate: can any excluded candidate beat the top-k? -------
-    live = cseg >= 0
-    own = jnp.clip(cseg, 0, qp - 1)
-    u = jnp.where(live, sy[:, 0], 0.0)
-    t = jnp.where(live, sy[:, 0] * (yl1[:, 0] + d / 2.0), 0.0)
-    umax = jnp.zeros((qp,), f32).at[own].max(u)
-    tmax = jnp.zeros((qp,), f32).at[own].max(t)
-    oq = jnp.clip(qseg[:, 0], 0, qp - 1)
-    eps = sx[:, 0] * (xl1[:, 0] * umax[oq] + tmax[oq])
-    qkq = vals_q[:, -1]                      # kq-th kept quantized dist
-    dk = vals[:, k - 1]                      # k-th exact reranked dist
-    # margin absorbs f32 rounding of the quantized estimate; a NaN or a
-    # clamped-to-zero q_kq fails the comparison and escalates safely
-    margin = eps + 1e-5 * (jnp.abs(qkq) + jnp.abs(dk)) + 1e-12
-    cert = jnp.isposinf(qkq) | (dk < qkq - margin)
-    return vals, gids, cert
+        # --- certificate: can any excluded candidate beat the top-k? -------
+        live = cseg >= 0
+        own = jnp.clip(cseg, 0, qp - 1)
+        u = jnp.where(live, sy[:, 0], 0.0)
+        t = jnp.where(live, sy[:, 0] * (yl1[:, 0] + d / 2.0), 0.0)
+        umax = jnp.zeros((qp,), f32).at[own].max(u)
+        tmax = jnp.zeros((qp,), f32).at[own].max(t)
+        oq = jnp.clip(qseg[:, 0], 0, qp - 1)
+        eps = sx[:, 0] * (xl1[:, 0] * umax[oq] + tmax[oq])
+        qkq = vals_q[:, -1]                      # kq-th kept quantized dist
+        dk = vals[:, k - 1]                      # k-th exact reranked dist
+        # margin absorbs f32 rounding of the quantized estimate; a NaN or a
+        # clamped-to-zero q_kq fails the comparison and escalates safely
+        margin = eps + 1e-5 * (jnp.abs(qkq) + jnp.abs(dk)) + 1e-12
+        cert = jnp.isposinf(qkq) | (dk < qkq - margin)
+        return vals, gids, cert
 
 
 def topk_sq8_segmented_desc(vectors, quant, base_ids, deleted, x, qseg,

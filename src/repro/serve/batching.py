@@ -48,6 +48,7 @@ from typing import Callable, Dict, Deque, List, Optional, Sequence, Tuple
 import numpy as np
 
 from .engine import Request, Response, RetrievalEngine
+from .telemetry import span
 
 
 class DrainTimeout(RuntimeError):
@@ -163,16 +164,35 @@ class ContinuousBatcher:
         compiler's selectivity estimate (Σ|V_state| over the compiled
         sources) — boolean predicates are priced by the candidate rows
         their strategies will actually touch."""
-        with self.engine._lock:              # pred-cache is shared state
-            cp = self.engine.index.compile(req.pattern)
-        t = time.perf_counter()
-        with self._lock:
-            q = _Queued(sort_key=(t, self._seq), seq=self._seq,
-                        request=req, key=cp.key, cost=cp.est, t_arrival=t)
-            heapq.heappush(self._queue, q)
-            self._seq += 1
-            self._tenants.setdefault(req.tenant, _TenantState())
+        wave = self._wave_counter            # the earliest it can join
+        with span("submit", wave=wave) as submit_span:
+            t0 = time.perf_counter()
+            with span("engine_lock", wave=wave):
+                self.engine._lock.acquire()     # pred-cache is shared state
+            lock_ms = (time.perf_counter() - t0) * 1e3
+            try:
+                with span("compile_predicate", wave=wave):
+                    cp = self.engine.index.compile(req.pattern)
+            finally:
+                self.engine._lock.release()
+            t = time.perf_counter()
+            with self._lock:
+                q = _Queued(sort_key=(t, self._seq), seq=self._seq,
+                            request=req, key=cp.key, cost=cp.est,
+                            t_arrival=t)
+                heapq.heappush(self._queue, q)
+                self._seq += 1
+                self._tenants.setdefault(req.tenant, _TenantState())
+                self._count("batcher_submitted", 1)
+                self._count("batcher_submit_lock_ms", lock_ms)
+            submit_span.set_metadata(ticket=q.seq)
             return q.seq
+
+    def _count(self, key: str, value: float) -> None:
+        """Adds to a batcher counter in the engine's ``pipeline_stats``
+        (surfaced by ``maintenance_stats``); the caller holds ``_lock``."""
+        stats = self.engine.pipeline_stats
+        stats[key] = stats.get(key, 0) + value
 
     def pending(self) -> int:
         with self._lock:
@@ -267,10 +287,15 @@ class ContinuousBatcher:
         with self._lock:
             if not self._queue:
                 return []
-            tenants = {q.request.tenant for q in self._queue}
-            if len(tenants) <= 1:
-                return self._next_wave_fifo()
-            return self._next_wave_drr()
+            with span("admit", wave=self._wave_counter):
+                tenants = {q.request.tenant for q in self._queue}
+                wave = (self._next_wave_fifo() if len(tenants) <= 1
+                        else self._next_wave_drr())
+                now = time.perf_counter()
+                self._count("batcher_admitted", len(wave))
+                self._count("batcher_queue_wait_ms", sum(
+                    now - q.t_arrival for q in wave) * 1e3)
+                return wave
 
     def _next_wave_fifo(self) -> List[_Queued]:
         wave: List[_Queued] = []
@@ -443,7 +468,8 @@ class ContinuousBatcher:
     def _collect_jobs(self, jobs: List, out: Dict[int, Response]) -> None:
         for job, items in jobs:
             try:
-                results = job.wait(timeout=self.request_timeout_s)
+                with span("collect", wave=job.index):
+                    results = job.wait(timeout=self.request_timeout_s)
             except TimeoutError:
                 # deadline blown: record the loss per tenant and surface
                 # a typed error instead of hanging the submitter on a
@@ -458,13 +484,14 @@ class ContinuousBatcher:
                     f"{self.request_timeout_s:.1f}s "
                     f"(request_timeout_s deadline)",
                     tickets=[q.seq for q in items]) from None
-            t1 = time.perf_counter()
-            for q, (d, i) in zip(items, results):
-                resp = Response(ids=i, distances=d,
-                                latency_s=t1 - q.t_arrival)
-                out[q.seq] = resp
-                self._record(q, resp)
-                self._deferred.pop(q.seq, None)
+            with span("deliver", wave=job.index):
+                t1 = time.perf_counter()
+                for q, (d, i) in zip(items, results):
+                    resp = Response(ids=i, distances=d,
+                                    latency_s=t1 - q.t_arrival)
+                    out[q.seq] = resp
+                    self._record(q, resp)
+                    self._deferred.pop(q.seq, None)
         jobs.clear()
 
     def drain(self, max_waves: Optional[int] = None,

@@ -37,6 +37,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..core.vectormaton import VectorMaton, VectorMatonConfig
+from .telemetry import compile_stats, span
 
 
 @dataclass
@@ -72,6 +73,7 @@ class WavePlan:
     rt: object          # PackedRuntime snapshot
     plan: object        # QueryPlan (generation/delta-version stamped)
     staged: Optional[object] = None   # StagingSlot (double-buffered upload)
+    wave: int = -1      # the pipeline's wave id, carried by the spans
 
 
 @dataclass
@@ -115,7 +117,7 @@ class RetrievalEngine:
     # pipeline stage API (DESIGN.md §7): plan -> dispatch -> fetch
     # ------------------------------------------------------------------ #
     def plan_batch(self, queries: np.ndarray, patterns: Sequence, k: int,
-                   ef_search: int = 64) -> WavePlan:
+                   ef_search: int = 64, wave: int = -1) -> WavePlan:
         """Host planning stage: snapshot one runtime generation, compile
         every predicate (pred-cache), coalesce into a QueryPlan.  Pure
         host work — safe on a background thread under the engine lock.
@@ -123,15 +125,16 @@ class RetrievalEngine:
         executor feedback folds into the adaptive planner's cost model
         (DESIGN.md §11) — so cost state is frozen per wave and a
         dispatched plan is never re-decided mid-flight."""
-        with self._lock:
-            rt = self.index.snapshot()
-            t0 = time.perf_counter()
-            plan = self.index.plan(patterns, rt)
-            rt.wave_times["plan_ms"] += (time.perf_counter() - t0) * 1e3
-        return WavePlan(
-            queries=np.ascontiguousarray(queries, dtype=np.float32),
-            patterns=list(patterns), k=k, ef_search=ef_search,
-            rt=rt, plan=plan)
+        with span("plan_batch", wave=wave):
+            with self._lock:
+                rt = self.index.snapshot()
+                t0 = time.perf_counter()
+                plan = self.index.plan(patterns, rt)
+                rt.wave_times["plan_ms"] += (time.perf_counter() - t0) * 1e3
+            return WavePlan(
+                queries=np.ascontiguousarray(queries, dtype=np.float32),
+                patterns=list(patterns), k=k, ef_search=ef_search,
+                rt=rt, plan=plan, wave=wave)
 
     def dispatch_batch(self, wave: WavePlan) -> WavePending:
         """Device dispatch stage: launch the wave's kernels without
@@ -139,12 +142,13 @@ class RetrievalEngine:
         the runtime moved since ``plan_batch`` (insert bumped the delta
         version, compaction swapped the generation) — the pipeline
         replans; it never locks writers out."""
-        with self._lock:
+        with span("dispatch_batch", wave=wave.wave), self._lock:
             if self.mesh is None:
                 q = (wave.staged.view(len(wave.queries))
                      if wave.staged is not None else wave.queries)
                 inner = wave.rt.dispatch(q, wave.plan, wave.k,
-                                         ef_search=wave.ef_search)
+                                         ef_search=wave.ef_search,
+                                         wave=wave.wave)
                 return WavePending(wave=wave, inner=inner, sharded=False)
             from ..distributed.sharded_search import sharded_plan_dispatch
             inner = sharded_plan_dispatch(
@@ -158,14 +162,15 @@ class RetrievalEngine:
         assemble per-request results.  Deliberately lock-free — the
         arrays it touches belong to the dispatched wave alone, and
         blocking here must overlap the next wave's planning."""
-        if pending.sharded:
-            from ..distributed.sharded_search import sharded_plan_fetch
-            out = sharded_plan_fetch(pending.wave.rt, pending.inner)
-        else:
-            out = pending.wave.rt.fetch(pending.inner)
-        if pending.wave.staged is not None:
-            pending.wave.staged.release()
-        return out
+        with span("fetch_batch", wave=pending.wave.wave):
+            if pending.sharded:
+                from ..distributed.sharded_search import sharded_plan_fetch
+                out = sharded_plan_fetch(pending.wave.rt, pending.inner)
+            else:
+                out = pending.wave.rt.fetch(pending.inner)
+            if pending.wave.staged is not None:
+                pending.wave.staged.release()
+            return out
 
     # ------------------------------------------------------------------ #
     def query_batch(self, queries: np.ndarray, patterns: Sequence,
@@ -238,11 +243,13 @@ class RetrievalEngine:
     def maintenance_stats(self):
         """Generation / delta / compaction counters (bench_churn), plus
         the live pipeline counters (pipeline_depth, device_idle_ms,
-        planner-queue wait, per-tenant depth/latency) when a pipelined
-        batcher is attached (DESIGN.md §7)."""
+        planner-queue wait, per-tenant depth/latency, the batcher's
+        admission counters) when a pipelined batcher is attached, and
+        the process's compile counters (DESIGN.md §7)."""
         with self._lock:
             stats = self.index.maintenance_stats()
             stats.update(self.pipeline_stats)
+        stats.update(compile_stats())
         return stats
 
     def replication_token(self) -> Tuple[int, int]:

@@ -46,6 +46,8 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .telemetry import span
+
 
 @dataclass
 class WaveJob:
@@ -176,17 +178,21 @@ class PipelinedExecutor:
             try:
                 wave = self.engine.plan_batch(job.queries, job.patterns,
                                               job.k,
-                                              ef_search=job.ef_search)
+                                              ef_search=job.ef_search,
+                                              wave=job.index)
                 if self._ring is not None:
-                    wave.staged = self._ring.acquire(job.queries,
-                                                     timeout=60.0)
-                self._planned.put((job, wave))
+                    with span("stage_queries", wave=job.index):
+                        wave.staged = self._ring.acquire(job.queries,
+                                                         timeout=60.0)
+                with span("handoff", wave=job.index):
+                    self._planned.put((job, wave))
             except BaseException as e:          # surface to the submitter
                 job.error = e
                 self._finish(job)
 
     def _exec_loop(self) -> None:
         inflight: List[Tuple[WaveJob, object]] = []
+        expect = 0                      # id of the next wave to arrive
         while True:
             if inflight:
                 # a wave is executing: give the planner a moment to hand
@@ -194,19 +200,22 @@ class PipelinedExecutor:
                 # (the overlap); if nothing is ready, the stream really
                 # has gone dry — fetch and deliver rather than hold
                 try:
-                    item = self._planned.get(timeout=0.001)
+                    with span("await_plan", wave=expect):
+                        item = self._planned.get(timeout=0.001)
                 except queue.Empty:
                     self._fetch(*inflight.pop(0))
                     continue
             else:
-                t0 = time.perf_counter()
-                item = self._planned.get()
-                self.stats["planner_wait_ms"] += (
-                    (time.perf_counter() - t0) * 1e3)
+                with span("await_plan", wave=expect):
+                    t0 = time.perf_counter()
+                    item = self._planned.get()
+                    self.stats["planner_wait_ms"] += (
+                        (time.perf_counter() - t0) * 1e3)
             if item is None:
                 self._drain(inflight)
                 return
             job, wave = item
+            expect = job.index + 1
             try:
                 if job.pre_dispatch is not None:
                     job.pre_dispatch()
@@ -254,7 +263,7 @@ class PipelinedExecutor:
                     wave.staged.release()
                 wave = self.engine.plan_batch(
                     job.queries, job.patterns, job.k,
-                    ef_search=job.ef_search)
+                    ef_search=job.ef_search, wave=job.index)
 
     def _fetch(self, job: WaveJob, pending) -> None:
         try:
@@ -273,13 +282,14 @@ class PipelinedExecutor:
             self._fetch(*inflight.pop(0))
 
     def _finish(self, job: WaveJob) -> None:
-        with self._cv:
-            self._completed += 1
-            self.stats["pipeline_depth"] = max(
-                0, self._submitted - self._completed)
-            self._cv.notify_all()
-        job.done.set()
-        self._publish()
+        with span("finish", wave=job.index):
+            with self._cv:
+                self._completed += 1
+                self.stats["pipeline_depth"] = max(
+                    0, self._submitted - self._completed)
+                self._cv.notify_all()
+            job.done.set()
+            self._publish()
 
     def _publish(self) -> None:
         """Mirror the live counters into the engine so
