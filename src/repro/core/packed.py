@@ -286,6 +286,19 @@ class PendingExecution:
     wave: int = -1                      # the pipeline's wave id (spans)
 
 
+def merge_sel(pools: List[List[int]], pad: int) -> np.ndarray:
+    """The device merge's (R_pad, S) index matrix: row j lists the launch
+    rows of request j's candidate pool (``pools[j]``, in merge order) and
+    points its other slots at row ``pad``, the merge's all-padding row;
+    both sides are bucketed so waves share the merge's executables."""
+    from ..kernels.ops import bucket
+    sel = np.full((bucket(len(pools), 8),
+                   bucket(max(len(p) for p in pools), 1)), pad, np.int32)
+    for j, pool in enumerate(pools):
+        sel[j, :len(pool)] = pool
+    return sel
+
+
 class PackedRuntime:
     """Flattened, device-residable view of a built VectorMaton index."""
 
@@ -359,7 +372,11 @@ class PackedRuntime:
             "shard_query_bytes": 0,
             # query-row x candidate-row pairs the scan grids evaluate, and
             # the pairs of real query rows with their own candidates
-            "scan_pairs_computed": 0, "scan_pairs_needed": 0}
+            "scan_pairs_computed": 0, "scan_pairs_needed": 0,
+            # transfers and program dispatches the executor issues for
+            # the scan, graph and merge launches and the fetch (residual
+            # verification's eager math is not counted)
+            "host_device_calls": 0}
         # SQ8 scan-path accounting: every batch is either certified
         # (provably equal to the fp32 scan) or escalated to it; fallbacks
         # count batches the eligibility gate routed to fp32 outright
@@ -790,14 +807,18 @@ class PackedRuntime:
             return pending
         scan_items, graph_shared, graph_filtered, residual_items = (
             self._gather_work(plan))
+        sel = None
         if self.backend == "jax":
             self.traffic["batches"] += 1
-            if self.quantize == "sq8":
-                self._execute_scan_sq8(queries, scan_items, k, launches,
-                                       dev_parts, wave)
-            else:
-                self._execute_scan_device(queries, scan_items, k, launches,
-                                          dev_parts, wave)
+            # a wave of scans alone merges every request on device from
+            # the scan's one launch, so the merge's index matrix is known
+            # now and rides in the scan's upload
+            scan_only = self.device_merge and not (
+                graph_shared or graph_filtered or residual_items)
+            execute_scan = (self._execute_scan_sq8 if self.quantize == "sq8"
+                            else self._execute_scan_device)
+            sel = execute_scan(queries, scan_items, k, launches, dev_parts,
+                               wave, with_sel=scan_only)
             t0 = time.perf_counter()
             self._execute_graphs_device(queries, graph_shared, graph_filtered,
                                         k, ef_search, launches, dev_parts)
@@ -819,7 +840,7 @@ class PackedRuntime:
             with span("launch_merge", wave=wave):
                 t0 = time.perf_counter()
                 pending.merged = self._merge_device_launch(
-                    pending.dev_only, launches, dev_parts, k)
+                    pending.dev_only, launches, dev_parts, k, sel)
                 self.wave_times["merge_launch_ms"] += (
                     (time.perf_counter() - t0) * 1e3)
         return pending
@@ -855,14 +876,15 @@ class PackedRuntime:
         to the host; the rest — host backend, or residual parts present —
         run the NumPy merge, which is the bit-exactness oracle
         (``device_merge=False`` forces it everywhere)."""
+        import jax
         plan, launches, dev_parts, parts, k, out = (
             pending.plan, pending.launches, pending.dev_parts,
             pending.parts, pending.k, pending.out)
         n = plan.n_requests
         dev_only = pending.dev_only
         if pending.merged is not None:
-            md, mi = (np.asarray(pending.merged[0]),
-                      np.asarray(pending.merged[1]))
+            md, mi = jax.device_get(pending.merged)
+            self.traffic["host_device_calls"] += 1
             for j, r in enumerate(dev_only):
                 valid = mi[j] >= 0
                 out[r] = (md[j][valid], mi[j][valid].astype(np.int64))
@@ -872,8 +894,8 @@ class PackedRuntime:
 
         def _host_rows(li: int) -> Tuple[np.ndarray, np.ndarray]:
             if conv[li] is None:
-                v, g = launches[li]
-                conv[li] = (np.asarray(v), np.asarray(g))
+                conv[li] = jax.device_get(launches[li])
+                self.traffic["host_device_calls"] += 1
             return conv[li]
 
         for r in range(n):
@@ -907,46 +929,59 @@ class PackedRuntime:
             out[r] = (d[:k], i[:k])
 
     def _merge_device_launch(self, reqs: List[int], launches, dev_parts,
-                             k: int) -> Tuple[object, object]:
-        """Stack this batch's launch outputs into one (T, W) pool, gather
-        each request's rows by index matrix, and fold dedup + top-k on
-        device — replacing the per-request Python concatenate/argsort
-        loop with one bucketed launch and ONE (R, k) transfer back.
-        Returns the (R_pad, k) device arrays WITHOUT syncing: ``fetch``
-        crosses them to the host when the caller needs the results."""
+                             k: int, sel=None) -> Tuple[object, object]:
+        """Gather each request's launch rows by index matrix and fold
+        dedup + top-k on device — replacing the per-request Python
+        concatenate/argsort loop with one bucketed launch and ONE (R, k)
+        transfer back.  A wave of one launch (every scan-only wave) hands
+        that launch's outputs to the merge as they are, with ``sel``
+        already uploaded by the scan; several launches (graph beams) are
+        stacked into one (T, W) pool first.  Returns the (R_pad, k)
+        device arrays WITHOUT syncing: ``fetch`` crosses them to the host
+        when the caller needs the results."""
+        import jax
         import jax.numpy as jnp
 
         from ..kernels import ops
         dev = self.to_device()
-        w = max(int(v.shape[1]) for v, _ in launches)
-        pd, pi, offs = [], [], []
-        t = 0
-        for v, g in launches:
-            if int(v.shape[1]) < w:
-                v = jnp.pad(v, ((0, 0), (0, w - int(v.shape[1]))),
-                            constant_values=np.inf)
-                g = jnp.pad(g, ((0, 0), (0, w - int(g.shape[1]))),
-                            constant_values=-1)
-            pd.append(v)
-            pi.append(g)
-            offs.append(t)
-            t += int(v.shape[0])
-        t_pad = ops.bucket(t + 1, 8)
-        big_d = jnp.pad(jnp.concatenate(pd, axis=0),
-                        ((0, t_pad - t), (0, 0)), constant_values=np.inf)
-        big_i = jnp.pad(jnp.concatenate(pi, axis=0),
-                        ((0, t_pad - t), (0, 0)), constant_values=-1)
-        s_max = ops.bucket(max(len(dev_parts[r]) for r in reqs), 1)
-        r_pad = ops.bucket(len(reqs), 8)
-        sel = np.full((r_pad, s_max), t_pad - 1, np.int32)   # padding row
-        for j, r in enumerate(reqs):
-            for s, (li, row) in enumerate(dev_parts[r]):
-                sel[j, s] = offs[li] + row
+        calls = 1                                       # the merge launch
+        if len(launches) == 1:
+            big_d, big_i = launches[0]
+            t_pad = int(big_d.shape[0])
+            offs = [0]
+        else:
+            w = max(int(v.shape[1]) for v, _ in launches)
+            pd, pi, offs = [], [], []
+            t = 0
+            for v, g in launches:
+                if int(v.shape[1]) < w:
+                    v = jnp.pad(v, ((0, 0), (0, w - int(v.shape[1]))),
+                                constant_values=np.inf)
+                    g = jnp.pad(g, ((0, 0), (0, w - int(g.shape[1]))),
+                                constant_values=-1)
+                    calls += 2
+                pd.append(v)
+                pi.append(g)
+                offs.append(t)
+                t += int(v.shape[0])
+            t_pad = ops.bucket(t, 8)
+            big_d = jnp.pad(jnp.concatenate(pd, axis=0),
+                            ((0, t_pad - t), (0, 0)), constant_values=np.inf)
+            big_i = jnp.pad(jnp.concatenate(pi, axis=0),
+                            ((0, t_pad - t), (0, 0)), constant_values=-1)
+            calls += 4
+        if sel is None:
+            sel = jax.device_put(merge_sel(
+                [[offs[li] + row for li, row in dev_parts[r]] for r in reqs],
+                t_pad))
+            calls += 1
         delmask = (dev["deleted"] if self._dev_n
                    else jnp.zeros(1, dtype=bool))
-        md, mi = ops.merge_topk_device(big_d, big_i, jnp.asarray(sel),
-                                       delmask, k)
-        ops.record_launch("merge", (t_pad, s_max, w, r_pad, k))
+        md, mi = ops.merge_topk_device(big_d, big_i, sel, delmask, k)
+        self.traffic["host_device_calls"] += calls
+        r_pad, s_max = sel.shape
+        ops.record_launch("merge",
+                          (t_pad, s_max, int(big_d.shape[1]), r_pad, k))
         return md, mi
 
     def _gather_work(self, plan: QueryPlan):
@@ -1144,51 +1179,86 @@ class PackedRuntime:
         self.traffic["scan_pairs_computed"] += launches * computed
         self.traffic["scan_pairs_needed"] += launches * needed
 
+    def _upload_scan_batch(self, queries, flat, with_sel: bool):
+        """Pad an assembled scan batch into its two host buffers
+        (``ops.pad_descriptor_batch``) and ship them in ONE
+        ``jax.device_put`` — with the device merge's index matrix when
+        ``with_sel`` (a scan-only wave).  Returns the device ``(floats,
+        ints)`` pair, its bucket key and the device ``sel`` (or None)."""
+        import jax
+
+        from ..kernels import ops
+        (q_rows, q_owner, dstarts, dlens, downers, tres_i, tres_ow,
+         tship_i, tship_ow, rows) = flat
+        host, key = ops.pad_descriptor_batch(
+            queries[q_rows], q_owner, dstarts, dlens, downers, tres_i,
+            tres_ow, tship_i, rows, tship_ow)
+        sel = None
+        if with_sel:
+            per_req: Dict[int, List[int]] = {}
+            for row, r in enumerate(q_rows):
+                per_req.setdefault(r, []).append(row)
+            sel = merge_sel([per_req[r] for r in sorted(per_req)], key[0])
+        batch, sel = jax.device_put((host, sel))
+        self.traffic["host_device_calls"] += 1
+        return batch, key, sel
+
     def _execute_scan_device(self, queries, scan_items, k, launches,
-                             dev_parts, wave: int = -1) -> None:
+                             dev_parts, wave: int = -1,
+                             with_sel: bool = False):
         """ONE descriptor-driven segmented Pallas launch for every
         brute-forced candidate set in the batch — chain raw segments,
         OR-union scans, masked conjunction scans alike.  Entries with
         several sources expand into one query row per (request, source)
-        pair; outputs stay on device for the merge fold."""
+        pair; outputs stay on device for the merge fold.  Returns the
+        merge's uploaded index matrix (``_upload_scan_batch``) or None."""
         from ..kernels import ops
         with span("assemble", wave=wave):
             t0 = time.perf_counter()
             flat = self._assemble_scan_batch(queries, scan_items)
             self.wave_times["upload_ms"] += (time.perf_counter() - t0) * 1e3
         if flat is None:
-            return
-        (q_rows, q_owner, dstarts, dlens, downers, tres_i, tres_ow,
-         tship_i, tship_ow, rows) = flat
+            return None
         dev = self.to_device()
         with span("launch_scan", wave=wave):
             t0 = time.perf_counter()
+            with span("upload", wave=wave):
+                batch, key, sel = self._upload_scan_batch(queries, flat,
+                                                          with_sel)
             v, g = ops.topk_segmented_desc(
-                dev["vectors"], dev["base_ids"], dev["deleted"],
-                queries[q_rows], q_owner, dstarts, dlens, downers,
-                tres_i, tres_ow, tship_i, rows, tship_ow, k,
-                metric=self.metric, accum=self.accum)
+                dev["vectors"], dev["base_ids"], dev["deleted"], batch, key,
+                k, metric=self.metric, accum=self.accum)
+            self.traffic["host_device_calls"] += 1
             dt = time.perf_counter() - t0
             self.wave_times["launch_ms"] += dt * 1e3
         self._count_scan_pairs(flat)
         self._observe("scan", self._scan_units(scan_items), dt)
+        self._emit_scan(flat[0], v, g, launches, dev_parts)
+        return sel
+
+    @staticmethod
+    def _emit_scan(q_rows, v, g, launches, dev_parts) -> None:
         li = len(launches)
         launches.append((v, g))
         for row, r in enumerate(q_rows):
             dev_parts[r].append((li, row))
 
     def _execute_scan_sq8(self, queries, scan_items, k, launches,
-                          dev_parts, wave: int = -1) -> None:
+                          dev_parts, wave: int = -1,
+                          with_sel: bool = False):
         """Default SQ8 scan path (``VectorMatonConfig.quantize='sq8'``):
         the whole batch's candidate sets run ONE segmented int8 launch
         against the resident quantized table, an fp32 rerank of the
         over-fetched top-kq, and the exactness certificate
         (``quant._sq8_topk_descriptors``).  A batch whose certificate
         fails on any query row is re-run through the fp32 descriptor
-        path, so results always equal the fp32 scan's; ``sq8_stats``
-        counts certified vs escalated batches.  Batches the eligibility
-        gate rejects outright (metric/dim/k outside ``sq8_supported``)
-        fall back to the fp32 path with a one-time warning."""
+        path on the same uploaded buffers, so results always equal the
+        fp32 scan's; ``sq8_stats`` counts certified vs escalated batches.
+        Batches the eligibility gate rejects outright (metric/dim/k
+        outside ``sq8_supported``) fall back to the fp32 path with a
+        one-time warning.  Returns what ``_execute_scan_device`` does."""
+        import jax
+
         from ..kernels import ops
         from ..kernels.quant import sq8_supported, topk_sq8_segmented_desc
         d_dim = int(queries.shape[1])
@@ -1201,61 +1271,59 @@ class PackedRuntime:
                     RuntimeWarning, stacklevel=3)
                 self._sq8_warned = True
             self.sq8_stats["fallbacks"] += 1
-            self._execute_scan_device(queries, scan_items, k, launches,
-                                      dev_parts, wave)
-            return
+            return self._execute_scan_device(queries, scan_items, k,
+                                             launches, dev_parts, wave,
+                                             with_sel)
         if self.sq8_escalate and self._sq8_bad_streak >= self.SQ8_MAX_STREAK:
             # the certificate keeps failing on this workload: int8 scan
             # plus escalation is pure overhead, so serve fp32 directly
             self.sq8_stats["fallbacks"] += 1
-            self._execute_scan_device(queries, scan_items, k, launches,
-                                      dev_parts, wave)
-            return
+            return self._execute_scan_device(queries, scan_items, k,
+                                             launches, dev_parts, wave,
+                                             with_sel)
         overfetch = max(1, min(4, 128 // max(k, 1)))
         with span("assemble", wave=wave):
             t0 = time.perf_counter()
             flat = self._assemble_scan_batch(queries, scan_items)
             self.wave_times["upload_ms"] += (time.perf_counter() - t0) * 1e3
         if flat is None:
-            return
-        (q_rows, q_owner, dstarts, dlens, downers, tres_i, tres_ow,
-         tship_i, tship_ow, rows) = flat
+            return None
         dev = self.to_device()
         self.sq8_stats["batches"] += 1
         with span("launch_scan", wave=wave):
             t0 = time.perf_counter()
+            with span("upload", wave=wave):
+                batch, key, sel = self._upload_scan_batch(queries, flat,
+                                                          with_sel)
             v, g, cert = topk_sq8_segmented_desc(
                 dev["vectors"], dev["quant"], dev["base_ids"],
-                dev["deleted"], queries[q_rows], q_owner, dstarts, dlens,
-                downers, tres_i, tres_ow, tship_i, rows, tship_ow, k,
-                overfetch=overfetch)
+                dev["deleted"], batch, key, k, overfetch=overfetch)
+            self.traffic["host_device_calls"] += 1
             escalated = False
-            if not self.sq8_escalate:
-                # approximate operating point: trust the rerank, never read
-                # the certificate back (no device sync on the hot path)
-                pass
-            elif bool(np.asarray(cert).all()):         # device sync
-                self.sq8_stats["certified"] += 1
-                self._sq8_bad_streak = 0
-            else:
-                # quantization noise could have pushed a true top-k candidate
-                # out of the over-fetched set: redo the whole batch exactly
-                v, g = ops.topk_segmented_desc(
-                    dev["vectors"], dev["base_ids"], dev["deleted"],
-                    queries[q_rows], q_owner, dstarts, dlens, downers,
-                    tres_i, tres_ow, tship_i, rows, tship_ow, k,
-                    metric=self.metric, accum=self.accum)
-                escalated = True
-                self.sq8_stats["escalations"] += 1
-                self._sq8_bad_streak += 1
+            # without ``sq8_escalate`` (the approximate operating point)
+            # the rerank is trusted and the certificate never read back
+            if self.sq8_escalate:
+                self.traffic["host_device_calls"] += 1
+                if bool(jax.device_get(cert).all()):      # device sync
+                    self.sq8_stats["certified"] += 1
+                    self._sq8_bad_streak = 0
+                else:
+                    # quantization noise could have pushed a true top-k
+                    # candidate out of the over-fetched set: redo the
+                    # whole batch exactly
+                    v, g = ops.topk_segmented_desc(
+                        dev["vectors"], dev["base_ids"], dev["deleted"],
+                        batch, key, k, metric=self.metric, accum=self.accum)
+                    self.traffic["host_device_calls"] += 1
+                    escalated = True
+                    self.sq8_stats["escalations"] += 1
+                    self._sq8_bad_streak += 1
             dt = time.perf_counter() - t0
             self.wave_times["launch_ms"] += dt * 1e3
         self._count_scan_pairs(flat, launches=2 if escalated else 1)
         self._observe("scan", self._scan_units(scan_items), dt)
-        li = len(launches)
-        launches.append((v, g))
-        for row, r in enumerate(q_rows):
-            dev_parts[r].append((li, row))
+        self._emit_scan(flat[0], v, g, launches, dev_parts)
+        return sel
 
     # ---- graph states ------------------------------------------------- #
 
@@ -1340,6 +1408,7 @@ class PackedRuntime:
             # legacy per-state launches (parity oracle for the fused path)
             al = (jnp.asarray(compose_mask(None)) if bitmap_tombs
                   else None)
+            self.traffic["host_device_calls"] += bitmap_tombs
             for u, reqs in graph_shared.items():
                 h = dev["graphs"][u]
                 d, i = hnsw_search_batch(
@@ -1349,6 +1418,7 @@ class PackedRuntime:
                     metric=self.metric, allowed=al)
                 ops.record_launch(
                     "graph_state", (u, len(reqs), kk, ef_cap, bitmap_tombs))
+                self.traffic["host_device_calls"] += 2
                 emit(d, i, reqs)
             for u, allowed, reqs in graph_filtered:
                 h = dev["graphs"][u]
@@ -1362,6 +1432,7 @@ class PackedRuntime:
                               time.perf_counter() - t0)
                 ops.record_launch(
                     "graph_state_filt", (u, len(reqs), k, ef_cap))
+                self.traffic["host_device_calls"] += 3
                 emit(d, i, reqs)
             return
 
@@ -1412,7 +1483,8 @@ class PackedRuntime:
                               (bkey, p_pad, kk, ef_cap, self.metric))
             self.traffic["query_bytes"] += p_pad * (d_dim * 4 + 4)
             self.traffic["bytes_to_device"] += p_pad * (d_dim * 4 + 4)
-            emit(d[:p], i[:p], reqs)
+            self.traffic["host_device_calls"] += 3
+            emit(d, i, reqs)          # rows past p: padding, never selected
         for bkey, fr in filt.items():
             b = dev["graph_buckets"][bkey]
             p = len(fr["reqs"])
@@ -1440,7 +1512,8 @@ class PackedRuntime:
             self.traffic["query_bytes"] += p_pad * (d_dim * 4 + 4)
             self.traffic["bytes_to_device"] += (mn_pad * dn
                                                 + p_pad * (d_dim * 4 + 4))
-            emit(d[:p], i[:p], fr["reqs"])
+            self.traffic["host_device_calls"] += 5
+            emit(d, i, fr["reqs"])
 
     # ---- residual verification (strategy c) --------------------------- #
 

@@ -26,6 +26,7 @@ planning integers, and the post-watermark delta tail do.
 from __future__ import annotations
 
 import functools
+import itertools
 
 import jax
 import jax.numpy as jnp
@@ -269,19 +270,44 @@ def expand_descriptors(base_ids: jax.Array, starts: jax.Array,
     return cand, own
 
 
-@functools.partial(jax.jit, static_argnames=("k", "n_desc", "metric",
-                                             "block_q", "block_n",
+def packed_int_sizes(key: tuple) -> tuple:
+    """Lengths of the int32 buffer's parts for a descriptor launch with
+    bucket key ``(qp, n_desc, tr, ts, dp, d)``, in order: qseg (qp),
+    descriptor starts, lens and owners (dp each), the resident tail's
+    ids and owners (tr each) and the shipped tail's (ts each).  The
+    float32 buffer is (qp + ts, d): the query rows, then the shipped
+    tail's rows.  ``ops.pad_descriptor_batch`` packs this layout and
+    ``unpack_descriptor_batch`` slices it."""
+    qp, _, tr, ts, dp, _ = key
+    return (qp, dp, dp, dp, tr, tr, ts, ts)
+
+
+def unpack_descriptor_batch(floats: jax.Array, ints: jax.Array,
+                            key: tuple) -> tuple:
+    """The inputs of a descriptor launch out of the two buffers one
+    upload ships, sliced at the static offsets of its bucket key
+    (``packed_int_sizes``).  Returns ``(x, qseg, starts, lens, owners,
+    tail_res_ids, tail_res_owners, tail_ship_ids, tail_ship_owners,
+    tail_ship_rows)``, in ``distance_topk_descriptors``'s order."""
+    qp = key[0]
+    ends = list(itertools.accumulate(packed_int_sizes(key)))
+    parts = [ints[a:b] for a, b in zip([0] + ends[:-1], ends)]
+    return (floats[:qp], parts[0].reshape(qp, 1), *parts[1:],
+            floats[qp:])
+
+
+@functools.partial(jax.jit, static_argnames=("k", "n_desc", "packed",
+                                             "metric", "block_q", "block_n",
                                              "interpret", "accum", "impl"))
 def distance_topk_descriptors(vectors: jax.Array, base_ids: jax.Array,
                               deleted: jax.Array, x: jax.Array,
-                              qseg: jax.Array, starts: jax.Array,
-                              lens: jax.Array, owners: jax.Array,
-                              tail_res_ids: jax.Array,
-                              tail_res_owners: jax.Array,
-                              tail_ship_ids: jax.Array,
-                              tail_ship_owners: jax.Array,
-                              tail_ship_rows: jax.Array, k: int, *,
-                              n_desc: int, metric: str = "l2",
+                              qseg: jax.Array, starts=None, lens=None,
+                              owners=None, tail_res_ids=None,
+                              tail_res_owners=None, tail_ship_ids=None,
+                              tail_ship_owners=None, tail_ship_rows=None,
+                              k: int = 0, *, n_desc: int,
+                              packed: tuple | None = None,
+                              metric: str = "l2",
                               block_q: int = BLOCK_Q,
                               block_n: int = BLOCK_N,
                               interpret: bool = False,
@@ -306,9 +332,17 @@ def distance_topk_descriptors(vectors: jax.Array, base_ids: jax.Array,
     are tombstoned get the unmatchable owner -3 in-kernel; shipped-tail
     tombstones must be filtered host-side by the caller.
 
-    Returns ``(vals, gids)`` of shape (Q, k): distances ascending and
-    GLOBAL candidate ids (-1/+inf padding) — no flat-position indices
-    escape, so callers never map back through a host candidate array.
+    The inputs come as the ten arrays, or packed: with ``packed`` (the
+    launch's bucket key) ``x`` is the float buffer and ``qseg`` the int
+    buffer of ``ops.pad_descriptor_batch``, sliced apart here
+    (``unpack_descriptor_batch``), and the arrays between are left out.
+
+    Returns ``(vals, gids)`` of shape (Q, k), one row per (padded) query
+    row: distances ascending and GLOBAL candidate ids, with every
+    unfilled slot (+inf, -1) — no flat-position indices escape, so
+    callers never map back through a host candidate array, and nothing
+    is left to fix up outside the program.  The top-k core runs at k
+    rounded up to 8 and keeps the first k.
 
     ``impl="xla"`` swaps the Pallas core for the dense jnp segmented
     sweep (``segmented_dense_topk``) — the XLA-compiled twin used where
@@ -317,21 +351,28 @@ def distance_topk_descriptors(vectors: jax.Array, base_ids: jax.Array,
     top-k schedule.
     """
     with jax.named_scope("vm/scan"):
+        if packed is not None:
+            (x, qseg, starts, lens, owners, tail_res_ids, tail_res_owners,
+             tail_ship_ids, tail_ship_owners,
+             tail_ship_rows) = unpack_descriptor_batch(x, qseg, packed)
         y, cseg, gid_flat = assemble_flat_candidates(
             vectors, base_ids, deleted, starts, lens, owners, tail_res_ids,
             tail_res_owners, tail_ship_ids, tail_ship_owners, tail_ship_rows,
             n_desc)
         n = int(y.shape[0])
+        kp = -(-k // 8) * 8
         if impl == "xla":
-            vals, idx = segmented_dense_topk(x, y, qseg[:, 0], cseg, k,
+            vals, idx = segmented_dense_topk(x, y, qseg[:, 0], cseg, kp,
                                              metric=metric)
         else:
             vals, idx = _seg_pallas_call(
-                x, y, qseg, cseg.reshape(1, n), k, metric=metric,
+                x, y, qseg, cseg.reshape(1, n), kp, metric=metric,
                 block_q=block_q, block_n=block_n, interpret=interpret,
                 valid_n=n, accum=accum)
+        vals, idx = vals[:, :k], idx[:, :k]
         gids = jnp.where(idx >= 0, gid_flat[jnp.clip(idx, 0, n - 1)], -1)
-        return vals, gids
+        bad = (gids < 0) | ~jnp.isfinite(vals)
+        return jnp.where(bad, jnp.inf, vals), jnp.where(bad, -1, gids)
 
 
 def assemble_flat_candidates(vectors, base_ids, deleted, starts, lens,

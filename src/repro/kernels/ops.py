@@ -33,7 +33,8 @@ import numpy as np
 
 from . import ref
 from .distance_topk import (distance_topk, distance_topk_descriptors,
-                            distance_topk_segmented, segmented_dense_topk)
+                            distance_topk_segmented, packed_int_sizes,
+                            segmented_dense_topk)
 from .pairwise import pairwise_distance
 from .tuning import default_impl, default_interpret, select_tiles
 
@@ -216,14 +217,7 @@ def topk_segmented(x: jax.Array, y: jax.Array, qseg: jax.Array,
 
 
 def topk_segmented_desc(vectors: jax.Array, base_ids: jax.Array,
-                        deleted: jax.Array, x: np.ndarray,
-                        qseg: np.ndarray, desc_starts: np.ndarray,
-                        desc_lens: np.ndarray, desc_owners: np.ndarray,
-                        tail_res_ids: np.ndarray,
-                        tail_res_owners: np.ndarray,
-                        tail_ship_ids: np.ndarray,
-                        tail_ship_rows: np.ndarray,
-                        tail_ship_owners: np.ndarray, k: int, *,
+                        deleted: jax.Array, batch, key: Tuple, k: int, *,
                         metric: str = "l2",
                         interpret: bool | None = None,
                         accum: str = "f32", impl: str | None = None
@@ -232,28 +226,28 @@ def topk_segmented_desc(vectors: jax.Array, base_ids: jax.Array,
     (query, id-set) pairs whose frozen-base candidates are ``(seg_start,
     seg_len, owner)`` triples resolved against the device-resident CSR.
 
-    Host→device traffic is the query matrix plus planning integers (the
-    descriptor triples, owner ids, and tail id lists); candidate rows for
-    the descriptor region and the resident tail are gathered on device.
-    Only ``tail_ship_rows`` — delta inserts past the upload watermark —
-    ship vector rows, and the caller must pre-filter their tombstones.
+    ``batch`` is the ``(floats, ints)`` pair of ``pad_descriptor_batch``,
+    uploaded by the caller in one ``jax.device_put``, and ``key`` its
+    bucket key.  Host→device traffic is the query matrix plus planning
+    integers (the descriptor triples, owner ids, and tail id lists);
+    candidate rows for the descriptor region and the resident tail are
+    gathered on device.  Only the shipped tail's rows — delta inserts
+    past the upload watermark — ship vector rows, and the caller must
+    pre-filter their tombstones.
 
     Every dynamic dimension is padded to a power-of-two bucket (``bucket``)
     so repeated batches of similar size reuse one compiled executable.
-    Returns DEVICE arrays ``(vals, gids)`` of shape (Q, k): ascending
-    distances + global candidate ids, (+inf, -1) padding.
+    Returns DEVICE arrays ``(vals, gids)`` of shape (qp, k), one row per
+    padded query row (the caller keeps the first Q): ascending distances
+    + global candidate ids, (+inf, -1) padding.
     """
     if interpret is None:
         interpret = default_interpret()
     if impl is None:
         impl = default_impl()
-    q = x.shape[0]
     kp = _round_up(k, 8)
     if kp > _LANE:
         raise ValueError(f"k={k} exceeds kernel max {_LANE}")
-    args, key = pad_descriptor_batch(
-        x, qseg, desc_starts, desc_lens, desc_owners, tail_res_ids,
-        tail_res_owners, tail_ship_ids, tail_ship_rows, tail_ship_owners)
     qp, n_desc, tr, ts, _, d = key
     # the flat candidate extent is fixed by the pre-bucketed regions, so
     # block_n must divide it; block_q likewise divides the padded Q
@@ -261,13 +255,11 @@ def topk_segmented_desc(vectors: jax.Array, base_ids: jax.Array,
                           itemsize=2 if accum == "bf16" else 4,
                           divisor_n=max(n_desc + tr + ts, _LANE))
     vals, gids = distance_topk_descriptors(
-        vectors, base_ids, deleted, *args, kp, n_desc=n_desc,
+        vectors, base_ids, deleted, *batch, k=k, n_desc=n_desc, packed=key,
         metric=metric, block_q=min(bq, qp), block_n=bn,
         interpret=interpret, accum=accum, impl=impl)
     record_launch("desc_scan", key + (kp, metric, impl))
-    vals, gids = vals[:q, :k], gids[:q, :k]
-    bad = (gids < 0) | ~jnp.isfinite(vals)
-    return jnp.where(bad, jnp.inf, vals), jnp.where(bad, -1, gids)
+    return vals, gids
 
 
 def descriptor_extents(q: int, desc_lens: np.ndarray, n_res: int,
@@ -302,38 +294,33 @@ def pad_descriptor_batch(x, qseg, desc_starts, desc_lens, desc_owners,
                          tail_res_ids, tail_res_owners, tail_ship_ids,
                          tail_ship_rows, tail_ship_owners):
     """Bucket-pad the host-side inputs of a descriptor launch (shared by
-    the fp32 and SQ8 wrappers).  Returns the device-ready positional args
-    ``(x, qseg, starts, lens, owners, tail_res_ids, tail_res_owners,
-    tail_ship_ids, tail_ship_owners, tail_ship_rows)`` and the shape
-    bucket key ``(qp, n_desc, tr, ts, dp, d)``."""
+    the fp32 and SQ8 scans) into the two host buffers one
+    ``jax.device_put`` ships (layout: ``distance_topk.packed_int_sizes``):
+    ``floats``, the query rows then the shipped tail's rows, and
+    ``ints``, every planning integer end to end.  Returns ``((floats,
+    ints), key)`` with the shape bucket key ``(qp, n_desc, tr, ts, dp,
+    d)``, which fixes every offset."""
     q, d = x.shape
     qp, n_desc, tr, ts = descriptor_extents(q, desc_lens, len(tail_res_ids),
                                             len(tail_ship_ids))
-    xp = np.zeros((qp, d), np.float32)
-    xp[:q] = x
-    qsp = np.full((qp, 1), -1, np.int32)
-    qsp[:q, 0] = qseg
     dp = bucket(len(desc_starts), 8) if n_desc else 0
-
-    def _pad1(a, n, fill):
-        out = np.full(n, fill, np.int32)
-        out[:len(a)] = a
-        return out
-
     if n_desc + tr + ts == 0:
         raise ValueError("descriptor launch with no candidates")
-    rows = np.zeros((ts, d), np.float32)
-    rows[:len(tail_ship_rows)] = tail_ship_rows
-    args = (jnp.asarray(xp), jnp.asarray(qsp),
-            jnp.asarray(_pad1(desc_starts, dp, 0)),
-            jnp.asarray(_pad1(desc_lens, dp, 0)),
-            jnp.asarray(_pad1(desc_owners, dp, -3)),
-            jnp.asarray(_pad1(tail_res_ids, tr, 0)),
-            jnp.asarray(_pad1(tail_res_owners, tr, -3)),
-            jnp.asarray(_pad1(tail_ship_ids, ts, 0)),
-            jnp.asarray(_pad1(tail_ship_owners, ts, -3)),
-            jnp.asarray(rows))
-    return args, (qp, n_desc, tr, ts, dp, d)
+    key = (qp, n_desc, tr, ts, dp, d)
+    floats = np.zeros((qp + ts, d), np.float32)
+    floats[:q] = x
+    floats[qp:qp + len(tail_ship_rows)] = tail_ship_rows
+    ints = np.empty(sum(packed_int_sizes(key)), np.int32)
+    at = 0
+    # padded query rows own -1, padded candidate slots -3: neither matches
+    for a, n, fill in zip(
+            (qseg, desc_starts, desc_lens, desc_owners, tail_res_ids,
+             tail_res_owners, tail_ship_ids, tail_ship_owners),
+            packed_int_sizes(key), (-1, 0, 0, -3, 0, -3, 0, -3)):
+        ints[at:at + len(a)] = a
+        ints[at + len(a):at + n] = fill
+        at += n
+    return (floats, ints), key
 
 
 # --------------------------------------------------------------------- #
@@ -454,11 +441,12 @@ def merge_topk_device(big_d: jax.Array, big_i: jax.Array, sel: jax.Array,
                       ) -> Tuple[jax.Array, jax.Array]:
     """Per-request merge of kernel/beam launch outputs, entirely on device.
 
-    ``big_d``/``big_i``: (T, W) stacked launch output rows (distances +
-    global ids, (-1, +inf) padding); ``sel``: (R, S) row indices into the
-    stack — request r's candidate pool is rows ``sel[r]`` flattened, in
-    the same order the host merge would concatenate them (so tie-breaks
-    are bit-identical); out-of-pool slots point at an all-padding row.
+    ``big_d``/``big_i``: (T, W) launch output rows (distances + global
+    ids, (-1, +inf) padding) — one launch's outputs as they are, or
+    several stacked; ``sel``: (R, S) row indices into them — request r's
+    candidate pool is rows ``sel[r]`` flattened, in the same order the
+    host merge would concatenate them (so tie-breaks are bit-identical);
+    out-of-pool slots hold T, the all-padding row the program appends.
     ``deleted`` is the resident tombstone mask (ids past it must be
     pre-filtered by the caller, as in the scan path).
 
@@ -469,6 +457,10 @@ def merge_topk_device(big_d: jax.Array, big_i: jax.Array, sel: jax.Array,
     oracle workload.
     """
     with jax.named_scope("vm/merge"):
+        big_d = jnp.concatenate(
+            [big_d, jnp.full((1, big_d.shape[1]), jnp.inf, big_d.dtype)])
+        big_i = jnp.concatenate(
+            [big_i, jnp.full((1, big_i.shape[1]), -1, big_i.dtype)])
         r_n, s_n = sel.shape
         d = big_d[sel].reshape(r_n, -1)
         i = big_i[sel].reshape(r_n, -1)

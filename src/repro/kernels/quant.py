@@ -39,7 +39,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from .distance_topk import (LANES, expand_descriptors, fold_topk,
-                            topk_outputs)
+                            topk_outputs, unpack_descriptor_batch)
 from .tuning import (SQ8_DIM_CAP, default_impl, default_interpret,
                      select_tiles)
 
@@ -247,15 +247,15 @@ def _sq8_dense_segmented(xq, sx, x2, yq, sy, y2, qseg_vec, cseg, k: int):
     return jnp.where(bad, jnp.inf, vals), jnp.where(bad, -1, idx)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "kq", "n_desc",
+@functools.partial(jax.jit, static_argnames=("k", "kq", "packed",
                                              "interpret", "impl"))
-def _sq8_topk_descriptors(vectors, vq, vsc, vsq, vl1, base_ids, deleted, x,
-                          qseg, starts, lens, owners, tail_res_ids,
-                          tail_res_owners, tail_ship_ids, tail_ship_owners,
-                          tail_ship_rows, k: int, kq: int, *, n_desc: int,
+def _sq8_topk_descriptors(vectors, vq, vsc, vsq, vl1, base_ids, deleted,
+                          floats, ints, k: int, kq: int, *, packed: tuple,
                           interpret: bool = False, impl: str = "pallas"):
     """Descriptor-resolved SQ8 scan + fp32 rerank + certificate: the
-    quantized analogue of ``distance_topk_descriptors``.
+    quantized analogue of ``distance_topk_descriptors``, fed the same two
+    packed buffers (``ops.pad_descriptor_batch``; ``packed`` is the
+    launch's bucket key).
 
     The candidate codes come from the RESIDENT quantized table
     ``(vq, vsc, vsq, vl1)`` uploaded once by ``to_device`` — only the
@@ -264,8 +264,13 @@ def _sq8_topk_descriptors(vectors, vq, vsc, vsq, vl1, base_ids, deleted, x,
     gather.  Returns ``(vals, gids, cert)``: exact reranked distances,
     global ids, and a per-query bool that is True iff the result provably
     equals the fp32 scan's (see module docstring); the executor escalates
-    batches with any False row."""
+    batches with any False row.  Rows are the padded query rows, unfilled
+    slots (+inf, -1), padding rows certified."""
     with jax.named_scope("vm/sq8_scan"):
+        (x, qseg, starts, lens, owners, tail_res_ids, tail_res_owners,
+         tail_ship_ids, tail_ship_owners,
+         tail_ship_rows) = unpack_descriptor_batch(floats, ints, packed)
+        n_desc = packed[1]
         # --- assemble the flat candidate layout against the int8 table -----
         if n_desc:
             dcand, down = expand_descriptors(base_ids, starts, lens, owners,
@@ -355,48 +360,42 @@ def _sq8_topk_descriptors(vectors, vq, vsc, vsq, vl1, base_ids, deleted, x,
         # margin absorbs f32 rounding of the quantized estimate; a NaN or a
         # clamped-to-zero q_kq fails the comparison and escalates safely
         margin = eps + 1e-5 * (jnp.abs(qkq) + jnp.abs(dk)) + 1e-12
-        cert = jnp.isposinf(qkq) | (dk < qkq - margin)
-        return vals, gids, cert
+        cert = jnp.isposinf(qkq) | (dk < qkq - margin) | (qseg[:, 0] < 0)
+        bad = (gids < 0) | ~jnp.isfinite(vals)
+        return (jnp.where(bad, jnp.inf, vals), jnp.where(bad, -1, gids),
+                cert)
 
 
-def topk_sq8_segmented_desc(vectors, quant, base_ids, deleted, x, qseg,
-                            desc_starts, desc_lens, desc_owners,
-                            tail_res_ids, tail_res_owners, tail_ship_ids,
-                            tail_ship_rows, tail_ship_owners, k: int, *,
-                            overfetch: int = 4,
+def topk_sq8_segmented_desc(vectors, quant, base_ids, deleted, batch, key,
+                            k: int, *, overfetch: int = 4,
                             interpret: bool | None = None,
                             impl: str | None = None):
     """Batched SQ8 executor path: ONE segmented quantized launch for every
     scan item in the batch.  ``quant`` is the resident int8 table
-    ``(vq, vsc, vsq, vl1)`` from ``to_device``.  Same descriptor/tail
-    contract and shape bucketing as ``ops.topk_segmented_desc``;
+    ``(vq, vsc, vsq, vl1)`` from ``to_device``; ``batch`` the uploaded
+    ``(floats, ints)`` buffers of ``ops.pad_descriptor_batch`` and
+    ``key`` their bucket key, as ``ops.topk_segmented_desc`` takes them.
     ``k·overfetch`` beyond the 128-lane scratch budget raises like the
-    unsegmented wrapper.  Returns ``(vals, gids, cert)`` — see
-    ``_sq8_topk_descriptors``."""
-    from .ops import _round_up, pad_descriptor_batch, record_launch
+    unsegmented wrapper.  Returns padded-row device arrays ``(vals, gids,
+    cert)`` — see ``_sq8_topk_descriptors``."""
+    from .ops import _round_up, record_launch
     if interpret is None:
         interpret = default_interpret()
     if impl is None:
         impl = default_impl()
-    q = x.shape[0]
     kq = max(k * overfetch, k)
     if kq > 128:
         raise ValueError(
             f"k*overfetch={kq} exceeds the quantized kernel's 128-lane "
             f"scratch budget (k={k}, overfetch={overfetch}); lower k or "
             f"overfetch (the executor clamps overfetch to 128//k)")
-    args, key = pad_descriptor_batch(
-        x, qseg, desc_starts, desc_lens, desc_owners, tail_res_ids,
-        tail_res_owners, tail_ship_ids, tail_ship_rows, tail_ship_owners)
     kqp = min(_round_up(kq, 8), 128)
     vq, vsc, vsq, vl1 = quant
-    vals, gids, cert = _sq8_topk_descriptors(
-        vectors, vq, vsc, vsq, vl1, base_ids, deleted, *args, k, kqp,
-        n_desc=key[1], interpret=interpret, impl=impl)
+    out = _sq8_topk_descriptors(
+        vectors, vq, vsc, vsq, vl1, base_ids, deleted, *batch, k, kqp,
+        packed=key, interpret=interpret, impl=impl)
     record_launch("sq8_scan", key + (k, kqp, impl))
-    vals, gids, cert = vals[:q], gids[:q], cert[:q]
-    bad = (gids < 0) | ~jnp.isfinite(vals)
-    return jnp.where(bad, jnp.inf, vals), jnp.where(bad, -1, gids), cert
+    return out
 
 
 # --------------------------------------------------------------------- #

@@ -101,6 +101,46 @@ def test_descriptor_vs_materialized_parity(dataset):
     _assert_identical(res_desc, res_mat, "desc-vs-materialized")
 
 
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_descriptor_scan_padded_output_contract(impl):
+    """``distance_topk_descriptors`` fed the packed upload returns one
+    (padded query row, k) row per bucketed query row with every unfilled
+    slot already (+inf, -1): padding rows, a segment smaller than k (one
+    of its rows tombstoned) and an owner with no candidates; real rows
+    rank exactly their own live candidates."""
+    import jax
+    rng = np.random.default_rng(31)
+    n = 300
+    vecs = rng.standard_normal((n, DIM)).astype(np.float32)
+    base_ids = rng.permutation(n).astype(np.int32)
+    deleted = np.zeros(n, bool)
+    deleted[base_ids[[3, 51]]] = True
+    x = rng.standard_normal((3, DIM)).astype(np.float32)
+    qseg = np.array([0, 1, 2], np.int32)
+    starts, lens, owners = (np.array(a, np.int32) for a in
+                            ([0, 50], [50, 4], [0, 1]))
+    none = np.zeros(0, np.int32)
+    host, key = ops.pad_descriptor_batch(
+        x, qseg, starts, lens, owners, none, none, none,
+        np.zeros((0, DIM), np.float32), none)
+    vals, gids = jax.device_get(ops.topk_segmented_desc(
+        jax.device_put(vecs), jax.device_put(base_ids),
+        jax.device_put(deleted), jax.device_put(host), key, K, impl=impl))
+    assert vals.shape == gids.shape == (key[0], K) == (128, K)
+    assert np.array_equal(gids == -1, np.isposinf(vals))
+    assert (gids[2:] == -1).all()                # no candidates, padding
+    for row, (lo, ln) in enumerate(zip(starts, lens)):
+        cand = base_ids[lo:lo + ln]
+        cand = cand[~deleted[cand]]
+        dist = ((vecs[cand] - x[row]) ** 2).sum(1)
+        want = cand[np.argsort(dist, kind="stable")[:K]]
+        live = min(K, len(cand))
+        assert gids[row, :live].tolist() == want.tolist()
+        assert (gids[row, live:] == -1).all()
+        np.testing.assert_allclose(vals[row, :live], np.sort(dist)[:K],
+                                   rtol=1e-5, atol=1e-5)
+
+
 def test_delta_tail_ships_rows_per_batch(dataset):
     """Inserts past the upload watermark ship ids + rows per batch (the
     bounded delta tail) while the frozen cover stays descriptor-resolved;
@@ -210,6 +250,91 @@ def test_device_merge_matches_host_merge_under_churn(dataset):
     vm.runtime.device_merge = False
     res_host = vm.query_batch(q, preds, K)
     _assert_identical(res_dev, res_host, "device-vs-host-merge")
+
+
+def _uncertified(monkeypatch):
+    """Every SQ8 certificate fails, so each batch escalates to fp32."""
+    from repro.kernels import quant
+    real = quant.topk_sq8_segmented_desc
+
+    def uncertified(*args, **kwargs):
+        v, g, cert = real(*args, **kwargs)
+        return v, g, cert & False
+    monkeypatch.setattr(quant, "topk_sq8_segmented_desc", uncertified)
+
+
+# (predicates, VectorMatonConfig overrides, what is done to the index
+# first): one upload, the scan and merge launches and one download per
+# scan-only wave, against the NumPy merge of the same launch rows
+DISPATCH_CASES = {
+    "one_request": (["ab"], {}, None),
+    "two_requests": (["a", "cd"], {}, None),
+    "or_disjuncts": (["a OR cd", "ab OR ba OR dd"], {}, None),
+    "tombstones": (["a", "ab OR cd"], {}, "delete"),
+    "delta_tail": (["ab", "a OR cd"], {"auto_compact": False}, "insert"),
+    "sq8_certified": (["a", "ab OR cd"], {"quantize": "sq8"}, None),
+    "sq8_escalated": (["a", "ab OR cd"], {"quantize": "sq8"}, "uncertify"),
+    "graph_and_scan": (["a", "abc", "ab OR cd"], {"T": 5}, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DISPATCH_CASES))
+def test_consolidated_dispatch_matches_host_merge(dataset, monkeypatch,
+                                                  case):
+    """The dispatch that ships a wave's inputs in one upload, cuts and
+    cleans the scan's rows inside its program and hands a lone launch
+    straight to the merge answers bit-for-bit as the NumPy host merge
+    (``device_merge=False``), on waves of one and two requests, OR
+    disjuncts, tombstones, a shipped delta tail, certified and
+    escalated SQ8 batches, and a graph + scan multi-launch wave."""
+    preds, cfg, prep = DISPATCH_CASES[case]
+    vm = _vm(dataset, **{"T": 10 ** 9, **cfg})
+    vm.runtime.to_device()
+    rng = np.random.default_rng(21)
+    if prep == "delete":
+        for v in (2, 5, 11, 40, 41):
+            vm.delete(v)
+    elif prep == "insert":
+        for s in ("abab", "cdab", "bab"):
+            vm.insert(rng.standard_normal(DIM).astype(np.float32), s)
+    elif prep == "uncertify":
+        _uncertified(monkeypatch)
+    q = _queries(len(preds), 12)
+    stats0 = vm.maintenance_stats()
+    res_dev = vm.query_batch(q, preds, K)
+    stats1 = vm.maintenance_stats()
+    vm.runtime.device_merge = False
+    res_host = vm.query_batch(q, preds, K)
+    _assert_identical(res_dev, res_host, case)
+    if prep == "insert":
+        assert stats1["traffic_row_bytes"] > stats0["traffic_row_bytes"]
+    if cfg.get("quantize") == "sq8":
+        got = "escalations" if prep == "uncertify" else "certified"
+        assert stats1[f"sq8_{got}"] == stats0[f"sq8_{got}"] + 1
+    if case == "graph_and_scan":
+        kinds = vm.plan(preds).strategies
+        assert vm.stats()["hnsw_states"] > 0 and kinds["chain"] > 0
+        assert stats1["launch_graph_fused"] > stats0.get(
+            "launch_graph_fused", 0)
+
+
+@pytest.mark.parametrize("quantize,calls", [("none", 4), ("sq8", 5)])
+def test_scan_only_wave_host_device_calls(dataset, quantize, calls):
+    """A scan-only wave crosses between host and device at most five
+    times (``traffic_host_device_calls``): one upload, the scan launch,
+    the merge launch, one download, and with SQ8 the certificate's read
+    back."""
+    vm = _vm(dataset, T=10 ** 9, quantize=quantize)
+    preds = ["a", "ab OR cd"]
+    vm.query_batch(_queries(2, 13), preds, K)        # index upload
+    before = vm.maintenance_stats()
+    vm.query_batch(_queries(2, 14), preds, K)
+    after = vm.maintenance_stats()
+    assert after["sq8_certified"] == before["sq8_certified"] + (
+        quantize == "sq8")
+    got = (after["traffic_host_device_calls"]
+           - before["traffic_host_device_calls"])
+    assert got == calls <= 5
 
 
 def test_residual_predicates_fall_back_to_host_merge(dataset):

@@ -28,12 +28,13 @@ N = 1_048_576            # phase A table rows (SIFT1M shape)
 D = 128
 QP = 128                 # query rows of one 64-request wave, bucketed
 K = 10
-KP = 16                  # k rounded to 8, as ops.topk_segmented_desc does
+KP = 16                  # k rounded to 8, as the scan program runs it
 KQ = 40                  # SQ8 over-fetch k·4, rounded to 8
 N_DESC = 2 ** 21         # bucketed descriptor region of one wave
 TR = TS = 1024           # resident / shipped delta tails after the writes
 DP = 64                  # bucketed descriptor count
 CSR = 4 * N              # resident CSR base_ids
+KEY = (QP, N_DESC, TR, TS, DP, D)   # the packed batch's bucket key
 
 
 @pytest.fixture(scope="module")
@@ -65,13 +66,10 @@ def _sds(shape, dtype, sharding):
 
 
 def _desc_args(sh):
-    """Shapes of the descriptor batch ``ops.pad_descriptor_batch`` emits."""
-    i32 = jnp.int32
-    return (_sds((QP, D), jnp.float32, sh), _sds((QP, 1), i32, sh),
-            _sds((DP,), i32, sh), _sds((DP,), i32, sh),
-            _sds((DP,), i32, sh), _sds((TR,), i32, sh),
-            _sds((TR,), i32, sh), _sds((TS,), i32, sh),
-            _sds((TS,), i32, sh), _sds((TS, D), jnp.float32, sh))
+    """Shapes of the two buffers ``ops.pad_descriptor_batch`` packs a
+    descriptor batch into: query and shipped rows, planning integers."""
+    return (_sds((QP + TS, D), jnp.float32, sh),
+            _sds((QP + 3 * DP + 2 * TR + 2 * TS,), jnp.int32, sh))
 
 
 def _resident(sh):
@@ -84,8 +82,8 @@ def test_descriptor_scan_pallas_compiles(one_chip):
     n_flat = N_DESC + TR + TS
     bq, bn = select_tiles(QP, n_flat, D, k=KP, divisor_n=n_flat)
     compiled = distance_topk_descriptors.lower(
-        *_resident(one_chip), *_desc_args(one_chip), KP, n_desc=N_DESC,
-        block_q=min(bq, QP), block_n=bn, interpret=False,
+        *_resident(one_chip), *_desc_args(one_chip), k=K, n_desc=N_DESC,
+        packed=KEY, block_q=min(bq, QP), block_n=bn, interpret=False,
         impl="pallas").compile()
     assert "tpu_custom_call" in compiled.as_text()
 
@@ -97,7 +95,7 @@ def test_sq8_descriptor_scan_pallas_compiles(one_chip):
              *(_sds((N, 1), jnp.float32, one_chip) for _ in range(3)))
     compiled = _sq8_topk_descriptors.lower(
         vecs, *quant, base_ids, deleted, *_desc_args(one_chip), K, KQ,
-        n_desc=N_DESC, interpret=False, impl="pallas").compile()
+        packed=KEY, interpret=False, impl="pallas").compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
