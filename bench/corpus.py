@@ -1,5 +1,17 @@
 """The configurations' data, made from ``--seed``.
 
+A configuration's rows and queries come from its corpus module,
+``bench/corpora/<name>.py``, named by the configuration's ``"corpus"``
+key (``source``).  Such a module defines
+
+- ``rows(n, seed, cfg)``: float32 ``(n, cfg["dim"])`` vectors and a list
+  of ``n`` sequence strings, one per row;
+- ``queries(count, seed, cfg, stream)``: float32 ``(count, dim)`` query
+  vectors, on a seed stream of their own per ``stream``.
+
+A configuration without the key gets ``corpora/labels.py``: ACORN's
+label rule over the generators here.
+
 Vectors: a copy of the scale-corpus rule of ``repro.data.corpora``
 (``stream_scale_vectors``), kept here so that a change to the program's
 data module cannot move the yardstick: seeded clustered Gaussians (256
@@ -15,6 +27,9 @@ from __future__ import annotations
 
 import numpy as np
 
+from bench import load_module
+
+DEFAULT = "labels"
 _KNUTH = np.uint64(2654435761)
 _PHI32 = np.uint64(0x9E3779B9)
 _MASK32 = np.uint64(0xFFFFFFFF)
@@ -22,9 +37,30 @@ BLOCK = 8192
 N_CENTRES = 256
 
 
+def source(cfg: dict):
+    """The configuration's corpus module."""
+    return load_module("corpora", cfg.get("corpus", DEFAULT))
+
+
+def rows(cfg: dict, n: int, seed: int):
+    """The configuration's ``n`` rows from the seed: (vectors, sequences);
+    a corpus module that gives other shapes raises ``ValueError``."""
+    vecs, seqs = source(cfg).rows(n, seed, cfg)
+    if (vecs.dtype != np.float32 or vecs.shape != (n, cfg["dim"])
+            or len(seqs) != n):
+        raise ValueError(f"corpus {cfg.get('corpus', DEFAULT)!r} gave "
+                         f"{vecs.dtype} {vecs.shape} vectors and "
+                         f"{len(seqs)} sequences for {n} x {cfg['dim']}")
+    return vecs, seqs
+
+
 def labels(n: int, seed: int, count: int) -> np.ndarray:
     """Per row, the index of its label, uniform over ``count`` values, on
-    a seed stream apart from the vectors'."""
+    a seed stream apart from the vectors'; more than 256 values, which
+    the codes' ``uint8`` cannot hold, raise ``ValueError``."""
+    if not 0 < count <= 256:
+        raise ValueError(f"{count} labels: a label code is one byte, "
+                         "so 1 to 256 labels")
     rng = np.random.default_rng(np.random.SeedSequence([seed, 0x1AB]))
     return rng.integers(0, count, n).astype(np.uint8)
 

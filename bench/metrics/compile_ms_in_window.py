@@ -1,5 +1,6 @@
-"""Milliseconds the process spent lowering and compiling programs inside
-the window (``jit_compile_ms``); 0 once warm-up covered every shape.
+"""Milliseconds the process spent lowering and compiling programs from
+the window's first send to its last answer (``jit_compile_ms``); 0 once
+warm-up covered every shape.
 Silent for a program without the counter."""
 
 

@@ -1,7 +1,7 @@
-"""Requests answered in the window per wave the pipeline dispatched
-(``pipeline_waves``)."""
+"""Requests of the window answered per wave the pipeline dispatched to
+answer them (``pipeline_waves``)."""
 
 
 def read(run):
     waves = run.counter("pipeline_waves")
-    return run.completed_in_window / waves if waves > 0 else None
+    return run.answered / waves if waves > 0 else None

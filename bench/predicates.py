@@ -55,21 +55,21 @@ def compile_text(text: str):
         pos += 1
         return toks[pos - 1]
 
+    # chained closures, not any()/all() over a generator: a per-row
+    # corpus evaluates each predicate once per row
     def disj():
-        parts = [conj()]
+        fn = conj()
         while peek() == ("kw", "OR"):
             take()
-            parts.append(conj())
-        return parts[0] if len(parts) == 1 else (
-            lambda s, ps=parts: any(p(s) for p in ps))
+            fn = (lambda a, b: lambda s: a(s) or b(s))(fn, conj())
+        return fn
 
     def conj():
-        parts = [unary()]
+        fn = unary()
         while peek() == ("kw", "AND"):
             take()
-            parts.append(unary())
-        return parts[0] if len(parts) == 1 else (
-            lambda s, ps=parts: all(p(s) for p in ps))
+            fn = (lambda a, b: lambda s: a(s) and b(s))(fn, unary())
+        return fn
 
     def unary():
         kind, val = take()
@@ -99,8 +99,19 @@ def compile_text(text: str):
     return fn
 
 
-def code_table(text: str, vocabulary) -> np.ndarray:
-    """Whether each sequence of the vocabulary satisfies the predicate: a
-    row's membership is ``table[codes[row]]``."""
-    fn = compile_text(text)
-    return np.asarray([fn(s) for s in vocabulary], bool)
+def members(texts, sequences) -> dict:
+    """``{text: sorted int64 ids of the rows whose sequence satisfies
+    it}`` for each predicate text, over one sequence string per row.
+    Each predicate is evaluated once per distinct sequence and mapped
+    back to the rows, so a corpus of a few labels costs a few
+    evaluations and a corpus of rows of their own one per row."""
+    index: dict = {}
+    codes = np.fromiter((index.setdefault(s, len(index)) for s in sequences),
+                        np.int64, len(sequences))
+    distinct = list(index)
+    out = {}
+    for text in texts:
+        hit = np.fromiter(map(compile_text(text), distinct), bool,
+                          len(distinct))
+        out[text] = np.flatnonzero(hit[codes]).astype(np.int64, copy=False)
+    return out

@@ -19,16 +19,18 @@ MARGIN = 32
 
 
 class Reference:
-    def __init__(self, vecs: np.ndarray, codes: np.ndarray, metric: str,
-                 tables: Dict[str, np.ndarray]) -> None:
+    """``members``: per predicate text, the sorted ids of the rows that
+    satisfy it (``predicates.members``)."""
+
+    def __init__(self, vecs: np.ndarray, metric: str,
+                 members: Dict[str, np.ndarray]) -> None:
         self.vecs = vecs
-        self.codes = codes
         self.metric = metric
-        self.tables = tables            # predicate -> (32,) bool
+        self._members = members
         self.y2 = np.einsum("nd,nd->n", vecs, vecs, dtype=np.float64)
 
     def members(self, pattern: str) -> np.ndarray:
-        return np.flatnonzero(self.tables[pattern][self.codes])
+        return self._members[pattern]
 
     def exact(self, q: np.ndarray, ids: np.ndarray) -> np.ndarray:
         y = self.vecs[ids].astype(np.float64)
@@ -101,9 +103,8 @@ def compare(ref: Reference, queries: np.ndarray, patterns: Sequence[str],
         worst = max(worst, err)
         if got.tolist() == want.tolist():
             continue
-        members = ref.tables[patterns[r]][
-            ref.codes[np.clip(got, 0, len(ref.codes) - 1)]]
-        ok = (np.all((got >= 0) & (got < len(ref.codes))) and members.all()
+        members = np.isin(got, ref.members(patterns[r]))
+        ok = (np.all((got >= 0) & (got < len(ref.vecs))) and members.all()
               and len(set(got.tolist())) == len(got)
               and np.all(np.abs(ref.exact(queries[r], got) - wd)
                          <= dist_limit * scale))

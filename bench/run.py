@@ -6,8 +6,9 @@ The cell (``BENCHMARK.json`` ``workloads``) names a configuration
 (``bench/configs/<name>.json``) and a traffic mix
 (``bench/traffic/<name>.json``).  A run:
 
-1. Set-up (``setup_s``): makes the configuration's rows from the seed,
-   builds ``RetrievalEngine`` with the device executor, keeps JAX's
+1. Set-up (``setup_s``): makes the configuration's rows from the seed
+   (its corpus module, ``bench/corpora/<name>.py``), builds
+   ``RetrievalEngine`` with the device executor, keeps JAX's
    compilation cache at ``<checkout>/.jax_cache`` (or
    ``$JAX_COMPILATION_CACHE_DIR``), warms up with the cell's own traffic
    until a pass compiles nothing new, and reads the device memory the
@@ -18,7 +19,9 @@ The cell (``BENCHMARK.json`` ``workloads``) names a configuration
    Latency runs from each request's scheduled send to its answer.  With
    ``--trace 1`` the window is traced by the JAX profiler.
 3. Check: a sample of the window's answers, drawn from the seed, against
-   the float64 brute-force reference over the live rows.
+   the float64 brute-force reference over the live rows that satisfy
+   each predicate (``predicates.members``, evaluated on every row during
+   set-up and left out of ``setup_s``).
 
 The last line of stdout is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics`` (the cell's end-to-end metrics, or with
@@ -39,7 +42,6 @@ from __future__ import annotations
 
 import argparse
 import gc
-import importlib.util
 import json
 import os
 import shutil
@@ -54,6 +56,8 @@ if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
 import numpy as np  # noqa: E402
+
+from bench import load_module  # noqa: E402
 
 REHEARSE_ROWS = 2048
 SAMPLE = 400                 # answers checked per run
@@ -87,16 +91,6 @@ class CompileClock:
             self.lowered += 1
         if event in (self.LOWER, self.COMPILE):
             self.seconds += duration
-
-
-def load_module(kind: str, name: str):
-    """``bench/<kind>/<name>.py`` as a module."""
-    path = ROOT / "bench" / kind / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_{kind}_{name}".replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 class RunRecord:
@@ -140,8 +134,7 @@ def warm_bursts(batcher, mix, cfg, seed, k, sizes, cap):
     from bench import corpus
     from repro.serve.engine import Request
     preds = mix["predicates"]
-    vecs = corpus.queries(batcher.max_wave, cfg["dim"], seed,
-                          cfg["normalize"], stream=1)
+    vecs = corpus.source(cfg).queries(batcher.max_wave, seed, cfg, stream=1)
     cheapest = min(preds, key=lambda p: sizes[p])
     for p in preds:
         for q in range(1, (cap if p == cheapest else min(BURST, cap)) + 1):
@@ -178,14 +171,13 @@ def warm_passes(server, mix, cfg, seed, k, clock, rate, seconds):
 
 def set_up(cfg, mix, seed, seconds, n, accum, clock, dev):
     """Rows from the seed, the engine and its batcher, warm-up, and the
-    device bytes the index holds."""
+    device bytes the index holds.  ``check_s`` is the seconds spent on
+    the members of each predicate, the check's work and no set-up."""
     from bench import corpus, predicates, serving
     from repro.core.vectormaton import VectorMatonConfig
     k = int(cfg["k"])
     t = time.perf_counter()
-    codes = corpus.labels(n, seed, len(cfg["labels"]))
-    vecs = corpus.vectors(n, cfg["dim"], seed, cfg["normalize"])
-    seqs = corpus.sequences(codes, cfg["labels"])
+    vecs, seqs = corpus.rows(cfg, n, seed)
     log(f"set-up: {n} x {cfg['dim']} rows made in "
         f"{time.perf_counter() - t:.3f} s")
     mem0 = (dev.memory_stats() or {}).get("bytes_in_use", 0)
@@ -194,10 +186,12 @@ def set_up(cfg, mix, seed, seconds, n, accum, clock, dev):
         T=int(cfg["T"]), metric=cfg["metric"], backend="jax",
         quantize=cfg["quantize"], accum=accum, plan_mode=cfg["plan_mode"]))
     log(f"set-up: index built in {time.perf_counter() - t:.3f} s")
-    tables = {p: predicates.code_table(p, cfg["labels"])
-              for p in mix["predicates"]}
-    per_code = np.bincount(codes, minlength=len(cfg["labels"]))
-    sizes = {p: int(per_code[tab].sum()) for p, tab in tables.items()}
+    t = time.perf_counter()
+    members = predicates.members(mix["predicates"], seqs)
+    check_s = time.perf_counter() - t
+    log(f"check: members of {len(members)} predicates over {n} rows in "
+        f"{check_s:.3f} s, kept out of set-up")
+    sizes = {p: len(ids) for p, ids in members.items()}
     batcher = serving.RecordingBatcher(engine)
     rate = float(mix["rate_per_s"])
     t, c = time.perf_counter(), clock.seconds
@@ -214,15 +208,19 @@ def set_up(cfg, mix, seed, seconds, n, accum, clock, dev):
                       for s in engine.index.compile(p).sources)
                for p in mix["predicates"]}
     return SimpleNamespace(engine=engine, batcher=batcher, server=server,
-                           codes=codes, vecs=vecs, tables=tables,
-                           sizes=sizes, scanned=scanned, n=n,
-                           index_bytes=index_bytes)
+                           vecs=vecs, members=members, sizes=sizes,
+                           scanned=scanned, n=n, index_bytes=index_bytes,
+                           check_s=check_s)
 
 
 def serve_window(st, sched, seconds, k, clock, trace_dir=None):
     """Sends ``sched`` open loop for ``seconds`` and waits for the
-    answers; the counters are read at the window's edges, and with
-    ``trace_dir`` the window is traced."""
+    answers; with ``trace_dir`` the window is traced.  The counters are
+    read before the first send and after the last answer, with no wave
+    in flight: a wave counts its launches before the pipeline counts
+    the wave, and its download after, so a reading at the window's
+    close could split one.  ``waves`` are those admitted inside the
+    window, the trace's interval."""
     import jax
     from bench import serving
     lowered0 = clock.lowered
@@ -235,15 +233,15 @@ def serve_window(st, sched, seconds, k, clock, trace_dir=None):
     loop.start(t0)
     t_end = t0 + seconds
     time.sleep(max(0.0, t_end - time.perf_counter()))
-    counters1 = st.engine.maintenance_stats()
     waves = [w for tw, w in list(st.batcher.waves) if t0 <= tw < t_end]
     if trace_dir:
         jax.profiler.stop_trace()
-    lowered = clock.lowered - lowered0
     loop.join(ANSWER_WAIT_S)
     loop.wait_answered(t_end + ANSWER_WAIT_S)
+    counters1 = st.engine.maintenance_stats()
+    lowered = clock.lowered - lowered0
     log(f"window: {len(sched)} requests sent, {lowered} programs "
-        f"lowered inside the window, send lag p50 "
+        f"lowered from the first send to the last answer, send lag p50 "
         f"{np.percentile(loop.lag, 50) * 1e3:.3f} ms max "
         f"{loop.lag.max() * 1e3:.3f} ms; sq8 "
         + json.dumps({x: counters1[x] - counters0.get(x, 0)
@@ -373,7 +371,7 @@ def main(argv=None) -> int:
                 REHEARSE_ROWS if args.rehearse else int(cfg["rows"]),
                 accum="bf16" if args.control == "bf16" else cfg["accum"],
                 clock=clock, dev=dev)
-    setup_s = time.perf_counter() - t_start
+    setup_s = time.perf_counter() - t_start - st.check_s
     log(f"set-up: {setup_s:.3f} s")
     if args.rates:
         sweep(st, mix, cfg, args.seed, args.seconds,
@@ -403,7 +401,7 @@ def main(argv=None) -> int:
     run = RunRecord(
         setup_s=setup_s, seconds=args.seconds,
         latency_ms=(w.finish - w.due) * 1e3,
-        completed_in_window=int(np.sum(answered & (w.finish <= w.t_end))),
+        answered=int(answered.sum()),
         lag_ms=w.loop.lag * 1e3, counters0=w.counters0,
         counters1=w.counters1,
         waves=[[pattern_of[s] for s in wave if s in pattern_of]
@@ -431,7 +429,7 @@ def main(argv=None) -> int:
     # the program's device state goes before the reference runs
     answers = {i: st.batcher.answers[int(s)]
                for i, s in enumerate(w.loop.tickets) if answered[i]}
-    ref = reference.Reference(st.vecs, st.codes, cfg["metric"], st.tables)
+    ref = reference.Reference(st.vecs, cfg["metric"], st.members)
     del st, w
     gc.collect()
     k = int(cfg["k"])

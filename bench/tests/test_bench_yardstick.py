@@ -75,12 +75,26 @@ def test_predicate_evaluator_agrees_with_the_program_parser():
     texts |= {"NOT (a OR b)", "CONTAINS 'cz' OR LIKE 'a_c%'", "a AND b",
               "c AND NOT a", "d OR e", "LIKE '%b%d%'", "NOT LIKE '%a%'",
               "c AND NOT LIKE '%a%d%'"}
-    strings = sorted({s for c in (ROOT / "bench" / "configs").glob("*.json")
-                      for s in json.loads(c.read_text())["labels"]}
+    # each configuration's own sequences: its labels where it has them,
+    # and the rows its corpus makes
+    cfgs = [json.loads(c.read_text())
+            for c in (ROOT / "bench" / "configs").glob("*.json")]
+    strings = sorted({s for cfg in cfgs
+                      for s in [*cfg.get("labels", ()),
+                                *corpus.rows(cfg, 64, 1)[1]]}
                      | {"z", "az", "abz", "acz", "bcz", "bdz", "cez", "abcdez"})
+    got = predicates.members(texts, strings)
     for text in texts:
-        want = [parse_predicate(text).matches(s) for s in strings]
-        assert predicates.code_table(text, strings).tolist() == want, text
+        want = [r for r, s in enumerate(strings)
+                if parse_predicate(text).matches(s)]
+        assert got[text].tolist() == want, text
+
+
+@pytest.mark.parametrize("count", [0, 257, 1000])
+def test_labels_refuse_a_count_a_byte_cannot_hold(count):
+    with pytest.raises(ValueError):
+        corpus.labels(10, 1, count)
+    assert corpus.labels(5000, 1, 256).max() == 255
 
 
 def test_schedule_is_the_same_work_in_another_order():
@@ -111,13 +125,12 @@ def toy():
     rng = np.random.default_rng(0)
     n = 3000
     vocab = ["a", "b", "c", "bc"]
-    codes = corpus.labels(n, 0, len(vocab))
+    seqs = corpus.sequences(corpus.labels(n, 0, len(vocab)), vocab)
     vecs = rng.standard_normal((n, 16)).astype(np.float32)
-    tables = {p: predicates.code_table(p, vocab)
-              for p in ("a", "b AND NOT c")}
+    members = predicates.members(("a", "b AND NOT c"), seqs)
     queries = rng.standard_normal((6, 16)).astype(np.float32)
     pats = ["a", "b AND NOT c"] * 3
-    return reference.Reference(vecs, codes, "l2", tables), queries, pats
+    return reference.Reference(vecs, "l2", members), queries, pats
 
 
 def _exact(ref, queries, pats, k):
@@ -232,9 +245,12 @@ def test_reduction_of_a_recorded_chip_trace():
 def test_metric_readers_on_the_recorded_trace():
     tr = _recorded()
     waves = 20
+    # the counters, read after the last answer, also hold 3 waves
+    # dispatched after the trace stopped; the scan's time is per traced wave
     run = bench_run.RunRecord(
         trace=tr, peaks=bench_run.load_peaks("TPU v5 lite"),
-        counters0={"pipeline_waves": 0}, counters1={"pipeline_waves": waves},
+        counters0={"pipeline_waves": 0},
+        counters1={"pipeline_waves": waves + 3},
         waves=[["a"]] * waves, sizes={"a": 500_000}, scanned={"a": True},
         cfg={"dim": 128, "k": 10})
     idle = bench_run.load_module("metrics", "device_idle_share").read(run)
@@ -254,6 +270,8 @@ def test_benchmark_json_names_a_file_for_every_entry():
     for c in spec["configs"]:
         cfg = json.loads((ROOT / c["file"]).read_text())
         assert set(c["reduced"]) <= set(cfg["reduced"]) and cfg["check"]
+        src = corpus.source(cfg)
+        assert callable(src.rows) and callable(src.queries)
     for w in spec["workloads"]:
         traffic.load(w["traffic"])
     for m in spec["end_to_end"] + spec["per_layer"]:

@@ -46,5 +46,5 @@ def schedule(mix: dict, cfg: dict, seed: int, seconds: float,
     full, rest = divmod(n, len(preds))
     pats = np.concatenate([rng.permutation(len(preds)) for _ in range(full)]
                           + [rng.permutation(rest)]).astype(np.int64)
-    vecs = corpus.queries(n, cfg["dim"], seed, cfg["normalize"], stream)
+    vecs = corpus.source(cfg).queries(n, seed, cfg, stream)
     return [(float(offsets[i]), vecs[i], preds[pats[i]]) for i in range(n)]
