@@ -203,13 +203,13 @@ def run_point(n: int, dim: int, n_queries: int = 64, waves: int = 4,
     # fp32 scan ids bit-for-bit at any scale (the adaptive fallback may
     # kick in after SQ8_MAX_STREAK failed batches — still exact)
     rt.sq8_escalate = True
-    rt._sq8_bad_streak = 0
+    rt._sq8_bad_streak.clear()
     res_dflt = vm_answer(q_eval)
     sq8_exact = all(np.array_equal(a[1], b[1])
                     for a, b in zip(res_dflt, res_desc))
     # sharded sweep over the 8-shard host mesh
     mesh = make_host_mesh(data=8, model=1)
-    rt._sq8_bad_streak = 0
+    rt._sq8_bad_streak.clear()
 
     def sharded_answer(qw):
         snap = vm.snapshot()

@@ -22,12 +22,29 @@ search work referenced by its states runs on device (see vectormaton.py).
 
 from __future__ import annotations
 
+import itertools
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 ROOT = 0
 _NO_LINK = -1
+
+
+
+def lists_csr(lists, view=None) -> Tuple[np.ndarray, np.ndarray]:
+    """``(ptr, flat)`` of a sequence of int sequences, each read through
+    ``view`` where given (``dict.values``): row r is
+    ``flat[ptr[r]:ptr[r + 1]]``.  Rows are streamed, not gathered into
+    a list first: a million short-lived objects at once would start the
+    cyclic collector over every object of the automaton."""
+    lens = np.fromiter(map(len, lists), np.int64, len(lists))
+    ptr = np.zeros(len(lists) + 1, dtype=np.int64)
+    np.cumsum(lens, out=ptr[1:])
+    rows = lists if view is None else map(view, lists)
+    flat = np.fromiter(itertools.chain.from_iterable(rows), np.int64,
+                       int(ptr[-1]))
+    return ptr, flat
 
 
 class ESAM:
@@ -50,10 +67,9 @@ class ESAM:
         self.ids: List[List[int]] = [[]]
         self.num_sequences: int = 0
         self.total_symbols: int = 0
-        # Set by finalize():
-        self._ids_np: Optional[List[np.ndarray]] = None
-        self._topo: Optional[np.ndarray] = None
-        self._ids_frozen: List[np.ndarray] = []   # last finalize's arrays
+        # Set by finalize(), cleared by the next insert:
+        self.id_ptr: Optional[np.ndarray] = None
+        self.id_data: Optional[np.ndarray] = None
 
     # ------------------------------------------------------------------ #
     # construction
@@ -173,8 +189,8 @@ class ESAM:
         return self.state_ids(st)
 
     def state_ids(self, state: int) -> np.ndarray:
-        if self._ids_np is not None:
-            return self._ids_np[state]
+        if self.id_ptr is not None:
+            return self.id_data[self.id_ptr[state]:self.id_ptr[state + 1]]
         return np.asarray(self.ids[state], dtype=np.int64)
 
     # ------------------------------------------------------------------ #
@@ -194,60 +210,24 @@ class ESAM:
         return sum(len(x) for x in self.ids)
 
     def _invalidate(self) -> None:
-        self._ids_np = None
-        self._topo = None
+        self.id_ptr = None
+        self.id_data = None
 
     def finalize(self) -> None:
-        """Freeze ID lists to NumPy and compute a topological order of the
-        transition DAG (needed by the reverse-topo index build).  ID lists
-        only ever grow by appends, so each state's last array is reused
-        and only the appended tail is converted: an online insert costs
-        the ids it added, not all Σ|V_state| ids again."""
-        prev = self._ids_frozen
+        """Freeze every state's ID list into one CSR, ``id_ptr`` (n+1,) and
+        ``id_data`` (sum |V_state|,) int64, ids ascending within a state:
+        one array pass over the flattened lists, with no per-state Python
+        step."""
+        self.id_ptr, self.id_data = lists_csr(self.ids)
 
-        def frozen(u: int, ids: List[int]) -> np.ndarray:
-            if u >= len(prev):
-                return np.asarray(ids, dtype=np.int64)
-            a = prev[u]
-            if len(a) == len(ids):
-                return a
-            return np.concatenate([a, np.asarray(ids[len(a):], np.int64)])
-
-        self._ids_np = [frozen(u, x) for u, x in enumerate(self.ids)]
-        self._ids_frozen = self._ids_np
-        self._topo = self._topological_order()
-
-    def _topological_order(self) -> np.ndarray:
-        """Kahn's algorithm over transitions.  The automaton is a DAG because
-        every transition strictly increases all positions (§4.1)."""
-        n = self.num_states
-        indeg = np.zeros(n, dtype=np.int64)
-        for t in self.trans:
-            for v in t.values():
-                indeg[v] += 1
-        order = np.empty(n, dtype=np.int64)
-        head = 0
-        tail = 0
-        for u in range(n):
-            if indeg[u] == 0:
-                order[tail] = u
-                tail += 1
-        while head < tail:
-            u = order[head]
-            head += 1
-            for v in self.trans[u].values():
-                indeg[v] -= 1
-                if indeg[v] == 0:
-                    order[tail] = v
-                    tail += 1
-        if tail != n:  # pragma: no cover - structural invariant
-            raise RuntimeError("ESAM transition graph has a cycle")
-        return order
-
-    def topo_order(self) -> np.ndarray:
-        if self._topo is None:
-            self._topo = self._topological_order()
-        return self._topo
+    def transitions(self, states: Optional[Sequence[int]] = None
+                    ) -> Tuple[np.ndarray, np.ndarray]:
+        """``(ptr, targets)``: the targets of the transitions of
+        ``states`` (every state by default) as a CSR, each state's in its
+        own (insertion) order."""
+        trans = (self.trans if states is None
+                 else [self.trans[u] for u in states])
+        return lists_csr(trans, dict.values)
 
     # ------------------------------------------------------------------ #
     # serialization (checkpointing; DESIGN.md §5 fault tolerance)
@@ -269,12 +249,7 @@ class ESAM:
                 src.append(u)
                 sym.append(k)
                 dst.append(v)
-        id_ptr = np.zeros(self.num_states + 1, dtype=np.int64)
-        for u, lst in enumerate(self.ids):
-            id_ptr[u + 1] = id_ptr[u] + len(lst)
-        id_data = np.empty(int(id_ptr[-1]), dtype=np.int64)
-        for u, lst in enumerate(self.ids):
-            id_data[id_ptr[u]:id_ptr[u + 1]] = lst
+        id_ptr, id_data = lists_csr(self.ids)
         return {
             "maxlen": np.asarray(self.maxlen, dtype=np.int64),
             "link": np.asarray(self.link, dtype=np.int64),
@@ -305,9 +280,7 @@ class ESAM:
                     for u in range(n)]
         self.num_sequences = int(arrays["num_sequences"][0])
         self.total_symbols = int(arrays["total_symbols"][0])
-        self._ids_np = None
-        self._topo = None
-        self._ids_frozen = []
+        self._invalidate()
         return self
 
 

@@ -7,10 +7,13 @@ flattens them into struct-of-arrays form:
 
   * ``kind``      (n_states,)  int8   — NONE / RAW / GRAPH per state;
   * ``inherit``   (n_states,)  int64  — inheritance-chain successor (-1 end);
-  * ``base_ptr``  (n_states+1,) int64 + ``base_ids`` (Σ|base|,) int64 — CSR
-    of *every* state's base-ID segment (raw and graph states alike), so a
-    chain walk is a handful of array reads and the union of a chain's
-    segments is exactly V_state (Lemma 4);
+  * ``base_lo`` / ``base_hi`` (n_states,) int64 + ``base_ids`` (Σ|base|,)
+    int64 — *every* state's base-ID segment (raw and graph states alike)
+    at ``base_ids[base_lo[u]:base_hi[u]]``, so a chain walk is a handful
+    of array reads and the union of a chain's segments is exactly
+    V_state (Lemma 4).  Segments lie in ``chain_layout`` order: each
+    heavy path of the inheritance forest is one contiguous run, so a
+    chain of hundreds of states covers a few runs, not hundreds;
   * per-graph padded neighbour matrices (``HNSW.pack()``) kept by state.
 
 Query execution splits into a host **planner** and a device-resident
@@ -68,7 +71,7 @@ from __future__ import annotations
 import time
 import warnings
 from collections import Counter
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -82,6 +85,49 @@ KIND_GRAPH = 1
 
 _EMPTY_F = np.empty(0, np.float32)
 _EMPTY_I = np.empty(0, np.int64)
+
+
+def ranges(starts: np.ndarray, lens: np.ndarray) -> np.ndarray:
+    """The positions [s, s + l) of every (start, length) pair, end to end:
+    where CSR segments lie in their flat array."""
+    lens = np.asarray(lens, dtype=np.int64)
+    total = int(lens.sum())
+    if total == 0:
+        return np.empty(0, dtype=np.int64)
+    offsets = np.cumsum(lens) - lens
+    return (np.repeat(np.asarray(starts, dtype=np.int64) - offsets, lens)
+            + np.arange(total, dtype=np.int64))
+
+
+def chain_layout(inherit: np.ndarray, depth: np.ndarray) -> np.ndarray:
+    """States in an order where every inheritance chain is a few
+    contiguous runs: the heavy-path decomposition of the forest that
+    ``inherit`` (child -> parent, -1 at a root) spans, each heavy path
+    laid out child before parent.  A chain walk leaves a heavy path only
+    over a light edge, which at least doubles the subtree it enters, so
+    a chain crosses at most log2(n) + 1 runs.  ``depth`` must grow from
+    child to parent (an automaton state's ``maxlen``: a transition
+    appends a symbol); every pass is one array operation per depth."""
+    n = len(inherit)
+    order = np.argsort(depth, kind="stable")
+    cuts = np.flatnonzero(np.diff(depth[order])) + 1
+    levels = np.split(order, cuts)
+    size = np.ones(n, dtype=np.int64)
+    for nodes in levels:                    # children before parents
+        par = inherit[nodes]
+        has = par >= 0
+        np.add.at(size, par[has], size[nodes[has]])
+    child = np.flatnonzero(inherit >= 0)
+    heavy = np.zeros(n, dtype=bool)
+    if len(child):
+        by = child[np.lexsort((child, -size[child], inherit[child]))]
+        first = np.r_[True, inherit[by[1:]] != inherit[by[:-1]]]
+        heavy[by[first]] = True
+    head = np.arange(n, dtype=np.int64)
+    for nodes in reversed(levels):          # parents before children
+        hv = nodes[heavy[nodes]]
+        head[hv] = head[inherit[hv]]
+    return np.lexsort((depth, head))
 
 
 class VectorStore:
@@ -281,18 +327,26 @@ class PendingExecution:
     dev_parts: List[List[Tuple[int, int]]]
     parts: List[List[Tuple[np.ndarray, np.ndarray]]]
     dev_only: List[int] = field(default_factory=list)
+    # residual sources ranked on device: (entry, source, live candidate
+    # ids, device (distances, positions)), verified at fetch
+    residuals: List[Tuple[object, object, np.ndarray, object]] = field(
+        default_factory=list)
     merged: Optional[Tuple[object, object]] = None   # (md, mi) on device
     fetched: bool = False
     wave: int = -1                      # the pipeline's wave id (spans)
+    queries: Optional[np.ndarray] = None   # (n_requests, d) float32
 
 
-def merge_sel(pools: List[List[int]], pad: int) -> np.ndarray:
+def merge_sel(pools: List[List[int]], pad: int,
+              rows: Optional[int] = None) -> np.ndarray:
     """The device merge's (R_pad, S) index matrix: row j lists the launch
     rows of request j's candidate pool (``pools[j]``, in merge order) and
     points its other slots at row ``pad``, the merge's all-padding row;
-    both sides are bucketed so waves share the merge's executables."""
+    both sides are bucketed so waves share the merge's executables.
+    ``rows`` fixes R_pad where the caller knows a bound that holds for
+    every wave."""
     from ..kernels.ops import bucket
-    sel = np.full((bucket(len(pools), 8),
+    sel = np.full((rows or bucket(len(pools), 8),
                    bucket(max(len(p) for p in pools), 1)), pad, np.int32)
     for j, pool in enumerate(pools):
         sel[j, :len(pool)] = pool
@@ -303,8 +357,8 @@ class PackedRuntime:
     """Flattened, device-residable view of a built VectorMaton index."""
 
     def __init__(self, vectors: np.ndarray, kind: np.ndarray,
-                 inherit: np.ndarray, base_ptr: np.ndarray,
-                 base_ids: np.ndarray, graphs: Dict[int, Dict[str, np.ndarray]],
+                 inherit: np.ndarray, base_lo: np.ndarray,
+                 base_hi: np.ndarray, base_ids: np.ndarray, graphs: Dict[int, Dict[str, np.ndarray]],
                  graph_objs: Dict[int, object], *, metric: str = "l2",
                  backend: str = "numpy", deleted: Optional[set] = None,
                  sequences: Optional[Sequence] = None,
@@ -313,7 +367,8 @@ class PackedRuntime:
         self.vectors = vectors          # live view; base rows are immutable
         self.kind = kind
         self.inherit = inherit
-        self.base_ptr = base_ptr
+        self.base_lo = base_lo
+        self.base_hi = base_hi
         self.base_ids = base_ids
         self.graphs = graphs            # state -> HNSW.pack() arrays
         self.graph_objs = graph_objs    # state -> host HNSW (host beam search)
@@ -330,7 +385,7 @@ class PackedRuntime:
         # [0, n_states) are automaton states (kind/inherit/delta apply);
         # [n_states, n_csr) are attribute segments addressed by
         # attr_num/attr_tag and resolved as descriptors like any other.
-        self.n_csr = len(base_ptr) - 1
+        self.n_csr = len(base_lo)
         self.attr_schema: Dict[str, str] = {}
         self.attr_num: Dict[str, Tuple[int, np.ndarray]] = {}
         self.attr_tag: Dict[str, Dict[str, int]] = {}
@@ -339,6 +394,7 @@ class PackedRuntime:
         # id -> graph states whose node set contains it (delete fan-out)
         self._id_graph_states: Optional[Dict[int, List[int]]] = None
         self._dev: Optional[dict] = None    # device cache, built once
+        self._chains: Optional[bool] = None  # see ``chains``
         self._dev_n = 0                     # vector count at upload time
         # predicate key -> (delta version at compile, compiled predicate,
         # planner-measured winning strategy at compile — a later measured
@@ -374,9 +430,14 @@ class PackedRuntime:
             # the pairs of real query rows with their own candidates
             "scan_pairs_computed": 0, "scan_pairs_needed": 0,
             # transfers and program dispatches the executor issues for
-            # the scan, graph and merge launches and the fetch (residual
-            # verification's eager math is not counted)
-            "host_device_calls": 0}
+            # the scan, graph, residual-ranking and merge launches and the
+            # fetch
+            "host_device_calls": 0,
+            # (start, length, owner) descriptors the scan launches ship
+            "scan_descriptors": 0}
+        # residual verification: candidate rows checked against their
+        # predicate on the host (time in wave_times residual_verify_ms)
+        self.residual_stats: Dict[str, int] = {"rows_verified": 0}
         # SQ8 scan-path accounting: every batch is either certified
         # (provably equal to the fp32 scan) or escalated to it; fallbacks
         # count batches the eligibility gate routed to fp32 outright
@@ -393,15 +454,22 @@ class PackedRuntime:
         # trusts the rerank output without the certificate sync — the
         # approximate operating point the frontier benchmark measures.
         self.sq8_escalate = True
-        self._sq8_bad_streak = 0
+        # consecutive escalations: one streak (key None) for the
+        # launches of plain buckets, one per shape (bucket key) for
+        # floored launches, whose shapes hold candidate sets of unlike
+        # density (``_execute_scan_sq8``)
+        self._sq8_bad_streak: Dict[Optional[tuple], int] = {}
         self.SQ8_MAX_STREAK = 3
+        # floored shapes whose fp32 program the SQ8 path has run once
+        self._sq8_fp32_warm: set = set()
         # cumulative per-wave wall-clock (ms), surfaced by
         # maintenance_stats as time_*_ms.  Device dispatch is async, so
         # launch_ms and merge_launch_ms are trace+dispatch cost; the
         # device wait is fetch_sync_ms alone, merge_ms the host merge.
         self.wave_times: Dict[str, float] = {
             "plan_ms": 0.0, "upload_ms": 0.0, "launch_ms": 0.0,
-            "merge_launch_ms": 0.0, "fetch_sync_ms": 0.0, "merge_ms": 0.0}
+            "merge_launch_ms": 0.0, "fetch_sync_ms": 0.0, "merge_ms": 0.0,
+            "residual_verify_ms": 0.0}
 
     # ------------------------------------------------------------------ #
     # construction
@@ -409,30 +477,21 @@ class PackedRuntime:
 
     @classmethod
     def build(cls, vm, generation: int = 0) -> "PackedRuntime":
-        """Flatten a VectorMaton's chain structure + per-state indexes."""
-        from .vectormaton import _RAW  # local import avoids cycle
-
+        """A VectorMaton's chain structure and per-state indexes, as the
+        index's ``StateIndexes.flatten`` gives them, laid out in
+        ``chain_layout`` order, with the attribute segments appended."""
         n = vm.esam.num_states
-        kind = np.full(n, KIND_NONE, dtype=np.int8)
-        base_ptr = np.zeros(n + 1, dtype=np.int64)
-        chunks: List[np.ndarray] = []
-        graphs: Dict[int, Dict[str, np.ndarray]] = {}
-        graph_objs: Dict[int, object] = {}
-        for u in range(n):
-            idx = vm.state_index[u] if u < len(vm.state_index) else None
-            if idx is None:
-                base_ptr[u + 1] = base_ptr[u]
-                continue
-            if idx.kind == _RAW:
-                kind[u] = KIND_RAW
-                seg = np.asarray(idx.raw_ids, dtype=np.int64)
-            else:
-                kind[u] = KIND_GRAPH
-                seg = np.asarray(idx.graph.ids, dtype=np.int64)
-                graphs[u] = idx.graph.pack()
-                graph_objs[u] = idx.graph
-            chunks.append(seg)
-            base_ptr[u + 1] = base_ptr[u] + len(seg)
+        si = vm.state_index
+        kind, ptr, ids = si.flatten()
+        inherit = np.asarray(vm.inherit, dtype=np.int64)
+        layout = chain_layout(inherit, np.asarray(vm.esam.maxlen, np.int64))
+        lens = np.diff(ptr)
+        base_lo = np.empty(n, dtype=np.int64)
+        base_lo[layout] = np.cumsum(lens[layout]) - lens[layout]
+        base_hi = base_lo + lens
+        graph_objs: Dict[int, object] = dict(si.graphs)
+        graphs = {u: g.pack() for u, g in graph_objs.items()}
+        chunks: List[np.ndarray] = [ids[ranges(ptr[layout], lens[layout])]]
         # Attribute pseudo-segments (DESIGN.md §9): one sorted-by-value
         # ID segment per numeric field (rank ranges answer Range leaves
         # as descriptor slices) and one sorted-ID segment per (tag field,
@@ -468,14 +527,15 @@ class PackedRuntime:
                         v: _pseudo(np.asarray(groups[v], np.int64))
                         for v in sorted(groups)}
         if attr_segs:
-            lens = np.asarray([len(s) for s in attr_segs], np.int64)
-            base_ptr = np.concatenate(
-                [base_ptr, base_ptr[-1] + np.cumsum(lens)])
+            alens = np.asarray([len(s) for s in attr_segs], np.int64)
+            alo = int(lens.sum()) + np.cumsum(alens) - alens
+            base_lo = np.concatenate([base_lo, alo])
+            base_hi = np.concatenate([base_hi, alo + alens])
             chunks.extend(attr_segs)
-        base_ids = (np.concatenate(chunks) if chunks
-                    else np.empty(0, np.int64))
-        rt = cls(vm.vectors, kind, np.asarray(vm.inherit, dtype=np.int64),
-                 base_ptr, base_ids, graphs, graph_objs,
+        base_ids = (np.concatenate(chunks) if len(chunks) > 1
+                    else chunks[0])
+        rt = cls(vm.vectors, kind, inherit, base_lo, base_hi, base_ids,
+                 graphs, graph_objs,
                  metric=vm.config.metric, backend=vm.config.backend,
                  deleted=vm.deleted,
                  quantize=getattr(vm.config, "quantize", "none"),
@@ -659,8 +719,22 @@ class PackedRuntime:
                          generation=self.generation,
                          delta_version=self.delta.version)
 
+    @property
+    def chains(self) -> bool:
+        """Whether a state other than the root inherits, so that a
+        CONTAINS can resolve to a chain of several segments: rows carry
+        sequences of their own, not one label each.  Every descriptor
+        launch of such a runtime takes the shape floors
+        (``ops.floored``)."""
+        if self._chains is None:
+            self._chains = bool(np.any(self.inherit[1:] >= 0))
+        return self._chains
+
     def chain_cover(self, state: int) -> ChainCover:
-        """Walk the inheritance chain; CSR ranges covering exactly V_state."""
+        """Walk the inheritance chain; CSR ranges covering exactly V_state,
+        one per state in ``segments``, and in ``raw_segments`` the raw
+        states' ranges with each contiguous run (``chain_layout``) joined
+        into one: the scan's descriptors."""
         segments: List[Tuple[int, int]] = []
         raw_segments: List[Tuple[int, int]] = []
         graph_states: List[int] = []
@@ -668,15 +742,17 @@ class PackedRuntime:
         size = 0
         u = state
         while u != -1:
-            lo, hi = int(self.base_ptr[u]), int(self.base_ptr[u + 1])
+            lo, hi = int(self.base_lo[u]), int(self.base_hi[u])
             if hi > lo:
                 segments.append((lo, hi))
                 states.append(u)
                 size += hi - lo
-                if self.kind[u] == KIND_RAW:
-                    raw_segments.append((lo, hi))
-                else:
+                if self.kind[u] != KIND_RAW:
                     graph_states.append(u)
+                elif raw_segments and raw_segments[-1][1] == lo:
+                    raw_segments[-1] = (raw_segments[-1][0], hi)
+                else:
+                    raw_segments.append((lo, hi))
             u = int(self.inherit[u])
         return ChainCover(segments, raw_segments, graph_states, size,
                           states=states)
@@ -801,7 +877,7 @@ class PackedRuntime:
             [] for _ in range(plan.n_requests)]      # (launch idx, row)
         pending = PendingExecution(plan=plan, k=k, out=out,
                                    launches=launches, dev_parts=dev_parts,
-                                   parts=parts, wave=wave)
+                                   parts=parts, wave=wave, queries=queries)
         if not plan.entries:
             pending.fetched = True
             return pending
@@ -828,14 +904,19 @@ class PackedRuntime:
             self._execute_graphs_host(queries, graph_shared, graph_filtered,
                                       k, ef_search, parts)
         for e, s in residual_items:
-            self._execute_residual(queries, e, s, k, parts)
+            if self.backend == "jax":
+                pending.residuals.extend(self._launch_residual(queries, e, s))
+            else:
+                self._execute_residual(queries, e, s, k, parts)
         # device-merge half that can be DISPATCHED now: requests whose
         # parts are all launch rows fold on device; the (R, k) result
         # stays a device future until fetch
         n = plan.n_requests
+        verified = {r for e, *_ in pending.residuals for r in e.requests}
         if launches and self.device_merge:
             pending.dev_only = [r for r in range(n)
-                                if dev_parts[r] and not parts[r]]
+                                if dev_parts[r] and not parts[r]
+                                and r not in verified]
         if pending.dev_only:
             with span("launch_merge", wave=wave):
                 t0 = time.perf_counter()
@@ -854,19 +935,52 @@ class PackedRuntime:
         already executing."""
         if pending.fetched:
             return pending.out
-        if pending.launches:
+        if pending.launches or pending.residuals:
             import jax
             with span("sync", wave=pending.wave):
                 t0 = time.perf_counter()
-                jax.block_until_ready((pending.merged, pending.launches))
+                jax.block_until_ready((pending.merged, pending.launches,
+                                       [r[3] for r in pending.residuals]))
                 self.wave_times["fetch_sync_ms"] += (
                     (time.perf_counter() - t0) * 1e3)
+        if pending.residuals:
+            with span("verify_residual", wave=pending.wave):
+                self._verify_residuals(pending)
         with span("merge_host", wave=pending.wave):
             t0 = time.perf_counter()
             self._merge_fetch(pending)
+            self._rescore(pending)
             self.wave_times["merge_ms"] += (time.perf_counter() - t0) * 1e3
         pending.fetched = True
+        # a fetched wave pins no device memory: its launch outputs and
+        # residual rankings (2 MB for 8 queries over 32K candidates) go
+        pending.launches, pending.residuals, pending.merged = [], [], None
         return pending.out
+
+    def _rescore(self, pending: PendingExecution) -> None:
+        """Each answer's L2 distances recomputed as sum((x - y)^2) in
+        float32, in the order the scan ranked them.  The scan ranks by
+        |x|^2 + |y|^2 - 2 x.y, whose float32 sum loses about
+        eps * (|x|^2 + |y|^2) / |x - y|^2 to cancellation: 3e-7 of the
+        distance at 1024 dimensions, as much as one precision step
+        below float32 gives.  A ``bf16`` accumulation keeps its scan's
+        distances, which are what it asked for."""
+        if self.metric != "l2" or self.accum != "f32":
+            return
+        out = pending.out
+        reqs = [r for r, (_, i) in enumerate(out) if len(i)]
+        if not reqs:
+            return
+        ids = np.concatenate([out[r][1] for r in reqs])
+        owner = np.repeat(reqs, [len(out[r][1]) for r in reqs])
+        diff = (np.asarray(self.vectors[ids], np.float32)
+                - pending.queries[owner])
+        dist = np.sum(diff * diff, axis=1)      # pairwise summation
+        lo = 0
+        for r in reqs:
+            hi = lo + len(out[r][1])
+            out[r] = (dist[lo:hi], out[r][1])
+            lo = hi
 
     def _merge_fetch(self, pending: PendingExecution) -> None:
         """Per-request merge: dedup ids across OR disjuncts / overlapping
@@ -973,7 +1087,7 @@ class PackedRuntime:
         if sel is None:
             sel = jax.device_put(merge_sel(
                 [[offs[li] + row for li, row in dev_parts[r]] for r in reqs],
-                t_pad))
+                t_pad, t_pad if len(launches) == 1 else None))
             calls += 1
         delmask = (dev["deleted"] if self._dev_n
                    else jnp.zeros(1, dtype=bool))
@@ -1152,15 +1266,16 @@ class PackedRuntime:
             return None
         rows = (self.vectors[tship_i.astype(np.int64)] if len(tship_i)
                 else np.empty((0, queries.shape[1]), np.float32))
-        # traffic accounting mirrors the padded buckets actually shipped
+        # traffic accounting mirrors the padded buckets actually shipped;
+        # candidate ids are the tails' own (the resident tail's floor
+        # ships padding, no candidate)
         d_dim = queries.shape[1]
-        qp = ops.bucket(len(q_rows))
-        dp = ops.bucket(len(dstarts), 8) if nd else 0
-        tr, ts = ops.bucket(len(tres_i)), ops.bucket(len(tship_i))
+        qp, _, tr, ts, dp = ops.descriptor_extents(
+            len(q_rows), dlens, len(tres_i), len(tship_i), self.chains)
         tf = self.traffic
         tf["query_bytes"] += qp * (d_dim * 4 + 4)
         tf["descriptor_bytes"] += dp * 12
-        tf["candidate_id_bytes"] += (tr + ts) * 8    # ids + owner ids
+        tf["candidate_id_bytes"] += (len(tres_i) + len(tship_i)) * 8
         tf["row_bytes"] += ts * d_dim * 4
         tf["bytes_to_device"] += (qp * (d_dim * 4 + 4) + dp * 12
                                   + (tr + ts) * 8 + ts * d_dim * 4)
@@ -1171,11 +1286,13 @@ class PackedRuntime:
 
     def _count_scan_pairs(self, flat, launches: int = 1) -> None:
         """Adds the pairs of ``launches`` descriptor launches of one
-        assembled batch to ``scan_pairs_*``."""
+        assembled batch to ``scan_pairs_*``, and their descriptors to
+        ``scan_descriptors``."""
         from ..kernels import ops
         _, q_owner, _, dlens, downers, _, tres_ow, _, tship_ow, _ = flat
+        self.traffic["scan_descriptors"] += launches * len(dlens)
         computed, needed = ops.scan_pairs(q_owner, dlens, downers, tres_ow,
-                                          tship_ow)
+                                          tship_ow, self.chains)
         self.traffic["scan_pairs_computed"] += launches * computed
         self.traffic["scan_pairs_needed"] += launches * needed
 
@@ -1192,13 +1309,18 @@ class PackedRuntime:
          tship_i, tship_ow, rows) = flat
         host, key = ops.pad_descriptor_batch(
             queries[q_rows], q_owner, dstarts, dlens, downers, tres_i,
-            tres_ow, tship_i, rows, tship_ow)
+            tres_ow, tship_i, rows, tship_ow, self.chains)
         sel = None
         if with_sel:
             per_req: Dict[int, List[int]] = {}
             for row, r in enumerate(q_rows):
                 per_req.setdefault(r, []).append(row)
-            sel = merge_sel([per_req[r] for r in sorted(per_req)], key[0])
+            # a floored wave merges one row per padded query row, so its
+            # number of requests never picks the merge's program
+            fixed = ops.floored(len(q_rows), len(dlens), len(tres_i),
+                                self.chains)
+            sel = merge_sel([per_req[r] for r in sorted(per_req)], key[0],
+                            key[0] if fixed else None)
         batch, sel = jax.device_put((host, sel))
         self.traffic["host_device_calls"] += 1
         return batch, key, sel
@@ -1254,9 +1376,17 @@ class PackedRuntime:
         fails on any query row is re-run through the fp32 descriptor
         path on the same uploaded buffers, so results always equal the
         fp32 scan's; ``sq8_stats`` counts certified vs escalated batches.
-        Batches the eligibility gate rejects outright (metric/dim/k
-        outside ``sq8_supported``) fall back to the fp32 path with a
-        one-time warning.  Returns what ``_execute_scan_device`` does."""
+        After ``SQ8_MAX_STREAK`` consecutive escalations the scan runs
+        fp32 outright: every launch of plain buckets, or, for a floored
+        launch (``ops.floored``), its shape alone, since one floored shape
+        holds a dense chain cover and the next a few rows.  A floored
+        shape runs its fp32 program once with its first SQ8 batch, so
+        neither an escalation nor a flip lowers a program inside
+        serving, and the programs a runtime holds follow the shapes it
+        met, not which of its batches certified.  Batches the
+        eligibility gate rejects outright (metric/dim/k outside
+        ``sq8_supported``) fall back to the fp32 path with a one-time
+        warning.  Returns what ``_execute_scan_device`` does."""
         import jax
 
         from ..kernels import ops
@@ -1274,13 +1404,6 @@ class PackedRuntime:
             return self._execute_scan_device(queries, scan_items, k,
                                              launches, dev_parts, wave,
                                              with_sel)
-        if self.sq8_escalate and self._sq8_bad_streak >= self.SQ8_MAX_STREAK:
-            # the certificate keeps failing on this workload: int8 scan
-            # plus escalation is pure overhead, so serve fp32 directly
-            self.sq8_stats["fallbacks"] += 1
-            return self._execute_scan_device(queries, scan_items, k,
-                                             launches, dev_parts, wave,
-                                             with_sel)
         overfetch = max(1, min(4, 128 // max(k, 1)))
         with span("assemble", wave=wave):
             t0 = time.perf_counter()
@@ -1289,38 +1412,60 @@ class PackedRuntime:
         if flat is None:
             return None
         dev = self.to_device()
-        self.sq8_stats["batches"] += 1
+
+        def fp32():
+            self.traffic["host_device_calls"] += 1
+            return ops.topk_segmented_desc(
+                dev["vectors"], dev["base_ids"], dev["deleted"], batch, key,
+                k, metric=self.metric, accum=self.accum)
+
         with span("launch_scan", wave=wave):
             t0 = time.perf_counter()
             with span("upload", wave=wave):
                 batch, key, sel = self._upload_scan_batch(queries, flat,
                                                           with_sel)
-            v, g, cert = topk_sq8_segmented_desc(
-                dev["vectors"], dev["quant"], dev["base_ids"],
-                dev["deleted"], batch, key, k, overfetch=overfetch)
-            self.traffic["host_device_calls"] += 1
-            escalated = False
-            # without ``sq8_escalate`` (the approximate operating point)
-            # the rerank is trusted and the certificate never read back
-            if self.sq8_escalate:
+            runs = 1
+            floored = ops.floored(len(flat[0]), len(flat[3]), len(flat[5]),
+                                  self.chains)
+            streak = key if floored else None
+            if (self.sq8_escalate and self._sq8_bad_streak.get(streak, 0)
+                    >= self.SQ8_MAX_STREAK):
+                # the certificate keeps failing here: int8 scan plus
+                # escalation is pure overhead, so serve fp32 directly
+                self.sq8_stats["fallbacks"] += 1
+                v, g = fp32()
+            else:
+                self.sq8_stats["batches"] += 1
+                v, g, cert = topk_sq8_segmented_desc(
+                    dev["vectors"], dev["quant"], dev["base_ids"],
+                    dev["deleted"], batch, key, k, overfetch=overfetch)
                 self.traffic["host_device_calls"] += 1
-                if bool(jax.device_get(cert).all()):      # device sync
-                    self.sq8_stats["certified"] += 1
-                    self._sq8_bad_streak = 0
-                else:
-                    # quantization noise could have pushed a true top-k
-                    # candidate out of the over-fetched set: redo the
-                    # whole batch exactly
-                    v, g = ops.topk_segmented_desc(
-                        dev["vectors"], dev["base_ids"], dev["deleted"],
-                        batch, key, k, metric=self.metric, accum=self.accum)
+                warm = floored and key not in self._sq8_fp32_warm
+                # without ``sq8_escalate`` (the approximate operating
+                # point) the rerank is trusted and the certificate never
+                # read back
+                if self.sq8_escalate:
                     self.traffic["host_device_calls"] += 1
-                    escalated = True
-                    self.sq8_stats["escalations"] += 1
-                    self._sq8_bad_streak += 1
+                    if bool(jax.device_get(cert).all()):  # device sync
+                        self.sq8_stats["certified"] += 1
+                        self._sq8_bad_streak[streak] = 0
+                        if warm:
+                            fp32()
+                            runs = 2
+                    else:
+                        # quantization noise could have pushed a true
+                        # top-k candidate out of the over-fetched set:
+                        # redo the whole batch exactly
+                        v, g = fp32()
+                        runs = 2
+                        self.sq8_stats["escalations"] += 1
+                        self._sq8_bad_streak[streak] = (
+                            self._sq8_bad_streak.get(streak, 0) + 1)
+                if runs == 2:
+                    self._sq8_fp32_warm.add(key)
             dt = time.perf_counter() - t0
             self.wave_times["launch_ms"] += dt * 1e3
-        self._count_scan_pairs(flat, launches=2 if escalated else 1)
+        self._count_scan_pairs(flat, launches=runs)
         self._observe("scan", self._scan_units(scan_items), dt)
         self._emit_scan(flat[0], v, g, launches, dev_parts)
         return sel
@@ -1517,75 +1662,120 @@ class PackedRuntime:
 
     # ---- residual verification (strategy c) --------------------------- #
 
-    def _dense_dist(self, qmat: np.ndarray, cand: np.ndarray):
-        """The (Q, |cand|) dense distance matrix of ``qmat`` against
-        ``vectors[cand]`` — computed ONCE per residual source and kept on
-        the backend that computed it (device array on jax, ndarray on
-        numpy) so the over-fetch loop re-ranks without recomputing or
-        shipping the whole matrix."""
-        if self.backend == "jax":
-            import jax
-            import jax.numpy as jnp
-            x = jnp.asarray(qmat)
-            y = self._device_rows(np.asarray(cand))
-            xy = jnp.matmul(x, y.T, precision=jax.lax.Precision.HIGHEST)
-            if self.metric == "l2":
-                d = (jnp.sum(x * x, 1, keepdims=True) + jnp.sum(y * y, 1)
-                     - 2.0 * xy)
-                return jnp.maximum(d, 0.0)
-            return -xy
-        from ..kernels import ops
-        x = np.asarray(qmat, dtype=np.float32)
-        y = np.asarray(self.vectors[cand], dtype=np.float32)
-        if self.metric == "l2":
-            d = (np.sum(x * x, axis=1, keepdims=True)
-                 + np.sum(y * y, axis=1) - 2.0 * (x @ y.T))
-            np.maximum(d, 0.0, out=d)
-            return d
-        return -(x @ y.T)
+    RESIDUAL_ROWS = 8            # query rows of one residual ranking launch
 
-    def _rank_topm(self, dmat, m: int) -> Tuple[np.ndarray, np.ndarray]:
-        """Top-m (ascending distances, column indices) of a cached dense
-        distance matrix; only the (Q, m) winners cross to the host.  m is
-        unbounded (the over-fetch loop outgrows the 128-lane streaming
-        kernel), so the device path uses ``lax.top_k``."""
-        m = min(m, int(dmat.shape[1]))
-        if self.backend == "jax":
-            import jax
-            neg, idx = jax.lax.top_k(-dmat, m)
-            return np.asarray(-neg), np.asarray(idx)
-        part = np.argpartition(dmat, m - 1, axis=1)[:, :m]
-        pv = np.take_along_axis(dmat, part, axis=1)
-        order = np.argsort(pv, axis=1, kind="stable")
-        return (np.take_along_axis(pv, order, axis=1),
-                np.take_along_axis(part, order, axis=1))
+    def _launch_residual(self, queries, e: PlanEntry, s: CompiledSource):
+        """Rank a residual source's live candidates for each of its
+        requests on device, all of them (``ops.rank_candidates``; the
+        ranking stays a device future); ``fetch`` verifies them in
+        distance order.  The candidates upload once; the requests go
+        ``RESIDUAL_ROWS`` to a launch, so a predicate's prefilter fixes
+        one program whatever the wave holds.  Returns the
+        ``PendingExecution.residuals`` records, none without a live
+        candidate."""
+        import jax
+
+        from ..kernels import ops
+        cand = self._live(s.ids)
+        if len(cand) == 0:
+            return []
+        dev = self.to_device()
+        cp, d_dim = ops.bucket(len(cand)), queries.shape[1]
+        ids = np.zeros(cp, np.int32)
+        ids[:len(cand)] = cand
+        ship = np.nonzero(cand >= self._dev_n)[0]
+        tp = ops.bucket(len(ship), 8) if len(ship) else 0
+        tail_pos = np.full(tp, cp, np.int32)
+        tail_pos[:len(ship)] = ship
+        tail_rows = np.zeros((tp, d_dim), np.float32)
+        tail_rows[:len(ship)] = self.vectors[cand[ship]]
+        host = [ids, tail_pos, tail_rows]
+        rows = self.RESIDUAL_ROWS
+        chunks = [e.requests[lo:lo + rows]
+                  for lo in range(0, len(e.requests), rows)]
+        for reqs in chunks:
+            x = np.zeros((rows, d_dim), np.float32)
+            x[:len(reqs)] = queries[reqs]
+            host.append(x)
+        dev_ids, dev_pos, dev_rows, *dev_x = jax.device_put(host)
+        out = []
+        for reqs, x in zip(chunks, dev_x):
+            ranked = ops.rank_candidates(dev["vectors"], x, dev_ids,
+                                         len(cand), dev_pos, dev_rows,
+                                         metric=self.metric)
+            out.append((replace(e, requests=reqs), s, cand, ranked))
+        self.traffic["host_device_calls"] += 1 + len(chunks)
+        self.traffic["query_bytes"] += len(chunks) * rows * d_dim * 4
+        self.traffic["candidate_id_bytes"] += ids.nbytes
+        self.traffic["row_bytes"] += tail_rows.nbytes
+        self.traffic["bytes_to_device"] += sum(a.nbytes for a in host)
+        return out
+
+    def _verify_residuals(self, pending: PendingExecution) -> None:
+        """Fetch half of device-ranked residual sources: download each
+        ranking and verify it in distance order (``_verify_ranked``)."""
+        import jax
+        for e, s, cand, ranked in pending.residuals:
+            d, li = jax.device_get(ranked)
+            self.traffic["host_device_calls"] += 1
+            n = len(e.requests)
+            self._verify_ranked(e, s, cand, lambda m: (d[:n, :m], li[:n, :m]),
+                                len(cand), pending.k, pending.parts)
 
     def _execute_residual(self, queries, e: PlanEntry, s: CompiledSource,
                           k: int, parts) -> None:
-        """Over-fetch + exact host-side verification: compute the dense
-        distance matrix ONCE (kept on its backend), rank the top-m, and
-        verify hits in distance order, doubling m — a re-rank of the
-        cached matrix plus more verification, never a distance recompute
-        — until every request has k verified hits (or the prefilter is
-        exhausted).  The old loop recomputed the full dense distance
-        matrix every round, paying O(rounds · Q · |cand| · d) for
-        distances it already had; only the (Q, m) winners ever cross to
-        the host.
+        """Host backend: the (Q, |cand|) distance matrix once, then
+        ``_verify_ranked`` over its top-m, m doubling from 4k (or the
+        whole prefilter where the planner replays a measured yield
+        collapse, ``CompiledSource.residual_full``)."""
+        cand = self._live(s.ids)
+        if len(cand) == 0:
+            return
+        x = np.asarray(queries[e.requests], dtype=np.float32)
+        y = np.asarray(self.vectors[cand], dtype=np.float32)
+        if self.metric == "l2":
+            dmat = (np.sum(x * x, axis=1, keepdims=True)
+                    + np.sum(y * y, axis=1) - 2.0 * (x @ y.T))
+            np.maximum(dmat, 0.0, out=dmat)
+        else:
+            dmat = -(x @ y.T)
+
+        def rank(m: int) -> Tuple[np.ndarray, np.ndarray]:
+            part = np.argpartition(dmat, m - 1, axis=1)[:, :m]
+            pv = np.take_along_axis(dmat, part, axis=1)
+            order = np.argsort(pv, axis=1, kind="stable")
+            return (np.take_along_axis(pv, order, axis=1),
+                    np.take_along_axis(part, order, axis=1))
+
+        full = s.residual_full and self._adaptive
+        self._verify_ranked(e, s, cand, rank,
+                            len(cand) if full else min(len(cand), 4 * k),
+                            k, parts)
+
+    @property
+    def _adaptive(self) -> bool:
+        return (self.planner is not None
+                and getattr(self.planner, "adaptive", False))
+
+    def _verify_ranked(self, e: PlanEntry, s: CompiledSource,
+                       cand: np.ndarray, rank, m: int, k: int,
+                       parts) -> None:
+        """Exact host verification in distance order: ``rank(m)`` gives
+        each request's top-m candidates (ascending distances, positions
+        into ``cand``); hits are verified in that order, doubling m until
+        every request has k verified hits or the prefilter is exhausted.
+        The ranking is prefix-stable in m, so the answers do not depend
+        on where m starts.
 
         Adaptive escalation (DESIGN.md §11): the loop tracks observed
         verification yield; when a row's projected need ``k/yield``
         already covers the whole prefilter — the doubling ramp would
         provably walk every candidate anyway — it jumps straight to the
-        full scan instead of re-ranking through the remaining doublings,
-        reports the switch to the planner (``planner_residual_switches``)
-        and remembers it per (predicate, delta version) so a re-compile
-        starts there (``CompiledSource.residual_full``).  Result-
-        identical: the top-m ranking of the cached matrix is prefix-
-        stable in m, and assembly still stops at k verified hits."""
+        full ranking, reports the switch to the planner
+        (``planner_residual_switches``) and remembers it per (predicate,
+        delta version) so a re-compile starts there
+        (``CompiledSource.residual_full``)."""
         t_start = time.perf_counter()
-        cand = self._live(s.ids)
-        if len(cand) == 0:
-            return
         seqs = self.sequences
         cache: Dict[int, bool] = {}
 
@@ -1597,13 +1787,9 @@ class PackedRuntime:
             return v
 
         reqs = e.requests
-        adaptive = (self.planner is not None
-                    and getattr(self.planner, "adaptive", False))
-        dmat = self._dense_dist(queries[reqs], cand)
-        m = (len(cand) if (s.residual_full and adaptive)
-             else min(len(cand), max(4 * k, k)))
+        adaptive = self._adaptive
         while True:
-            d, li = self._rank_topm(dmat, m)
+            d, li = rank(m)
             done = True
             checked = cnt = 0
             for row in range(len(reqs)):
@@ -1636,8 +1822,6 @@ class PackedRuntime:
                         e.key, int(self.delta.version))
                     continue
             m = grown
-        self._observe("residual", m * len(reqs),
-                      time.perf_counter() - t_start)
         for row, r in enumerate(reqs):
             vd: List[float] = []
             vi: List[int] = []
@@ -1652,6 +1836,10 @@ class PackedRuntime:
                         break
             parts[r].append((np.asarray(vd, np.float32),
                              np.asarray(vi, np.int64)))
+        dt = time.perf_counter() - t_start
+        self.residual_stats["rows_verified"] += len(cache)
+        self.wave_times["residual_verify_ms"] += dt * 1e3
+        self._observe("residual", m * len(reqs), dt)
 
     # ------------------------------------------------------------------ #
     # accounting
@@ -1662,7 +1850,7 @@ class PackedRuntime:
         segs = []
         u = state
         while u != -1:
-            segs.append(self.base_ids[self.base_ptr[u]:self.base_ptr[u + 1]])
+            segs.append(self.base_ids[self.base_lo[u]:self.base_hi[u]])
             u = int(self.inherit[u])
         return (np.concatenate(segs) if segs else np.empty(0, np.int64))
 
@@ -1671,7 +1859,7 @@ class PackedRuntime:
             "states": len(self.kind),
             "raw_states": int((self.kind == KIND_RAW).sum()),
             "graph_states": int((self.kind == KIND_GRAPH).sum()),
-            "base_entries": int(self.base_ptr[-1]),
+            "base_entries": len(self.base_ids),
             "attr_segments": self.n_csr - self.n_states,
             "device_resident": int(self._dev is not None),
             "generation": self.generation,

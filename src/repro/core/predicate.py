@@ -786,7 +786,7 @@ class _Ctx:
         full pseudo-states, partial (state, rank_lo, rank_hi) ranges,
         frozen member count)."""
         field_name = self.attr_field(node)
-        ptr = self.rt.base_ptr
+        lo_of, hi_of = self.rt.base_lo, self.rt.base_hi
         if isinstance(node, Tag):
             tmap = getattr(self.rt, "attr_tag", {}).get(field_name, {})
             segs, states = [], []
@@ -794,7 +794,7 @@ class _Ctx:
                 u = tmap.get(v)
                 if u is None:
                     continue
-                lo, hi = int(ptr[u]), int(ptr[u + 1])
+                lo, hi = int(lo_of[u]), int(hi_of[u])
                 if hi > lo:
                     segs.append((lo, hi))
                     states.append(u)
@@ -809,7 +809,7 @@ class _Ctx:
             vals, node.hi, side="right" if node.incl_hi else "left")))
         if b <= a:
             return [], [], [], 0
-        lo, hi = int(ptr[u]) + a, int(ptr[u]) + b
+        lo, hi = int(lo_of[u]) + a, int(lo_of[u]) + b
         return [(lo, hi)], [], [(int(u), a, b)], b - a
 
     def attr_delta_ids(self, node) -> np.ndarray:

@@ -33,30 +33,35 @@ and a threshold-triggered *compaction* folds delta + tombstone GC into a
 fresh generation swapped in behind the readers.  Deletes are lazy marks
 filtered at query time and physically GC'd at compaction.
 
-Parallel build mirrors the paper's concurrent ready-queue over reverse
-topological order (thread pool; NumPy releases the GIL inside distance
-batches).
+The build's per-state passes (inheritance, base sets, the CSR) are
+array operations over the finalized automaton's flattened id lists;
+only the graphs of states whose base set reaches ``T`` are built one by
+one, on a thread pool (NumPy releases the GIL inside distance batches).
 """
 
 from __future__ import annotations
 
-import queue as queue_mod
+import contextlib
+import gc
 import threading
 import time
-from dataclasses import dataclass, field
+from concurrent.futures import ThreadPoolExecutor
+from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from .esam import ESAM, ROOT
+from ..serve.telemetry import phase_span
+from .esam import ESAM, lists_csr
 from .hnsw import HNSW
-from .packed import PackedRuntime, QueryPlan, VectorStore
+from .packed import KIND_GRAPH, KIND_NONE, KIND_RAW, PackedRuntime, \
+    QueryPlan, VectorStore, ranges
 from .planner import AdaptivePlanner
 from .predicate import CompiledPredicate, Predicate, as_predicate, \
     compile_predicate
 
-_RAW = 0
-_HNSW = 1
+_RAW = KIND_RAW
+_HNSW = KIND_GRAPH
 
 
 @dataclass
@@ -105,10 +110,142 @@ class _StateIndex:
     def n_indexed(self) -> int:
         return (len(self.raw_ids) if self.kind == _RAW else len(self.graph))
 
-    @property
-    def size_entries(self) -> int:
-        return (len(self.raw_ids) if self.kind == _RAW
-                else self.graph.size_entries)
+
+@contextlib.contextmanager
+def _collector_paused():
+    """Python's cyclic collector off for a block.  The automaton is one
+    dict and one list per state and holds no cycles, but each of the
+    collector's passes while it grows walks every object built so far
+    (a third of the build's time at 8K protein rows)."""
+    was = gc.isenabled()
+    gc.disable()
+    try:
+        yield
+    finally:
+        if was:
+            gc.enable()
+
+
+def csr_difference(a_ptr, a_ids, b_ptr, b_ids
+                   ) -> Tuple[np.ndarray, np.ndarray]:
+    """Row by row ``a \\ b`` of two CSRs with the same rows, ids strictly
+    increasing within a row, each row of b inside the same row of a: one
+    ``searchsorted`` of b's (row, id) keys among a's, which are sorted."""
+    rows = len(a_ptr) - 1
+    width = int(a_ids.max()) + 1 if len(a_ids) else 1
+    a_len, b_len = np.diff(a_ptr), np.diff(b_ptr)
+    key_a = np.repeat(np.arange(rows, dtype=np.int64), a_len) * width + a_ids
+    key_b = np.repeat(np.arange(rows, dtype=np.int64), b_len) * width + b_ids
+    keep = np.ones(len(a_ids), dtype=bool)
+    keep[np.searchsorted(key_a, key_b)] = False
+    ptr = np.zeros(rows + 1, dtype=np.int64)
+    np.cumsum(a_len - b_len, out=ptr[1:])
+    return ptr, a_ids[keep]
+
+
+def pick_inherit(lens: np.ndarray, dst: np.ndarray,
+                 size: np.ndarray) -> np.ndarray:
+    """Per state, the direct successor with the largest V (the first in
+    transition order among equals), -1 where no successor holds an id.
+    ``lens``/``dst``: transitions per state and their targets
+    (``ESAM.transitions``); ``size``: |V| of each target."""
+    out = np.full(len(lens), -1, dtype=np.int64)
+    if len(dst) == 0:
+        return out
+    src = np.repeat(np.arange(len(lens), dtype=np.int64), lens)
+    order = np.lexsort((-size, src))            # stable: ties keep order
+    has = lens > 0
+    first = order[(np.cumsum(lens) - lens)[has]]
+    out[has] = np.where(size[first] > 0, dst[first], -1)
+    return out
+
+
+class StateIndexes:
+    """Every state's index: its base set V_u \\ V_inherit(u) (Lemma 4)
+    in one CSR (``kind``, ``ptr``, ``ids``), and an HNSW graph for each
+    graph state, whose segment is the graph's node list.  The write path
+    changes single states (``set_raw``, ``set_graph``, ``grow``);
+    ``flatten`` folds them into a fresh CSR.  ``state_index[u]`` reads
+    one state as a ``_StateIndex`` (None where the state has none)."""
+
+    def __init__(self, kind: np.ndarray, ptr: np.ndarray, ids: np.ndarray,
+                 graphs: Optional[Dict[int, HNSW]] = None) -> None:
+        self.kind = np.asarray(kind, dtype=np.int8)
+        self.ptr = np.asarray(ptr, dtype=np.int64)
+        self.ids = np.asarray(ids, dtype=np.int64)
+        self.graphs: Dict[int, HNSW] = dict(graphs or {})
+        self.patched: Dict[int, np.ndarray] = {}    # raw sets since flatten
+
+    def __len__(self) -> int:
+        return len(self.kind)
+
+    def __getitem__(self, u: int) -> Optional[_StateIndex]:
+        k = int(self.kind[u])
+        if k == _HNSW:
+            return _StateIndex(_HNSW, graph=self.graphs[u])
+        if k == _RAW:
+            return _StateIndex(_RAW, raw_ids=self.raw(u))
+        return None
+
+    def raw(self, u: int) -> np.ndarray:
+        seg = self.patched.get(u)
+        return (seg if seg is not None
+                else self.ids[self.ptr[u]:self.ptr[u + 1]])
+
+    def set_raw(self, u: int, ids) -> None:
+        self.kind[u] = _RAW
+        self.graphs.pop(u, None)
+        self.patched[u] = np.asarray(ids, dtype=np.int64)
+
+    def set_graph(self, u: int, graph: HNSW) -> None:
+        self.kind[u] = _HNSW
+        self.patched.pop(u, None)
+        self.graphs[u] = graph
+
+    def grow(self, n: int) -> None:
+        """States ``len(self)`` .. n-1, with no index yet."""
+        add = n - len(self.kind)
+        if add > 0:
+            self.kind = np.concatenate(
+                [self.kind, np.full(add, KIND_NONE, np.int8)])
+            self.ptr = np.concatenate(
+                [self.ptr, np.full(add, self.ptr[-1], np.int64)])
+
+    def drop_ids(self, gone: np.ndarray) -> None:
+        """Remove ``gone`` from every raw set (tombstone GC)."""
+        self.flatten()
+        dead = np.isin(self.ids, gone)
+        if dead.any():
+            row = np.repeat(np.arange(len(self.kind)), np.diff(self.ptr))
+            lens = np.bincount(row[~dead], minlength=len(self.kind))
+            self.ptr = np.zeros_like(self.ptr)
+            np.cumsum(lens, out=self.ptr[1:])
+            self.ids = self.ids[~dead]
+
+    def flatten(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """``(kind, ptr, ids)`` with every change folded in and each graph
+        state's segment read from its graph (inserts add nodes to it): a
+        copy of ``kind``, and new arrays wherever a segment changed."""
+        seg = dict(self.patched)
+        for u, g in self.graphs.items():
+            seg[u] = np.asarray(g.ids, dtype=np.int64)
+        if seg:
+            us = np.fromiter(sorted(seg), np.int64, len(seg))
+            old = np.diff(self.ptr)
+            lens = old.copy()
+            lens[us] = [len(seg[int(u)]) for u in us]
+            ptr = np.zeros_like(self.ptr)
+            np.cumsum(lens, out=ptr[1:])
+            ids = np.empty(int(ptr[-1]), dtype=np.int64)
+            keep = np.ones(len(lens), dtype=bool)
+            keep[us] = False
+            ids[ranges(ptr[:-1][keep], lens[keep])] = self.ids[
+                ranges(self.ptr[:-1][keep], old[keep])]
+            for u in us.tolist():
+                ids[ptr[u]:ptr[u + 1]] = seg[u]
+            self.ptr, self.ids = ptr, ids
+            self.patched = {}
+        return self.kind.copy(), self.ptr, self.ids
 
 
 class VectorMaton:
@@ -127,8 +264,6 @@ class VectorMaton:
                     f"(expected 'tag' or 'numeric')")
         self.vectors = vectors                   # adopted into a VectorStore
         self.esam = ESAM()
-        self.inherit: List[int] = []
-        self.state_index: List[Optional[_StateIndex]] = []
         self.deleted: set = set()
         self.sequences: List = list(sequences)   # LIKE residual verification
         if attributes is not None and len(attributes) != len(sequences):
@@ -150,10 +285,22 @@ class VectorMaton:
         # measured winners survive compactions (DESIGN.md §11).  Raises
         # on an unknown plan_mode before any build work happens.
         self.planner = AdaptivePlanner(self.config.plan_mode)
-        for s in sequences:
-            self.esam.add_sequence(s)
-        self.esam.finalize()
-        self._build_state_indexes(workers=workers)
+        # milliseconds of each build phase (maintenance_stats time_*)
+        self.build_times: Dict[str, float] = {
+            "build_esam_ms": 0.0, "build_states_ms": 0.0,
+            "build_runtime_ms": 0.0}
+        with phase_span("build/esam"), _collector_paused():
+            t0 = time.perf_counter()
+            for s in sequences:
+                self.esam.add_sequence(s)
+            self.esam.finalize()
+            self.build_times["build_esam_ms"] += (
+                time.perf_counter() - t0) * 1e3
+        with phase_span("build/state_indexes"):
+            t0 = time.perf_counter()
+            self._build_state_indexes(workers=workers)
+            self.build_times["build_states_ms"] += (
+                time.perf_counter() - t0) * 1e3
         self._runtime: Optional[PackedRuntime] = self._build_runtime()
 
     def _norm_attrs(self, attrs: Optional[Dict]) -> Dict:
@@ -184,91 +331,71 @@ class VectorMaton:
     # index construction (Algorithm 3 lines 17-21)
     # ------------------------------------------------------------------ #
 
-    def _pick_inherit(self, u: int) -> int:
-        """Direct successor with the largest covered set (== |V_succ|)."""
+    def _inherit_of(self, states: Optional[Sequence[int]] = None
+                    ) -> np.ndarray:
+        """Index reuse: each of ``states``' (every state's by default)
+        direct successor with the largest covered set (== |V_succ|), -1
+        without reuse."""
+        esam = self.esam
         if not self.config.reuse:
-            return -1
-        best, best_size = -1, 0
-        for v in self.esam.trans[u].values():
-            sz = len(self.esam.state_ids(v))
-            if sz > best_size:
-                best, best_size = v, sz
-        return best
+            n = esam.num_states if states is None else len(states)
+            return np.full(n, -1, dtype=np.int64)
+        ptr, dst = esam.transitions(states)
+        if esam.id_ptr is not None:
+            size = np.diff(esam.id_ptr)[dst]
+        else:
+            size = np.fromiter((len(esam.ids[v]) for v in dst.tolist()),
+                               np.int64, len(dst))
+        return pick_inherit(np.diff(ptr), dst, size)
 
-    def _base_ids(self, u: int, h: int) -> np.ndarray:
-        vu = self.esam.state_ids(u)
-        if h == -1:
-            return vu
-        vh = self.esam.state_ids(h)
-        # V_h ⊆ V_u (DAG monotonicity) — difference by sorted merge.
-        return np.setdiff1d(vu, vh, assume_unique=True)
+    def _graph_states(self, lens: np.ndarray) -> np.ndarray:
+        """Which base sets get an HNSW graph (skip-build below T)."""
+        big = lens > 0
+        if self.config.skip_build:
+            big &= lens >= self.config.T
+        return big
 
-    def _build_one(self, u: int) -> _StateIndex:
-        h = self.inherit[u]
-        base = self._base_ids(u, h)
+    def _new_graph(self, u: int, base: np.ndarray) -> HNSW:
         cfg = self.config
-        if cfg.skip_build and len(base) < cfg.T:
-            return _StateIndex(_RAW, raw_ids=base)
-        if len(base) == 0:
-            return _StateIndex(_RAW, raw_ids=base)
         g = HNSW(self.vectors, M=cfg.M, ef_con=cfg.ef_con, metric=cfg.metric,
                  seed=cfg.seed + u)
         g.build(base)
-        return _StateIndex(_HNSW, graph=g)
+        return g
+
+    def _parallel_build(self, jobs: List[Tuple[int, np.ndarray]],
+                      workers: int) -> Dict[int, HNSW]:
+        """An HNSW over each (state, base set); ``workers`` threads build
+        them side by side (the paper's parallel construction, §4.3: base
+        sets depend only on V sets, so the graphs are independent, and
+        NumPy releases the GIL inside distance batches)."""
+        if workers <= 1 or len(jobs) <= 1:
+            return {u: self._new_graph(u, b) for u, b in jobs}
+        with ThreadPoolExecutor(workers) as pool:
+            graphs = list(pool.map(lambda job: self._new_graph(*job), jobs))
+        return {u: g for (u, _), g in zip(jobs, graphs)}
 
     def _build_state_indexes(self, workers: int = 1) -> None:
-        n = self.esam.num_states
-        self.inherit = [self._pick_inherit(u) for u in range(n)]
-        self.state_index = [None] * n
-        if workers <= 1:
-            for u in self.esam.topo_order()[::-1]:
-                self.state_index[int(u)] = self._build_one(int(u))
-            return
-        self._parallel_build(workers)
-
-    def _parallel_build(self, workers: int) -> None:
-        """Paper §4.3 'parallel construction': a concurrent ready-queue over
-        reverse topological order.  A state is ready once all its transition
-        successors are built (its base set only depends on V sets, but we
-        keep the paper's dependency schedule so online-reuse variants that
-        consult successor indexes stay correct)."""
-        n = self.esam.num_states
-        remaining = np.zeros(n, dtype=np.int64)
-        preds: List[List[int]] = [[] for _ in range(n)]
-        for u in range(n):
-            succs = self.esam.trans[u].values()
-            remaining[u] = len(succs)
-            for v in succs:
-                preds[v].append(u)
-        ready: "queue_mod.Queue[int]" = queue_mod.Queue()
-        for u in range(n):
-            if remaining[u] == 0:
-                ready.put(u)
-        done = threading.Event()
-        n_done = [0]
-
-        def worker() -> None:
-            while not done.is_set():
-                try:
-                    u = ready.get(timeout=0.05)
-                except queue_mod.Empty:
-                    continue
-                idx = self._build_one(u)
-                with self._lock:
-                    self.state_index[u] = idx
-                    n_done[0] += 1
-                    if n_done[0] == n:
-                        done.set()
-                    for p in preds[u]:
-                        remaining[p] -= 1
-                        if remaining[p] == 0:
-                            ready.put(p)
-
-        threads = [threading.Thread(target=worker) for _ in range(workers)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
+        """Algorithm 3 lines 17-21 as array passes over the finalized
+        ESAM: every state's inheritance, then every base set
+        V_u \\ V_inherit(u) in one ``csr_difference`` over the flattened
+        id lists, then a graph for each base set that reaches ``T``."""
+        esam = self.esam
+        n = esam.num_states
+        self.inherit = self._inherit_of()
+        has = self.inherit >= 0
+        h = np.where(has, self.inherit, 0)
+        h_len = np.where(has, np.diff(esam.id_ptr)[h], 0)
+        h_ptr = np.zeros(n + 1, dtype=np.int64)
+        np.cumsum(h_len, out=h_ptr[1:])
+        ptr, ids = csr_difference(
+            esam.id_ptr, esam.id_data, h_ptr,
+            esam.id_data[ranges(esam.id_ptr[h], h_len)])
+        graph = self._graph_states(np.diff(ptr))
+        kind = np.where(graph, _HNSW, _RAW).astype(np.int8)
+        jobs = [(u, ids[ptr[u]:ptr[u + 1]])
+                for u in np.nonzero(graph)[0].tolist()]
+        self.state_index = StateIndexes(kind, ptr, ids,
+                                        self._parallel_build(jobs, workers))
 
     # ------------------------------------------------------------------ #
     # query processing (Algorithm 3 Query)
@@ -285,7 +412,11 @@ class VectorMaton:
     def _build_runtime(self) -> PackedRuntime:
         """One full re-flatten = one generation.  Counted: the churn
         acceptance criterion is builds == compactions, not inserts."""
-        rt = PackedRuntime.build(self, generation=self._gen_seq)
+        with phase_span("build/runtime"):
+            t0 = time.perf_counter()
+            rt = PackedRuntime.build(self, generation=self._gen_seq)
+            self.build_times["build_runtime_ms"] += (
+                time.perf_counter() - t0) * 1e3
         self._gen_seq += 1
         self.runtime_builds += 1
         return rt
@@ -433,59 +564,70 @@ class VectorMaton:
         self.attributes.append(self._norm_attrs(attributes))
         self._vec_store.append(vector)
         view = self.vectors
-        for si in self.state_index:
-            if si is not None and si.kind == _HNSW:
-                si.graph.vectors = view          # re-point at the live view
+        for g in self.state_index.graphs.values():
+            g.vectors = view                     # re-point at the live view
         rt = self._runtime
         delta = rt.delta if rt is not None else None
         if rt is not None:
             rt.vectors = view
         old_n = self.esam.num_states
         self.esam.add_sequence(sequence)
-        self.esam.finalize()
         n = self.esam.num_states
+        ids = self.esam.ids
         # new states (created by this sequence): fresh indexes.  They are
         # past the generation's state watermark, so the compiler answers
         # them from their live ESAM V sets — no delta record needed.
-        self.inherit.extend([-1] * (n - old_n))
-        self.state_index.extend([None] * (n - old_n))
+        self.inherit = np.concatenate(
+            [self.inherit, np.full(n - old_n, -1, np.int64)])
+        si = self.state_index
+        si.grow(n)
+        clones = np.asarray([u for u in range(old_n, n) if len(ids[u]) > 1],
+                            dtype=np.int64)
         for u in range(old_n, n):
-            vu = self.esam.state_ids(u)
-            if len(vu) > 1:
-                # clone: recompute inheritance against current successors
-                self.inherit[u] = self._pick_inherit(u)
-                self.state_index[u] = self._build_one(u)
-                if (delta is not None
-                        and self.state_index[u].kind == _HNSW):
+            if len(ids[u]) <= 1:
+                si.set_raw(u, [i])
+        if len(clones):
+            # clones: inheritance against current successors, base sets
+            # by the build's own difference
+            inherit = self._inherit_of(clones.tolist())
+            self.inherit[clones] = inherit
+            ptr, base = csr_difference(
+                *lists_csr([ids[u] for u in clones.tolist()]),
+                *lists_csr([ids[h] if h >= 0 else ()
+                            for h in inherit.tolist()]))
+            graph = self._graph_states(np.diff(ptr))
+            for j, u in enumerate(clones.tolist()):
+                seg = base[ptr[j]:ptr[j + 1]]
+                if not graph[j]:
+                    si.set_raw(u, seg)
+                    continue
+                si.set_graph(u, self._new_graph(u, seg))
+                if delta is not None:
                     # a graph born after the freeze: delete() must reach
                     # it, and compaction should fold it into service
                     delta.fresh_graph_states.add(u)
-            else:
-                self.state_index[u] = _StateIndex(
-                    _RAW, raw_ids=np.asarray([i], dtype=np.int64))
         # affected old states: those whose V gained i
         for u in range(old_n):
-            vu = self.esam.state_ids(u)
-            if len(vu) == 0 or vu[-1] != i:
+            vu = ids[u]
+            if not vu or vu[-1] != i:
                 continue
-            h = self.inherit[u]
-            if h != -1:
-                vh = self.esam.state_ids(h)
-                if len(vh) and vh[-1] == i:
-                    continue  # coverage flows up the chain
-            idx = self.state_index[u]
-            if idx is None:
-                self.state_index[u] = _StateIndex(
-                    _RAW, raw_ids=np.asarray([i], dtype=np.int64))
-            elif idx.kind == _RAW:
-                idx.raw_ids = np.append(idx.raw_ids, i)
+            h = int(self.inherit[u])
+            if h != -1 and ids[h] and ids[h][-1] == i:
+                continue  # coverage flows up the chain
+            kind = si.kind[u]
+            if kind == KIND_NONE:
+                si.set_raw(u, [i])
+            elif kind == _RAW:
+                raw = np.append(si.raw(u), i)
                 if (not self.config.skip_build
-                        or len(idx.raw_ids) >= 4 * self.config.T):
-                    self.state_index[u] = self._promote(idx.raw_ids, u)
+                        or len(raw) >= 4 * self.config.T):
+                    si.set_graph(u, self._promote(raw, u))
                     if delta is not None:
                         delta.fresh_graph_states.add(u)
+                else:
+                    si.set_raw(u, raw)
             else:
-                idx.graph.add(i)
+                si.graphs[u].add(i)
                 if delta is not None:
                     # keep the delete fan-out map fresh incrementally; a
                     # post-freeze graph (promotion/clone) is absent from
@@ -542,31 +684,18 @@ class VectorMaton:
     _GRAPH_GC_FRAC = 0.5
 
     def _gc_tombstones(self) -> None:
-        gone = np.fromiter(self.deleted, dtype=np.int64)
-        for u, idx in enumerate(self.state_index):
-            if idx is None:
+        si = self.state_index
+        si.drop_ids(np.fromiter(self.deleted, dtype=np.int64))
+        for u, g in list(si.graphs.items()):
+            dead = g._deleted & set(int(x) for x in g.ids)
+            if len(dead) <= self._GRAPH_GC_FRAC * max(1, len(g.ids)):
                 continue
-            if idx.kind == _RAW:
-                if len(idx.raw_ids):
-                    keep = ~np.isin(idx.raw_ids, gone)
-                    if not keep.all():
-                        idx.raw_ids = idx.raw_ids[keep]
+            live = np.asarray([x for x in g.ids if x not in dead],
+                              dtype=np.int64)
+            if len(live) < max(1, self.config.T):
+                si.set_raw(u, live)
             else:
-                g = idx.graph
-                dead = g._deleted & set(int(x) for x in g.ids)
-                if len(dead) <= self._GRAPH_GC_FRAC * max(1, len(g.ids)):
-                    continue
-                live = np.asarray([x for x in g.ids if x not in dead],
-                                  dtype=np.int64)
-                if len(live) < max(1, self.config.T):
-                    self.state_index[u] = _StateIndex(_RAW, raw_ids=live)
-                else:
-                    ng = HNSW(self.vectors, M=self.config.M,
-                              ef_con=self.config.ef_con,
-                              metric=self.config.metric,
-                              seed=self.config.seed + u)
-                    ng.build(live)
-                    self.state_index[u] = _StateIndex(_HNSW, graph=ng)
+                si.set_graph(u, self._new_graph(u, live))
 
     def maintenance_stats(self) -> Dict[str, int]:
         """Write-path accounting (generation / delta / compaction counters
@@ -587,7 +716,10 @@ class VectorMaton:
             "vector_reallocations": self._vec_store.reallocations,
             "vector_bytes_copied": self._vec_store.bytes_copied,
             "deleted": len(self.deleted),
+            "esam_symbols": self.esam.total_symbols,
         }
+        for key, val in self.build_times.items():
+            out[f"time_{key}"] = val
         for key, val in ops.launch_stats().items():
             out[f"launch_{key}"] = val
         if rt is not None:
@@ -601,21 +733,21 @@ class VectorMaton:
                 out[f"sq8_{key}"] = val
             for key, val in rt.wave_times.items():
                 out[f"time_{key}"] = val
+            for key, val in rt.residual_stats.items():
+                out[f"residual_{key}"] = val
         # adaptive-planner trace (DESIGN.md §11): estimates vs observed,
         # strategy switches, cache-replayed winners
         out.update(self.planner.stats())
         return out
 
-    def _promote(self, raw_ids: np.ndarray, u: int) -> _StateIndex:
+    def _promote(self, raw_ids: np.ndarray, u: int) -> HNSW:
         """Raw -> HNSW promotion once a raw set outgrows 4*T (paper §5): the
         brute-force sweep over the set now costs more than a graph search,
         so rebuild it as a graph against the packed runtime."""
-        g = HNSW(self.vectors, M=self.config.M, ef_con=self.config.ef_con,
-                 metric=self.config.metric, seed=self.config.seed + u)
-        g.build(raw_ids)
+        g = self._new_graph(u, raw_ids)
         for vid in self.deleted & set(int(x) for x in raw_ids):
             g.mark_deleted(vid)
-        return _StateIndex(_HNSW, graph=g)
+        return g
 
     def delete(self, vector_id: int) -> None:
         """Lazy deletion (paper §5): mark and filter at query time.  The
@@ -644,23 +776,20 @@ class VectorMaton:
     def size_entries(self) -> int:
         """Paper's index-size metric: stored ID entries + graph edge slots +
         automaton states/transitions."""
-        s = self.esam.num_states + self.esam.num_transitions
-        for idx in self.state_index:
-            if idx is not None:
-                s += idx.size_entries
-        return s
+        si = self.state_index
+        kind, ptr, _ = si.flatten()
+        raw = int(np.diff(ptr)[kind == _RAW].sum())
+        return (self.esam.num_states + self.esam.num_transitions + raw
+                + sum(g.size_entries for g in si.graphs.values()))
 
     def stats(self) -> Dict[str, int]:
-        n_raw = sum(1 for i in self.state_index
-                    if i is not None and i.kind == _RAW)
-        n_hnsw = sum(1 for i in self.state_index
-                     if i is not None and i.kind == _HNSW)
+        kind = self.state_index.kind
         return {
             "states": self.esam.num_states,
             "transitions": self.esam.num_transitions,
             "total_id_entries": self.esam.total_id_entries(),
-            "raw_states": n_raw,
-            "hnsw_states": n_hnsw,
+            "raw_states": int((kind == _RAW).sum()),
+            "hnsw_states": int((kind == _HNSW).sum()),
             "size_entries": self.size_entries(),
             "total_symbols": self.esam.total_symbols,
         }

@@ -25,6 +25,7 @@ from __future__ import annotations
 import string
 import zlib
 from dataclasses import dataclass
+from statistics import NormalDist
 from typing import List, Tuple
 
 import numpy as np
@@ -208,3 +209,45 @@ def sample_patterns(seqs: List[str], length: int, count: int,
         i = rng.integers(0, len(s) - length + 1)
         out.append(s[i:i + length])
     return out
+
+
+# --------------------------------------------------------------------- #
+# Swiss-Prot-shaped protein sequences (the motif-search deployment)
+#
+# UniProtKB/Swiss-Prot release statistics: about 570K reviewed entries,
+# mean length about 360 residues, and the amino-acid composition below
+# (percent of all residues).  Residues are drawn independently at that
+# composition.  Lengths are the quantiles, at (i + 1/2) / n, of a
+# log-normal with the published mean and an assumed median of 300, in a
+# seeded order: every seed holds as many residues, so the index does
+# the same work whatever the seed.  Motifs (PROSITE patterns) then
+# occur at the rates independence gives: RGD in about 7 % of rows, KDEL
+# as a C-terminus in about 0.01 %.
+# --------------------------------------------------------------------- #
+
+SWISSPROT_COMPOSITION = {
+    "A": 8.25, "R": 5.53, "N": 4.06, "D": 5.46, "C": 1.38, "Q": 3.93,
+    "E": 6.72, "G": 7.07, "H": 2.27, "I": 5.91, "L": 9.65, "K": 5.80,
+    "M": 2.41, "F": 3.86, "P": 4.74, "S": 6.64, "T": 5.35, "W": 1.10,
+    "Y": 2.92, "V": 6.86}
+SWISSPROT_MEAN_LEN = 360.0
+SWISSPROT_MEDIAN_LEN = 300.0
+
+
+def swissprot_sequences(n: int, seed: int) -> List[str]:
+    """``n`` protein strings of independent residues at Swiss-Prot's
+    composition, on a seed stream of their own.  Their lengths are the
+    ``n`` quantiles of a log-normal (mean 360, median 300, at least 2
+    residues), in the seed's order: every seed gives the same lengths
+    and as many residues."""
+    rng = np.random.default_rng(np.random.SeedSequence([seed, 0x5B07]))
+    sigma = np.sqrt(2.0 * np.log(SWISSPROT_MEAN_LEN / SWISSPROT_MEDIAN_LEN))
+    z = np.asarray([NormalDist().inv_cdf((i + 0.5) / n) for i in range(n)])
+    lens = rng.permutation(np.maximum(2, np.rint(
+        SWISSPROT_MEDIAN_LEN * np.exp(sigma * z))).astype(np.int64))
+    letters = "".join(SWISSPROT_COMPOSITION)
+    p = np.asarray(list(SWISSPROT_COMPOSITION.values()), np.float64)
+    codes = rng.choice(len(letters), size=int(lens.sum()), p=p / p.sum())
+    text = np.frombuffer(letters.encode(), np.uint8)[codes].tobytes().decode()
+    ends = np.cumsum(lens).tolist()
+    return [text[e - m:e] for e, m in zip(ends, lens.tolist())]

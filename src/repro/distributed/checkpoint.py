@@ -41,6 +41,7 @@ import numpy as np
 
 def save_vectormaton(vm, path: str,
                      extra_meta: Optional[Dict] = None) -> None:
+    from ..core.packed import ranges
     from ..core.vectormaton import _RAW
     tmp = path + ".tmp"
     if os.path.exists(tmp):
@@ -67,31 +68,21 @@ def save_vectormaton(vm, path: str,
         np.save(os.path.join(tmp, "attributes.npy"),
                 np.asarray(attrs, dtype=object), allow_pickle=True)
     # state indexes: raw sets into one CSR; graphs into per-state npz
-    raw_ptr = [0]
-    raw_data: List[np.ndarray] = []
-    kinds = np.full(len(vm.state_index), -1, dtype=np.int8)
-    graph_states = []
-    for u, idx in enumerate(vm.state_index):
-        if idx is None:
-            raw_ptr.append(raw_ptr[-1])
-            continue
-        if idx.kind == _RAW:
-            kinds[u] = 0
-            raw_data.append(idx.raw_ids)
-            raw_ptr.append(raw_ptr[-1] + len(idx.raw_ids))
-        else:
-            kinds[u] = 1
-            raw_ptr.append(raw_ptr[-1])
-            graph_states.append(u)
-            np.savez_compressed(os.path.join(tmp, f"graph_{u}.npz"),
-                                **idx.graph.pack_full())
+    kinds, ptr, ids = vm.state_index.flatten()
+    lens = np.where(kinds == _RAW, np.diff(ptr), 0)
+    raw_ptr = np.zeros(len(kinds) + 1, dtype=np.int64)
+    np.cumsum(lens, out=raw_ptr[1:])
+    raw_data = ids[ranges(ptr[:-1], lens)]
+    graph_states = sorted(vm.state_index.graphs)
+    for u in graph_states:
+        np.savez_compressed(os.path.join(tmp, f"graph_{u}.npz"),
+                            **vm.state_index.graphs[u].pack_full())
     np.savez_compressed(
         os.path.join(tmp, "states.npz"),
         kinds=kinds,
         inherit=np.asarray(vm.inherit, dtype=np.int64),
-        raw_ptr=np.asarray(raw_ptr, dtype=np.int64),
-        raw_data=(np.concatenate(raw_data) if raw_data
-                  else np.empty(0, np.int64)),
+        raw_ptr=raw_ptr,
+        raw_data=raw_data,
         deleted=np.asarray(sorted(vm.deleted), dtype=np.int64),
         graph_states=np.asarray(graph_states, dtype=np.int64),
         schema=np.asarray(json.dumps(getattr(vm.config, "schema", None)
@@ -138,8 +129,7 @@ def load_checkpoint_meta(path: str) -> Dict:
 def load_vectormaton(cls, path: str):
     from ..core.esam import ESAM
     from ..core.hnsw import HNSW
-    from ..core.vectormaton import (VectorMatonConfig, _HNSW, _RAW,
-                                    _StateIndex)
+    from ..core.vectormaton import StateIndexes, VectorMatonConfig, _HNSW
     esam_arrays = dict(np.load(os.path.join(path, "esam.npz"),
                                allow_pickle=True))
     states = np.load(os.path.join(path, "states.npz"))
@@ -171,10 +161,12 @@ def load_vectormaton(cls, path: str):
         len(vm.sequences) - len(vm.attributes)))
     vm.esam = ESAM.from_arrays(esam_arrays)
     vm.esam.finalize()
-    vm.inherit = states["inherit"].tolist()
+    vm.inherit = states["inherit"].astype(np.int64)
     vm.deleted = set(int(x) for x in states["deleted"])
     vm._lock = threading.Lock()
     vm._compact_lock = threading.Lock()
+    vm.build_times = {"build_esam_ms": 0.0, "build_states_ms": 0.0,
+                      "build_runtime_ms": 0.0}
     # fresh adaptive planner (cost-model EWMAs are host-local runtime
     # measurements — deliberately not persisted; calibration defaults
     # re-seed it and feedback re-accumulates on the restored host)
@@ -188,30 +180,20 @@ def load_vectormaton(cls, path: str):
     vm.n_compactions = int(meta[3]) if meta is not None else 0
     vm.runtime_builds = int(meta[4]) if meta is not None else 0
     kinds = states["kinds"]
-    raw_ptr = states["raw_ptr"]
-    raw_data = states["raw_data"]
-    vm.state_index = []
-    for u in range(len(kinds)):
-        if kinds[u] == -1:
-            vm.state_index.append(None)
-        elif kinds[u] == 0:
-            vm.state_index.append(_StateIndex(
-                _RAW, raw_ids=raw_data[raw_ptr[u]:raw_ptr[u + 1]].copy()))
-        else:
-            g = HNSW.from_packed(
-                vm.vectors,
-                dict(np.load(os.path.join(path, f"graph_{u}.npz"))))
-            vm.state_index.append(_StateIndex(_HNSW, graph=g))
+    vm.state_index = StateIndexes(kinds, states["raw_ptr"],
+                                  states["raw_data"], {
+        int(u): HNSW.from_packed(vm.vectors, dict(np.load(
+            os.path.join(path, f"graph_{int(u)}.npz"))))
+        for u in np.nonzero(kinds == _HNSW)[0]})
     # Re-apply tombstones into every per-state graph whose base contains a
     # deleted id.  Graphs persist their own deleted sets, but a checkpoint
     # written by an older saver (or edited by hand) may carry the global
     # set only — the union is idempotent and restores the invariant that
     # graph searches skip tombstones in-scan.
     if vm.deleted:
-        for idx in vm.state_index:
-            if idx is not None and idx.kind == _HNSW:
-                for vid in vm.deleted & set(int(x) for x in idx.graph.ids):
-                    idx.graph.mark_deleted(vid)
+        for g in vm.state_index.graphs.values():
+            for vid in vm.deleted & set(int(x) for x in g.ids):
+                g.mark_deleted(vid)
     # restored indexes flatten straight back into the packed query runtime —
     # no rebuild, same restart path the serving tier uses after a failure;
     # the rebuilt runtime re-derives the device tombstone mask from

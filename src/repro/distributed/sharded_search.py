@@ -43,6 +43,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..core.packed import ranges
+
 f32 = jnp.float32
 
 _EMPTY_I = np.empty(0, np.int64)
@@ -184,9 +186,11 @@ class ShardedDeviceIndex:
         base_ids = np.asarray(runtime.base_ids, dtype=np.int64)
         # n_csr counts chain states PLUS the attribute pseudo-segments
         # appended at build time — both address the same shard-local CSR
-        n_csr = len(runtime.base_ptr) - 1
-        state_of = np.repeat(np.arange(n_csr, dtype=np.int64),
-                             np.diff(runtime.base_ptr))
+        n_csr = runtime.n_csr
+        state_of = np.empty(len(base_ids), dtype=np.int64)
+        lens = runtime.base_hi - runtime.base_lo
+        state_of[ranges(runtime.base_lo, lens)] = np.repeat(
+            np.arange(n_csr, dtype=np.int64), lens)
         resident = base_ids < n
         ids_r, st_r = base_ids[resident], state_of[resident]
         owner = ids_r // self.local_n
@@ -226,9 +230,8 @@ class ShardedDeviceIndex:
         # members keep their ranks so overflow respects the window too.
         self._seg_ranks: Dict[int, List[np.ndarray]] = {}
         self._rank_overflow: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
-        ptr_g = np.asarray(runtime.base_ptr, dtype=np.int64)
         for u in range(runtime.n_states, n_csr):
-            lo, hi = int(ptr_g[u]), int(ptr_g[u + 1])
+            lo, hi = int(runtime.base_lo[u]), int(runtime.base_hi[u])
             seg = base_ids[lo:hi]
             ranks = np.arange(hi - lo, dtype=np.int64)
             rm = seg < n
@@ -760,15 +763,16 @@ def sharded_plan_dispatch(mesh: Mesh, base, runtime, queries, plan,
                      sh.csr_local)
         dv = gv = None
         t_sweep = time.perf_counter()
+        streaks = getattr(runtime, "_sq8_bad_streak", {})
         streak_out = (getattr(runtime, "sq8_escalate", True)
-                      and getattr(runtime, "_sq8_bad_streak", 0)
+                      and streaks.get(None, 0)
                       >= getattr(runtime, "SQ8_MAX_STREAK", 3))
         if (sh.quant is not None and not streak_out
                 and sq8_supported(k, d_dim, metric)):
             # quantized sweep + per-shard certificate; a failed batch
             # escalates to the fp32 sweep below (exactness contract),
             # and a streak of failures flips the runtime to fp32
-            # outright (same adaptive policy as the single-chip path)
+            # outright (the single-chip path's policy for plain buckets)
             kq = min(128, max(k, k * max(1, min(4, 128 // max(k, 1)))))
             fn = _sweep_fn_sq8(mesh, axis, n_desc, k, kq, metric,
                                sh.local_n)
@@ -779,11 +783,11 @@ def sharded_plan_dispatch(mesh: Mesh, base, runtime, queries, plan,
                 pass          # approximate point: trust the rerank
             elif int(bad):
                 runtime.sq8_stats["escalations"] += 1
-                runtime._sq8_bad_streak += 1
+                streaks[None] = streaks.get(None, 0) + 1
                 dv = gv = None
             else:
                 runtime.sq8_stats["certified"] += 1
-                runtime._sq8_bad_streak = 0
+                streaks[None] = 0
         elif sh.quant is not None:
             runtime.sq8_stats["fallbacks"] += 1
         if dv is None:

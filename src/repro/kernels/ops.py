@@ -36,9 +36,11 @@ from .distance_topk import (distance_topk, distance_topk_descriptors,
                             distance_topk_segmented, packed_int_sizes,
                             segmented_dense_topk)
 from .pairwise import pairwise_distance
-from .tuning import default_impl, default_interpret, select_tiles
+from .tuning import MAX_BLOCK_N, default_impl, default_interpret, \
+    select_tiles
 
 _LANE = 128
+DESC_FLOOR = 64     # descriptors a floored launch holds before doubling
 
 
 # --------------------------------------------------------------------- #
@@ -262,18 +264,52 @@ def topk_segmented_desc(vectors: jax.Array, base_ids: jax.Array,
     return vals, gids
 
 
-def descriptor_extents(q: int, desc_lens: np.ndarray, n_res: int,
-                       n_ship: int) -> Tuple[int, int, int, int]:
-    """Bucketed extents ``(qp, n_desc, tr, ts)`` of a descriptor launch:
-    query rows, then the descriptor region, the resident tail and the
-    shipped tail of the flat candidate layout."""
-    nd_real = int(desc_lens.sum()) if len(desc_lens) else 0
-    return bucket(q), bucket(nd_real), bucket(n_res), bucket(n_ship)
+def floored(q: int, n_descriptors: int, n_res: int,
+            chains: bool = False) -> bool:
+    """Whether a descriptor launch takes the shape floors: the runtime's
+    chain covers can walk several states (``chains``: rows with
+    sequences of their own), or its query rows carry more descriptors
+    than rows, or it has a resident tail (masked scans).  Such waves mix
+    many small covers, and the sums of their extents would otherwise
+    pick a program per mix; on the floors only the candidate region
+    moves, so each predicate's own bursts compile every program.  A wave
+    of one segment per query row and no tail in a runtime without chains
+    (a label per row and per request) keeps plain power-of-two buckets:
+    it is made of a few large segments, whose bursts compile every shape
+    it takes, and the floors' padding cost its large extents 9-12 % of
+    the median latency in label deployments on a TPU v5e."""
+    return chains or n_res > 0 or n_descriptors > q
+
+
+def descriptor_extents(q: int, desc_lens, n_res: int, n_ship: int,
+                       chains: bool = False
+                       ) -> Tuple[int, int, int, int, int]:
+    """Bucketed extents ``(qp, n_desc, tr, ts, dp)`` of a descriptor
+    launch: query rows, then the descriptor region, the resident tail
+    and the shipped tail of the flat candidate layout, and the number of
+    descriptors.  In a ``floored`` wave the resident tail and the
+    descriptor count have floors that a wave seldom passes, so a wave
+    that mixes chain covers and masked scans keeps the programs its
+    predicates' own bursts compiled.  The tail's floor is one whole
+    column tile (``MAX_BLOCK_N``): the tile must divide the flat extent,
+    and a smaller floor beside a power-of-two descriptor region would
+    shrink it.  The descriptor floor (``DESC_FLOOR``) holds a wave of a
+    few dozen chain covers at the two or three runs ``chain_layout``
+    leaves each."""
+    nd_real = int(np.sum(desc_lens)) if len(desc_lens) else 0
+    n_desc = bucket(nd_real)
+    if not floored(q, len(desc_lens), n_res, chains):
+        return (bucket(q), n_desc, 0, bucket(n_ship),
+                bucket(len(desc_lens), 8) if n_desc else 0)
+    return (bucket(q), n_desc, bucket(max(n_res, 1), MAX_BLOCK_N),
+            bucket(n_ship),
+            bucket(len(desc_lens), DESC_FLOOR) if n_desc else 0)
 
 
 def scan_pairs(qseg: np.ndarray, desc_lens: np.ndarray,
                desc_owners: np.ndarray, tail_res_owners: np.ndarray,
-               tail_ship_owners: np.ndarray) -> Tuple[int, int]:
+               tail_ship_owners: np.ndarray, chains: bool = False
+               ) -> Tuple[int, int]:
     """(pairs computed, pairs needed) of one descriptor launch, fp32 or
     SQ8.  The kernels' grid evaluates every padded query row against
     every padded candidate slot: the owner mask only discards a pair's
@@ -281,8 +317,9 @@ def scan_pairs(qseg: np.ndarray, desc_lens: np.ndarray,
     candidates its owner's descriptors and tails name (tombstones
     inside a frozen segment included: the host does not look inside
     segments)."""
-    qp, n_desc, tr, ts = descriptor_extents(
-        len(qseg), desc_lens, len(tail_res_owners), len(tail_ship_owners))
+    qp, n_desc, tr, ts, _ = descriptor_extents(
+        len(qseg), desc_lens, len(tail_res_owners), len(tail_ship_owners),
+        chains)
     m = int(qseg.max()) + 1 if len(qseg) else 0
     rows = (np.bincount(desc_owners, weights=desc_lens, minlength=m)[:m]
             + np.bincount(tail_res_owners, minlength=m)[:m]
@@ -292,18 +329,18 @@ def scan_pairs(qseg: np.ndarray, desc_lens: np.ndarray,
 
 def pad_descriptor_batch(x, qseg, desc_starts, desc_lens, desc_owners,
                          tail_res_ids, tail_res_owners, tail_ship_ids,
-                         tail_ship_rows, tail_ship_owners):
+                         tail_ship_rows, tail_ship_owners,
+                         chains: bool = False):
     """Bucket-pad the host-side inputs of a descriptor launch (shared by
     the fp32 and SQ8 scans) into the two host buffers one
     ``jax.device_put`` ships (layout: ``distance_topk.packed_int_sizes``):
     ``floats``, the query rows then the shipped tail's rows, and
     ``ints``, every planning integer end to end.  Returns ``((floats,
     ints), key)`` with the shape bucket key ``(qp, n_desc, tr, ts, dp,
-    d)``, which fixes every offset."""
+    d)``, which fixes every offset; ``chains`` as in ``floored``."""
     q, d = x.shape
-    qp, n_desc, tr, ts = descriptor_extents(q, desc_lens, len(tail_res_ids),
-                                            len(tail_ship_ids))
-    dp = bucket(len(desc_starts), 8) if n_desc else 0
+    qp, n_desc, tr, ts, dp = descriptor_extents(
+        q, desc_lens, len(tail_res_ids), len(tail_ship_ids), chains)
     if n_desc + tr + ts == 0:
         raise ValueError("descriptor launch with no candidates")
     key = (qp, n_desc, tr, ts, dp, d)
@@ -426,6 +463,40 @@ def topk_segmented_numpy(x: np.ndarray, y: np.ndarray, qseg: np.ndarray,
         vals[r, :m] = v[0][valid]
         idx[r, :m] = cols[li[0][valid]]
     return vals, idx
+
+
+# --------------------------------------------------------------------- #
+# residual verification: a prefilter ranked on device
+# --------------------------------------------------------------------- #
+
+@functools.partial(jax.jit, static_argnames=("metric",))
+def rank_candidates(vectors: jax.Array, x: jax.Array, cand: jax.Array,
+                    n_valid, tail_pos: jax.Array, tail_rows: jax.Array, *,
+                    metric: str = "l2"):
+    """Every query row's candidates in ascending distance, for residual
+    verification: ``x`` (Q, d) query rows, ``cand`` (C,) row ids of which
+    the first ``n_valid`` are real, gathered from the resident
+    ``vectors`` except at ``tail_pos``, whose rows ``tail_rows`` ship
+    from the host (inserts past the upload watermark; padding positions
+    point past C).  Returns (Q, C) distances and positions into
+    ``cand``, padding last at +inf.  Q, C and the tail come bucketed, so
+    a predicate's prefilter size fixes one program; ``n_valid`` is
+    traced, not a shape."""
+    with jax.named_scope("vm/residual_rank"):
+        y = vectors[jnp.minimum(cand, vectors.shape[0] - 1)]
+        if tail_rows.shape[0]:
+            y = y.at[tail_pos].set(tail_rows, mode="drop")
+        xy = jnp.matmul(x, y.T, precision=jax.lax.Precision.HIGHEST)
+        if metric == "l2":
+            d = jnp.maximum(jnp.sum(x * x, 1, keepdims=True)
+                            + jnp.sum(y * y, 1) - 2.0 * xy, 0.0)
+        else:
+            d = -xy
+        pos = jnp.broadcast_to(jnp.arange(cand.shape[0], dtype=jnp.int32),
+                               d.shape)
+        d = jnp.where(pos < n_valid, d, jnp.inf)
+        return jax.lax.sort((d, pos), dimension=1, is_stable=True,
+                            num_keys=1)
 
 
 # --------------------------------------------------------------------- #

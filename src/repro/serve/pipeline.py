@@ -93,7 +93,10 @@ class PipelinedExecutor:
       * ``planner_wait_ms``  — executor thread blocked waiting for the
         planner (positive = planning is the bottleneck);
       * ``pipeline_replans`` — waves replanned after a staleness reject;
-      * ``pipeline_waves`` / ``pipeline_barriers`` / ``pipeline_depth``.
+      * ``pipeline_waves`` — waves dispatched with device work;
+        ``pipeline_empty_waves`` — waves whose every request is a miss
+        (no row can satisfy it), answered with no device call;
+      * ``pipeline_barriers`` / ``pipeline_depth``.
     """
 
     def __init__(self, engine, staging: bool = True) -> None:
@@ -110,7 +113,8 @@ class PipelinedExecutor:
         self._closed = False
         self._cv = threading.Condition()
         self.stats: Dict[str, float] = {
-            "pipeline_waves": 0, "pipeline_replans": 0,
+            "pipeline_waves": 0, "pipeline_empty_waves": 0,
+            "pipeline_replans": 0,
             "pipeline_barriers": 0, "device_idle_ms": 0.0,
             "planner_wait_ms": 0.0, "pipeline_depth": 0,
         }
@@ -243,7 +247,8 @@ class PipelinedExecutor:
         while True:
             try:
                 pending = self.engine.dispatch_batch(wave)
-                self.stats["pipeline_waves"] += 1
+                self.stats["pipeline_waves" if wave.plan.entries
+                           else "pipeline_empty_waves"] += 1
                 self._inflight_n += 1
                 return pending
             except ValueError as e:

@@ -37,6 +37,12 @@ def span(name: str, **ids) -> jax.profiler.TraceAnnotation:
         f"{threading.current_thread().name}/{name}", **ids)
 
 
+def phase_span(name: str) -> jax.profiler.TraceAnnotation:
+    """A profiler span named ``name`` as given, for a phase that runs on
+    no thread of the served path (``build/esam`` while an index builds)."""
+    return jax.profiler.TraceAnnotation(name)
+
+
 def _on_duration(event: str, duration_s: float, **_) -> None:
     if event not in (_LOWER, _COMPILE):
         return

@@ -47,7 +47,7 @@ def test_packed_kinds_match_state_indexes(dataset):
             continue
         want = KIND_RAW if idx.kind == _RAW else KIND_GRAPH
         assert rt.kind[u] == want
-        seg = rt.base_ids[rt.base_ptr[u]:rt.base_ptr[u + 1]]
+        seg = rt.base_ids[rt.base_lo[u]:rt.base_hi[u]]
         src = idx.raw_ids if idx.kind == _RAW else np.asarray(idx.graph.ids)
         assert np.array_equal(np.sort(seg), np.sort(np.asarray(src)))
 
@@ -66,7 +66,7 @@ def test_device_arrays_materialized_once(dataset):
     assert dev1 is not None
     vm.query_batch(q, ["ab", "a"], 5)
     assert vm.runtime._dev is dev1
-    assert dev1["base_ids"].shape[0] == int(vm.runtime.base_ptr[-1])
+    assert dev1["base_ids"].shape[0] == len(vm.runtime.base_ids)
 
 
 # --------------------------------------------------------------------- #
@@ -234,3 +234,23 @@ def test_insert_lands_in_delta_not_rebuild(dataset):
     assert nid in vm.runtime.chain_ids(vm.esam.walk("abab")).tolist()
     d2, ids2 = vm.query(vm.vectors[nid], "abab", 3)
     assert np.array_equal(ids, ids2)
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_l2_answers_carry_difference_form_distances(backend):
+    """Rows far from the origin, so the scan's expansion
+    |x|^2 + |y|^2 - 2x.y cancels most of its float32 digits: the
+    answers' distances are sum((x - y)^2) in float32, within two ulps of
+    float64."""
+    rng = np.random.default_rng(3)
+    n, d = 300, 256
+    seqs = ["".join(rng.choice(list("ab"), size=6)) for _ in range(n)]
+    vecs = (40.0 + rng.standard_normal((n, d))).astype(np.float32)
+    q = (40.0 + rng.standard_normal((4, d))).astype(np.float32)
+    vm = VectorMaton(vecs, seqs, VectorMatonConfig(T=10 ** 9,
+                                                   backend=backend))
+    for r, (dist, ids) in enumerate(vm.query_batch(q, ["a"] * 4, 10)):
+        exact = ((vecs[ids].astype(np.float64) - q[r]) ** 2).sum(1)
+        assert np.all(np.abs(dist - exact) <= 2 * np.spacing(
+            exact.astype(np.float32)))
+        assert len(ids) == 10 and all("a" in seqs[i] for i in ids)

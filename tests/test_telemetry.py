@@ -22,6 +22,7 @@ import pytest
 
 from repro.core.vectormaton import VectorMatonConfig
 from repro.kernels import ops
+from repro.kernels.tuning import MAX_BLOCK_N
 from repro.serve import telemetry
 from repro.serve.batching import ContinuousBatcher
 from repro.serve.engine import Request, RetrievalEngine
@@ -32,7 +33,7 @@ sys.path.insert(0, str(ROOT))
 from bench import tracefile  # noqa: E402
 
 DIM = 16
-PATTERNS = ["ab", "cd", "a", "bc"]
+PATTERNS = ["ab", "cd", "a", "bc", "LIKE 'a%d'"]
 
 # (thread, stage) of every span, and the parent each child nests in
 SPANS = {
@@ -46,13 +47,16 @@ SPANS = {
     ("repro-executor", "assemble"), ("repro-executor", "launch_scan"),
     ("repro-executor", "launch_merge"), ("repro-executor", "fetch_batch"),
     ("repro-executor", "sync"), ("repro-executor", "merge_host"),
-    ("repro-executor", "finish"),
+    ("repro-executor", "verify_residual"), ("repro-executor", "finish"),
 }
 PARENT = {"engine_lock": "submit", "compile_predicate": "submit",
           "assemble": "dispatch_batch", "launch_scan": "dispatch_batch",
           "launch_merge": "dispatch_batch", "sync": "fetch_batch",
-          "merge_host": "fetch_batch"}
-STAGES = tuple(sorted({stage for _, stage in SPANS}))
+          "merge_host": "fetch_batch", "verify_residual": "fetch_batch"}
+# the index build's phases, each named as it is (no thread of its own)
+BUILD_SPANS = {("build", "esam"), ("build", "state_indexes"),
+               ("build", "runtime")}
+STAGES = tuple(sorted({stage for _, stage in SPANS | BUILD_SPANS}))
 
 
 def _corpus(n, seed=0):
@@ -139,6 +143,17 @@ def _host_events(path):
     return out
 
 
+def test_build_phase_spans_in_a_traced_build(tmp_path):
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _engine(n=300)
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(tracefile.find_xplane(str(tmp_path)))
+    assert {(t, s) for t, s, *_ in events} == BUILD_SPANS
+
+
 def test_every_stage_span_on_its_thread_with_a_wave_id(traced):
     events = _host_events(traced)
     assert {(t, s) for t, s, *_ in events} == SPANS
@@ -194,12 +209,21 @@ def test_planner_and_executor_spans_cover_the_window(traced):
     assert {"repro-executor/launch_scan", "bench-server/collect"} <= labels
 
 
-def _pairs_of(vecs_seqs, patterns):
+def _pairs_of(eng, vecs_seqs, patterns):
     """(computed, needed) from the shapes: one query row per request,
-    each predicate's rows once in the descriptor region."""
+    each predicate's rows once in the descriptor region, and, where the
+    wave is floored (a runtime with chains, or more chain runs than
+    query rows), the resident tail's floor of one column tile (empty
+    here)."""
     _, seqs = vecs_seqs
     rows = {p: sum(p in s for s in seqs) for p in set(patterns)}
-    return (ops.bucket(len(patterns)) * ops.bucket(sum(rows.values())),
+    vm = eng.index
+    runs = sum(len(vm.runtime.chain_cover(vm.esam.walk(p)).raw_segments)
+               for p in set(patterns))
+    tail = (MAX_BLOCK_N if ops.floored(len(patterns), runs, 0,
+                                       vm.runtime.chains) else 0)
+    return (ops.bucket(len(patterns))
+            * (ops.bucket(sum(rows.values())) + tail),
             sum(rows[p] for p in patterns))
 
 
@@ -215,7 +239,10 @@ def test_scan_pairs_equal_the_shape_arithmetic(quantize):
     got = tuple(after[f"traffic_scan_pairs_{x}"]
                 - before[f"traffic_scan_pairs_{x}"]
                 for x in ("computed", "needed"))
-    assert got == _pairs_of(_corpus(1500), patterns)
+    # a floored shape's first SQ8 batch runs its fp32 program too
+    runs = 2 if quantize == "sq8" and eng.index.runtime.chains else 1
+    assert got == tuple(runs * x
+                        for x in _pairs_of(eng, _corpus(1500), patterns))
 
 
 def test_an_escalated_sq8_batch_counts_both_launches(monkeypatch):
@@ -234,7 +261,7 @@ def test_an_escalated_sq8_batch_counts_both_launches(monkeypatch):
     eng.query_batch(q, patterns, 5)
     st = eng.maintenance_stats()
     assert st["sq8_escalations"] == 1
-    computed, needed = _pairs_of(_corpus(1500), patterns)
+    computed, needed = _pairs_of(eng, _corpus(1500), patterns)
     assert st["traffic_scan_pairs_computed"] == 2 * computed
     assert st["traffic_scan_pairs_needed"] == 2 * needed
 
@@ -291,3 +318,22 @@ def test_a_span_without_a_profiler_raises_nothing_and_keeps_nothing():
             raise KeyError("passes through")
     assert telemetry.compile_stats() == before
     assert set(vars(telemetry)) == names
+
+
+def test_build_and_residual_counters():
+    """The build counters are set once the index is built; the residual
+    counters move with a wave that verifies a LIKE."""
+    eng = _engine(n=1500)
+    st = eng.maintenance_stats()
+    assert st["esam_symbols"] == 1500 * 6
+    for key in ("time_build_esam_ms", "time_build_states_ms",
+                "time_build_runtime_ms"):
+        assert st[key] > 0, key
+    assert st["residual_rows_verified"] == 0
+    assert st["time_residual_verify_ms"] == 0
+    q = np.random.default_rng(6).standard_normal((2, DIM)).astype(np.float32)
+    eng.query_batch(q, ["LIKE 'a%d'", "ab"], 5)
+    st = eng.maintenance_stats()
+    assert st["residual_rows_verified"] >= 5
+    assert st["time_residual_verify_ms"] > 0
+    assert st["traffic_scan_descriptors"] >= 1
