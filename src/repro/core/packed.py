@@ -72,7 +72,7 @@ import time
 import warnings
 from collections import Counter
 from dataclasses import dataclass, field, replace
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -128,6 +128,26 @@ def chain_layout(inherit: np.ndarray, depth: np.ndarray) -> np.ndarray:
         hv = nodes[heavy[nodes]]
         head[hv] = head[inherit[hv]]
     return np.lexsort((depth, head))
+
+
+def table_order(base_lo: np.ndarray, base_hi: np.ndarray,
+                base_ids: np.ndarray, n_rows: int) -> np.ndarray:
+    """The resident table's row order (DESIGN.md §2): position p holds
+    row ``order[p]``.  Each row goes to the smallest CSR segment that
+    holds it, ties to the earlier in CSR (``chain_layout``) order; the
+    segments lie in CSR order, with their rows in id order; rows no
+    segment holds go last, in id order.  A segment whose rows all went
+    to it, as every label's segment does where each row carries one
+    label, is then one run of consecutive positions."""
+    lens = np.asarray(base_hi, np.int64) - base_lo
+    segs = np.flatnonzero(lens)
+    span = int(base_hi.max()) + 1 if len(segs) else 1
+    none = np.iinfo(np.int64).max
+    best = np.full(n_rows, none, np.int64)       # min (length, start) key
+    np.minimum.at(best, base_ids[ranges(base_lo[segs], lens[segs])],
+                  np.repeat(lens[segs] * span + base_lo[segs], lens[segs]))
+    return np.argsort(np.where(best < none, best % span, span),
+                      kind="stable")
 
 
 class VectorStore:
@@ -337,6 +357,27 @@ class PendingExecution:
     queries: Optional[np.ndarray] = None   # (n_requests, d) float32
 
 
+class ScanBatch(NamedTuple):
+    """A wave's scan items flattened for one descriptor launch
+    (``PackedRuntime._assemble_scan_batch``): query rows and their
+    owners, the descriptors as CSR ``(starts, lens, owners)``, the
+    resident tail (table positions) and the shipped tail (ids, rows) with
+    their owners; ``runs``, the table position of each descriptor's first
+    row where every descriptor is one run and there is no tail (else
+    None)."""
+    q_rows: List[int]
+    q_owner: np.ndarray
+    starts: np.ndarray
+    lens: np.ndarray
+    owners: np.ndarray
+    tres: np.ndarray
+    tres_owners: np.ndarray
+    tship: np.ndarray
+    tship_owners: np.ndarray
+    rows: np.ndarray
+    runs: Optional[np.ndarray]
+
+
 def merge_sel(pools: List[List[int]], pad: int,
               rows: Optional[int] = None) -> np.ndarray:
     """The device merge's (R_pad, S) index matrix: row j lists the launch
@@ -391,10 +432,25 @@ class PackedRuntime:
         self.attr_tag: Dict[str, Dict[str, int]] = {}
         self.attributes: List[dict] = []   # live view, same as sequences
         self.delta = DeltaRuntime(len(vectors), len(kind))
+        # the resident table's position space (DESIGN.md §2): ``perm``
+        # maps a position to its row id, ``_pos`` a row id to its
+        # position, for the rows at build; rows past them keep
+        # position = id.  ``_run_breaks[i]`` counts the CSR entries up to
+        # i whose position does not follow the previous entry's, so a
+        # descriptor is one run iff it counts none (no chains only: a
+        # runtime with chains keeps every launch in the gather form)
+        self.perm = table_order(base_lo, base_hi, base_ids, len(vectors))
+        self._pos = np.empty_like(self.perm)
+        self._pos[self.perm] = np.arange(len(self.perm))
+        self._run_breaks: Optional[np.ndarray] = None
         # id -> graph states whose node set contains it (delete fan-out)
         self._id_graph_states: Optional[Dict[int, List[int]]] = None
         self._dev: Optional[dict] = None    # device cache, built once
         self._chains: Optional[bool] = None  # see ``chains``
+        if not self.chains and len(base_ids):
+            steps = np.diff(self._pos[base_ids]) != 1
+            self._run_breaks = np.concatenate(
+                [[0], np.cumsum(steps)]).astype(np.int32)
         self._dev_n = 0                     # vector count at upload time
         # predicate key -> (delta version at compile, compiled predicate,
         # planner-measured winning strategy at compile — a later measured
@@ -434,7 +490,10 @@ class PackedRuntime:
             # fetch
             "host_device_calls": 0,
             # (start, length, owner) descriptors the scan launches ship
-            "scan_descriptors": 0}
+            "scan_descriptors": 0,
+            # descriptor launches (fp32 and SQ8), and those of them in
+            # the in-place form (``_scan_fp32``)
+            "scan_launches": 0, "scan_inplace_launches": 0}
         # residual verification: candidate rows checked against their
         # predicate on the host (time in wave_times residual_verify_ms)
         self.residual_stats: Dict[str, int] = {"rows_verified": 0}
@@ -558,11 +617,40 @@ class PackedRuntime:
     # device residency
     # ------------------------------------------------------------------ #
 
+    def table_rows(self, n: int) -> np.ndarray:
+        """Row ids of the resident table's first ``n`` positions."""
+        m = len(self.perm)
+        return (self.perm[:n] if n <= m
+                else np.concatenate([self.perm, np.arange(m, n)]))
+
+    def positions(self, ids) -> np.ndarray:
+        """Table positions of row ids (int64; ids past the build's rows
+        keep their value)."""
+        ids = np.asarray(ids, np.int64)
+        out = ids.copy()
+        m = ids < len(self._pos)
+        out[m] = self._pos[ids[m]]
+        return out
+
+    def row_ids(self, pos) -> np.ndarray:
+        """Row ids of table positions, -1 kept (int64)."""
+        pos = np.asarray(pos, np.int64)
+        out = pos.copy()
+        m = (pos >= 0) & (pos < len(self.perm))
+        out[m] = self.perm[pos[m]]
+        return out
+
     def to_device(self) -> dict:
         """Upload the packed arrays once; reused by every later batch.
         ``_dev_n`` records the row count at upload time — delta rows
         appended later are shipped per batch by the executor's
         watermark-split gather, never by re-uploading the table.
+
+        The table uploads in position space (``table_order``): row
+        ``table_rows(n)[p]`` at position p, and every device array that
+        points into it — ``base_ids``, the ``deleted`` mask, the graph
+        ids — holds positions.  The executor converts ids to positions
+        where it ships them and the answers back to ids at ``fetch``.
 
         Graph matrices upload twice over: per state (legacy per-graph
         path, parity oracle) and as size-bucketed ``(G, n_max, 2M)``
@@ -580,7 +668,7 @@ class PackedRuntime:
             dmask = np.zeros(self._dev_n, dtype=bool)
             if self.deleted:
                 gone = [i for i in self.deleted if i < self._dev_n]
-                dmask[gone] = True
+                dmask[self.positions(gone)] = True
             by_bucket: Dict[Tuple[int, int], List[int]] = {}
             for u, pk in self.graphs.items():
                 bkey = (ops.bucket(len(pk["ids"]), 8),
@@ -596,7 +684,7 @@ class PackedRuntime:
                 ent = np.zeros(g, np.int32)
                 for j, u in enumerate(states):
                     pk = self.graphs[u]
-                    ids[j, :len(pk["ids"])] = pk["ids"]
+                    ids[j, :len(pk["ids"])] = self.positions(pk["ids"])
                     lvl[j, :len(pk["level0"])] = pk["level0"]
                     ent[j] = pk["entry"][0]
                     slots[u] = (bkey, j)
@@ -605,14 +693,16 @@ class PackedRuntime:
                     "level0": jax.device_put(jnp.asarray(lvl)),
                     "entry": jax.device_put(jnp.asarray(ent)),
                 }
-            vec_dev = jax.device_put(jnp.asarray(self.vectors))
+            rows = np.asarray(self.vectors)
             self._dev = {
-                "vectors": vec_dev,
+                "vectors": jax.device_put(
+                    rows[self.table_rows(self._dev_n)]),
                 "base_ids": jax.device_put(
-                    jnp.asarray(self.base_ids, jnp.int32)),
+                    self.positions(self.base_ids).astype(np.int32)),
                 "deleted": jax.device_put(jnp.asarray(dmask)),
                 "graphs": {
-                    u: {"ids": jax.device_put(jnp.asarray(pk["ids"])),
+                    u: {"ids": jax.device_put(jnp.asarray(
+                            self.positions(pk["ids"]))),
                         "level0": jax.device_put(jnp.asarray(pk["level0"])),
                         "entry": jax.device_put(jnp.asarray(pk["entry"][0]))}
                     for u, pk in self.graphs.items()},
@@ -680,8 +770,8 @@ class PackedRuntime:
         instead — one batched scatter at the head of each sharded batch
         (``ShardedDeviceIndex.sync_tombstones``), not one per delete."""
         if self._dev is not None and vector_id < self._dev_n:
-            self._dev["deleted"] = (
-                self._dev["deleted"].at[vector_id].set(True))
+            self._dev["deleted"] = self._dev["deleted"].at[
+                int(self.positions(vector_id))].set(True)
 
     def graph_states_of(self, vector_id: int) -> List[int]:
         """Graph states whose node set contains ``vector_id``.  Built from
@@ -1001,14 +1091,15 @@ class PackedRuntime:
             self.traffic["host_device_calls"] += 1
             for j, r in enumerate(dev_only):
                 valid = mi[j] >= 0
-                out[r] = (md[j][valid], mi[j][valid].astype(np.int64))
+                out[r] = (md[j][valid], self.row_ids(mi[j][valid]))
         done = set(dev_only)
         conv: List[Optional[Tuple[np.ndarray, np.ndarray]]] = (
             [None] * len(launches))
 
         def _host_rows(li: int) -> Tuple[np.ndarray, np.ndarray]:
             if conv[li] is None:
-                conv[li] = jax.device_get(launches[li])
+                v, g = jax.device_get(launches[li])
+                conv[li] = (v, self.row_ids(g))
                 self.traffic["host_device_calls"] += 1
             return conv[li]
 
@@ -1021,8 +1112,7 @@ class PackedRuntime:
                 for li, row in dev_parts[r]:
                     v, g = _host_rows(li)
                     valid = g[row] >= 0
-                    pre.append((v[row][valid],
-                                g[row][valid].astype(np.int64)))
+                    pre.append((v[row][valid], g[row][valid]))
                 host_parts = pre + host_parts
             if not host_parts:
                 continue
@@ -1154,25 +1244,6 @@ class PackedRuntime:
                 cand, np.fromiter(self.deleted, dtype=np.int64))]
         return cand
 
-    def _device_rows(self, cand_np: np.ndarray):
-        """(len(cand), d) rows on device: base rows gathered from the
-        resident table, rows past the upload watermark (delta inserts)
-        shipped from the host per call — the delta is bounded by the
-        compaction threshold, so this stays small against the distance
-        work itself."""
-        import jax.numpy as jnp
-        dev = self.to_device()
-        dn = self._dev_n
-        cand_dev = jnp.asarray(cand_np, jnp.int32)
-        tail = cand_np >= dn
-        if not tail.any():
-            return dev["vectors"][cand_dev]
-        if dn == 0:
-            return jnp.asarray(self.vectors[cand_np])
-        y = dev["vectors"][jnp.minimum(cand_dev, dn - 1)]
-        return y.at[jnp.asarray(np.nonzero(tail)[0], jnp.int32)].set(
-            jnp.asarray(self.vectors[cand_np[tail]]))
-
     @staticmethod
     def _scan_units(scan_items) -> int:
         """Cost-model work units for a scan batch: candidate rows ranked,
@@ -1208,14 +1279,15 @@ class PackedRuntime:
         self._observe("scan", self._scan_units(scan_items),
                       time.perf_counter() - t0)
 
-    def _assemble_scan_batch(self, queries, scan_items):
+    def _assemble_scan_batch(self, queries, scan_items
+                             ) -> Optional[ScanBatch]:
         """Flatten the batch's scan items into one descriptor launch:
         frozen CSR segments become ``(start, len, owner)`` triples; tails
         split at the upload watermark into resident ids (device-gathered,
-        device-tombstoned) and shipped ids (+ their rows — only the
-        post-watermark delta ever ships).  ``use_descriptors=False``
-        demotes every segment to explicit ids (the legacy
-        candidate-upload path, kept as the parity oracle)."""
+        device-tombstoned, shipped as table positions) and shipped ids
+        (+ their rows — only the post-watermark delta ever ships).
+        ``use_descriptors=False`` demotes every segment to explicit ids
+        (the legacy candidate-upload path, kept as the parity oracle)."""
         from ..kernels import ops
         if not scan_items:
             return None
@@ -1250,7 +1322,7 @@ class PackedRuntime:
                     ship = ship[~np.isin(
                         ship, np.fromiter(self.deleted, np.int64))]
                 if len(res):
-                    tres.append(res.astype(np.int32))
+                    tres.append(self.positions(res).astype(np.int32))
                     tres_o.append(np.full(len(res), owner, np.int32))
                 if len(ship):
                     tship.append(ship.astype(np.int32))
@@ -1279,24 +1351,45 @@ class PackedRuntime:
         tf["row_bytes"] += ts * d_dim * 4
         tf["bytes_to_device"] += (qp * (d_dim * 4 + 4) + dp * 12
                                   + (tr + ts) * 8 + ts * d_dim * 4)
-        return (q_rows, np.asarray(q_owner, np.int32),
-                np.asarray(dstarts, np.int32), np.asarray(dlens, np.int32),
-                np.asarray(downers, np.int32), tres_i, tres_ow,
-                tship_i, tship_ow, rows)
+        dstarts = np.asarray(dstarts, np.int32)
+        dlens = np.asarray(dlens, np.int32)
+        return ScanBatch(
+            q_rows, np.asarray(q_owner, np.int32), dstarts, dlens,
+            np.asarray(downers, np.int32), tres_i, tres_ow, tship_i,
+            tship_ow, rows,
+            None if len(tres_i) or len(tship_i) else self._desc_runs(
+                dstarts, dlens))
 
-    def _count_scan_pairs(self, flat, launches: int = 1) -> None:
-        """Adds the pairs of ``launches`` descriptor launches of one
-        assembled batch to ``scan_pairs_*``, and their descriptors to
-        ``scan_descriptors``."""
+    def _desc_runs(self, starts: np.ndarray, lens: np.ndarray
+                   ) -> Optional[np.ndarray]:
+        """The table position of each descriptor's first row, where
+        every descriptor covers one run of consecutive positions; else
+        None (and always in a runtime with chains)."""
+        brk = self._run_breaks
+        if brk is None or not len(starts):
+            return None
+        if np.any(brk[starts + lens - 1] != brk[starts]):
+            return None
+        return self.positions(self.base_ids[starts])
+
+    def _count_scan(self, batch: ScanBatch, inplace_cols: int = 0) -> None:
+        """Adds one descriptor launch of an assembled batch to the scan
+        counters: its pairs to ``scan_pairs_*``, its descriptors to
+        ``scan_descriptors``, and the launch to ``scan_launches`` and,
+        in the in-place form (``inplace_cols`` columns), to
+        ``scan_inplace_launches``."""
         from ..kernels import ops
-        _, q_owner, _, dlens, downers, _, tres_ow, _, tship_ow, _ = flat
-        self.traffic["scan_descriptors"] += launches * len(dlens)
-        computed, needed = ops.scan_pairs(q_owner, dlens, downers, tres_ow,
-                                          tship_ow, self.chains)
-        self.traffic["scan_pairs_computed"] += launches * computed
-        self.traffic["scan_pairs_needed"] += launches * needed
+        tf = self.traffic
+        tf["scan_launches"] += 1
+        tf["scan_inplace_launches"] += inplace_cols > 0
+        tf["scan_descriptors"] += len(batch.lens)
+        computed, needed = ops.scan_pairs(
+            batch.q_owner, batch.lens, batch.owners, batch.tres_owners,
+            batch.tship_owners, self.chains, inplace_cols)
+        tf["scan_pairs_computed"] += computed
+        tf["scan_pairs_needed"] += needed
 
-    def _upload_scan_batch(self, queries, flat, with_sel: bool):
+    def _upload_scan_batch(self, queries, batch: ScanBatch, with_sel: bool):
         """Pad an assembled scan batch into its two host buffers
         (``ops.pad_descriptor_batch``) and ship them in ONE
         ``jax.device_put`` — with the device merge's index matrix when
@@ -1305,25 +1398,41 @@ class PackedRuntime:
         import jax
 
         from ..kernels import ops
-        (q_rows, q_owner, dstarts, dlens, downers, tres_i, tres_ow,
-         tship_i, tship_ow, rows) = flat
+        b = batch
         host, key = ops.pad_descriptor_batch(
-            queries[q_rows], q_owner, dstarts, dlens, downers, tres_i,
-            tres_ow, tship_i, rows, tship_ow, self.chains)
+            queries[b.q_rows], b.q_owner, b.starts, b.lens, b.owners,
+            b.tres, b.tres_owners, b.tship, b.rows, b.tship_owners,
+            self.chains)
         sel = None
         if with_sel:
             per_req: Dict[int, List[int]] = {}
-            for row, r in enumerate(q_rows):
+            for row, r in enumerate(b.q_rows):
                 per_req.setdefault(r, []).append(row)
             # a floored wave merges one row per padded query row, so its
             # number of requests never picks the merge's program
-            fixed = ops.floored(len(q_rows), len(dlens), len(tres_i),
+            fixed = ops.floored(len(b.q_rows), len(b.lens), len(b.tres),
                                 self.chains)
             sel = merge_sel([per_req[r] for r in sorted(per_req)], key[0],
                             key[0] if fixed else None)
-        batch, sel = jax.device_put((host, sel))
+        dev_batch, sel = jax.device_put((host, sel))
         self.traffic["host_device_calls"] += 1
-        return batch, key, sel
+        return dev_batch, key, sel
+
+    def _scan_fp32(self, dev_batch, key: tuple, batch: ScanBatch, k: int):
+        """One fp32 descriptor launch of an uploaded batch, in the
+        in-place form where the batch has ``runs`` (no chains, no tail,
+        every descriptor one run), else the gather form; counted in the
+        scan counters.  Returns the device (vals, positions)."""
+        from ..kernels import ops
+        dev = self.to_device()
+        cols = ops.inplace_columns(batch.runs, batch.lens, key, k,
+                                   self.accum)
+        out = ops.topk_segmented_desc(
+            dev["vectors"], dev["base_ids"], dev["deleted"], dev_batch, key,
+            k, metric=self.metric, accum=self.accum, inplace=cols > 0)
+        self.traffic["host_device_calls"] += 1
+        self._count_scan(batch, cols)
+        return out
 
     def _execute_scan_device(self, queries, scan_items, k, launches,
                              dev_parts, wave: int = -1,
@@ -1341,21 +1450,16 @@ class PackedRuntime:
             self.wave_times["upload_ms"] += (time.perf_counter() - t0) * 1e3
         if flat is None:
             return None
-        dev = self.to_device()
         with span("launch_scan", wave=wave):
             t0 = time.perf_counter()
             with span("upload", wave=wave):
                 batch, key, sel = self._upload_scan_batch(queries, flat,
                                                           with_sel)
-            v, g = ops.topk_segmented_desc(
-                dev["vectors"], dev["base_ids"], dev["deleted"], batch, key,
-                k, metric=self.metric, accum=self.accum)
-            self.traffic["host_device_calls"] += 1
+            v, g = self._scan_fp32(batch, key, flat, k)
             dt = time.perf_counter() - t0
             self.wave_times["launch_ms"] += dt * 1e3
-        self._count_scan_pairs(flat)
         self._observe("scan", self._scan_units(scan_items), dt)
-        self._emit_scan(flat[0], v, g, launches, dev_parts)
+        self._emit_scan(flat.q_rows, v, g, launches, dev_parts)
         return sel
 
     @staticmethod
@@ -1414,10 +1518,7 @@ class PackedRuntime:
         dev = self.to_device()
 
         def fp32():
-            self.traffic["host_device_calls"] += 1
-            return ops.topk_segmented_desc(
-                dev["vectors"], dev["base_ids"], dev["deleted"], batch, key,
-                k, metric=self.metric, accum=self.accum)
+            return self._scan_fp32(batch, key, flat, k)
 
         with span("launch_scan", wave=wave):
             t0 = time.perf_counter()
@@ -1425,8 +1526,8 @@ class PackedRuntime:
                 batch, key, sel = self._upload_scan_batch(queries, flat,
                                                           with_sel)
             runs = 1
-            floored = ops.floored(len(flat[0]), len(flat[3]), len(flat[5]),
-                                  self.chains)
+            floored = ops.floored(len(flat.q_rows), len(flat.lens),
+                                  len(flat.tres), self.chains)
             streak = key if floored else None
             if (self.sq8_escalate and self._sq8_bad_streak.get(streak, 0)
                     >= self.SQ8_MAX_STREAK):
@@ -1440,6 +1541,7 @@ class PackedRuntime:
                     dev["vectors"], dev["quant"], dev["base_ids"],
                     dev["deleted"], batch, key, k, overfetch=overfetch)
                 self.traffic["host_device_calls"] += 1
+                self._count_scan(flat)
                 warm = floored and key not in self._sq8_fp32_warm
                 # without ``sq8_escalate`` (the approximate operating
                 # point) the rerank is trusted and the certificate never
@@ -1465,9 +1567,8 @@ class PackedRuntime:
                     self._sq8_fp32_warm.add(key)
             dt = time.perf_counter() - t0
             self.wave_times["launch_ms"] += dt * 1e3
-        self._count_scan_pairs(flat, launches=runs)
         self._observe("scan", self._scan_units(scan_items), dt)
-        self._emit_scan(flat[0], v, g, launches, dev_parts)
+        self._emit_scan(flat.q_rows, v, g, launches, dev_parts)
         return sel
 
     # ---- graph states ------------------------------------------------- #
@@ -1536,18 +1637,19 @@ class PackedRuntime:
                 dev_parts[r].append((li, row))
 
         def compose_mask(allowed: Optional[np.ndarray]) -> np.ndarray:
-            """(dn,) bool: candidate bitmap ∧ ¬tombstones, host-composed.
-            ``None`` means tombstones-only (the clamp fallback)."""
+            """(dn,) bool over table positions: candidate bitmap ∧
+            ¬tombstones, host-composed.  ``None`` means tombstones-only
+            (the clamp fallback)."""
             dmask = np.zeros(dn, dtype=bool)
             if self.deleted:
                 gone = [i for i in self.deleted if i < dn]
                 dmask[gone] = True
             if allowed is None:
-                return ~dmask
+                return ~dmask[self.table_rows(dn)]
             am = allowed
             if len(am) < dn:
                 am = np.pad(am, (0, dn - len(am)))
-            return am[:dn] & ~dmask
+            return (am[:dn] & ~dmask)[self.table_rows(dn)]
 
         if not self.fuse_graphs:
             # legacy per-state launches (parity oracle for the fused path)
@@ -1682,7 +1784,7 @@ class PackedRuntime:
         dev = self.to_device()
         cp, d_dim = ops.bucket(len(cand)), queries.shape[1]
         ids = np.zeros(cp, np.int32)
-        ids[:len(cand)] = cand
+        ids[:len(cand)] = self.positions(cand)
         ship = np.nonzero(cand >= self._dev_n)[0]
         tp = ops.bucket(len(ship), 8) if len(ship) else 0
         tail_pos = np.full(tp, cp, np.int32)

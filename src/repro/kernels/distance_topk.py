@@ -20,7 +20,11 @@ owner-id masking, and its **descriptor mode** (DESIGN.md §3,
 on device: ``(seg_start, seg_len, owner)`` triples expand against the
 resident CSR ``base_ids`` inside the same executable, so frozen-base
 candidate ids never ship from the host — only the query rows, the
-planning integers, and the post-watermark delta tail do.
+planning integers, and the post-watermark delta tail do.  Where every
+descriptor of a launch is one run of consecutive table rows (a label's
+segment in a table laid out in segment order), its **in-place form**
+reads those rows where they lie, block by block through scalar-prefetched
+block indices, instead of gathering a copy first.
 """
 
 from __future__ import annotations
@@ -185,6 +189,73 @@ def _topk_seg_kernel(x_ref, y_ref, qseg_ref, cseg_ref, val_out_ref,
         idx_out_ref[...] = idx_scr[...]
 
 
+def _topk_inplace_kernel(blk_ref, own_ref, lo_ref, hi_ref, x_ref, y_ref,
+                         dead_ref, qseg_ref, val_out_ref, idx_out_ref,
+                         val_scr, idx_scr, *, metric: str, k: int,
+                         block_n: int, n_blocks: int, accum: str):
+    """In-place variant: column step j reads block ``blk[j]`` of the
+    resident table itself and ranks its rows at positions [lo[j], hi[j])
+    for the query rows of owner ``own[j]``, tombstones masked.  Padding
+    steps (owner -3) repeat the last real block, so no DMA is issued,
+    and do no work.  Indices are table positions."""
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        val_scr[...] = jnp.full_like(val_scr, jnp.inf)
+        idx_scr[...] = jnp.full_like(idx_scr, -1)
+
+    owner = own_ref[j]
+
+    @pl.when(owner >= 0)
+    def _scan():
+        dist = _dist_tile(x_ref[...], y_ref[...], metric, accum)
+        base = blk_ref[j] * block_n
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, block_n), 1)
+        live = ((pos >= lo_ref[j]) & (pos < hi_ref[j])
+                & jnp.logical_not(dead_ref[...]))
+        match = (qseg_ref[...] == owner) & live
+        fold_topk(val_scr, idx_scr, jnp.where(match, dist, jnp.inf), base, k)
+
+    @pl.when(j == n_blocks - 1)
+    def _emit():
+        val_out_ref[...] = val_scr[...]
+        idx_out_ref[...] = idx_scr[...]
+
+
+def _inplace_pallas_call(vectors, deleted, x, qseg, cols, k, *, metric,
+                         block_q, block_n, interpret, accum="f32"):
+    """``pallas_call`` plumbing of the in-place scan: ``cols`` is the
+    ``(blk, own, lo, hi)`` table of ``column_table``, prefetched to
+    scalar memory, whose block indices steer the table's and the
+    tombstone mask's ``BlockSpec``s."""
+    q, d = x.shape
+    n_blocks = int(cols[0].shape[0])
+    assert q % block_q == 0 and k <= LANES, (q, block_q, k)
+    kernel = functools.partial(_topk_inplace_kernel, metric=metric, k=k,
+                               block_n=block_n, n_blocks=n_blocks,
+                               accum=accum)
+    outs = topk_outputs(q, block_q)
+    spec = pl.BlockSpec((block_q, LANES), lambda i, j, *_: (i, 0))
+    vals, idx = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(q // block_q, n_blocks),
+            in_specs=[
+                pl.BlockSpec((block_q, d), lambda i, j, *_: (i, 0)),
+                pl.BlockSpec((block_n, d), lambda i, j, b, *_: (b[j], 0)),
+                pl.BlockSpec((1, block_n), lambda i, j, b, *_: (0, b[j])),
+                pl.BlockSpec((block_q, 1), lambda i, j, *_: (i, 0)),
+            ],
+            out_specs=[spec, spec],
+            scratch_shapes=outs["scratch_shapes"]),
+        out_shape=outs["out_shape"],
+        interpret=interpret,
+    )(*cols, x, vectors, deleted.reshape(1, -1), qseg)
+    return vals[:, :k], idx[:, :k]
+
+
 def _seg_pallas_call(x, y, qseg, cseg, k, *, metric, block_q, block_n,
                      interpret, valid_n, accum="f32"):
     """Shared pallas_call plumbing for the segmented kernel — used by the
@@ -270,6 +341,25 @@ def expand_descriptors(base_ids: jax.Array, starts: jax.Array,
     return cand, own
 
 
+def column_table(first: jax.Array, lens: jax.Array, owners: jax.Array,
+                 n_blocks: int, block_n: int) -> tuple:
+    """The column table ``(blk, own, lo, hi)``, each (n_blocks,), of an
+    in-place scan whose descriptor d holds the table positions [first[d],
+    first[d] + lens[d]): descriptor by descriptor, the aligned blocks of
+    ``block_n`` rows that cover its run, with its owner and bounds.
+    Columns past the last real block repeat that block with owner -3;
+    ``n_blocks`` must hold the real ones."""
+    lo_blk = first // block_n
+    cnt = jnp.where(lens > 0, (first + lens - 1) // block_n - lo_blk + 1, 0)
+    cum = jnp.cumsum(cnt)
+    col = jnp.arange(n_blocks, dtype=jnp.int32)
+    c = jnp.minimum(col, cum[-1] - 1)
+    d = jnp.sum(cum[None, :] <= c[:, None], axis=1, dtype=jnp.int32)
+    blk = lo_blk[d] + c - (cum[d] - cnt[d])
+    own = jnp.where(col < cum[-1], owners[d], -3)
+    return blk, own, first[d], first[d] + lens[d]
+
+
 def packed_int_sizes(key: tuple) -> tuple:
     """Lengths of the int32 buffer's parts for a descriptor launch with
     bucket key ``(qp, n_desc, tr, ts, dp, d)``, in order: qseg (qp),
@@ -298,7 +388,8 @@ def unpack_descriptor_batch(floats: jax.Array, ints: jax.Array,
 
 @functools.partial(jax.jit, static_argnames=("k", "n_desc", "packed",
                                              "metric", "block_q", "block_n",
-                                             "interpret", "accum", "impl"))
+                                             "interpret", "accum", "impl",
+                                             "inplace"))
 def distance_topk_descriptors(vectors: jax.Array, base_ids: jax.Array,
                               deleted: jax.Array, x: jax.Array,
                               qseg: jax.Array, starts=None, lens=None,
@@ -311,7 +402,8 @@ def distance_topk_descriptors(vectors: jax.Array, base_ids: jax.Array,
                               block_q: int = BLOCK_Q,
                               block_n: int = BLOCK_N,
                               interpret: bool = False,
-                              accum: str = "f32", impl: str = "pallas"):
+                              accum: str = "f32", impl: str = "pallas",
+                              inplace: bool = False):
     """Segmented top-k whose candidate sets are *descriptors* into the
     device-resident CSR, not host-materialized id lists.
 
@@ -338,7 +430,9 @@ def distance_topk_descriptors(vectors: jax.Array, base_ids: jax.Array,
     (``unpack_descriptor_batch``), and the arrays between are left out.
 
     Returns ``(vals, gids)`` of shape (Q, k), one row per (padded) query
-    row: distances ascending and GLOBAL candidate ids, with every
+    row: distances ascending and the candidates' rows of ``vectors`` (the
+    executor's resident table lies in position space, DESIGN.md §2, and
+    maps them back to ids), with every
     unfilled slot (+inf, -1) — no flat-position indices escape, so
     callers never map back through a host candidate array, and nothing
     is left to fix up outside the program.  The top-k core runs at k
@@ -349,18 +443,36 @@ def distance_topk_descriptors(vectors: jax.Array, base_ids: jax.Array,
     Pallas cannot compile (this container's CPU backend); assembly,
     gathers, and gid mapping are shared, so the two differ only in the
     top-k schedule.
+
+    ``inplace=True`` is the in-place form, for a launch with no tails
+    whose every descriptor covers one run of consecutive table positions
+    (``base_ids[s:s + l]`` = p, p + 1, ...): the scan reads the aligned
+    blocks of ``vectors`` that cover each run where they lie
+    (``column_table``), with no gathered copy, and the ids it returns
+    are those positions.  Its grid has the bucketed ``n_desc / block_n``
+    columns and two more per descriptor slot, which always hold the
+    runs' blocks: a run of l rows straddles at most ceil(l / block_n) + 1
+    aligned blocks.  Its XLA twin reads the same blocks by row index.
     """
     with jax.named_scope("vm/scan"):
         if packed is not None:
             (x, qseg, starts, lens, owners, tail_res_ids, tail_res_owners,
              tail_ship_ids, tail_ship_owners,
              tail_ship_rows) = unpack_descriptor_batch(x, qseg, packed)
+        kp = -(-k // 8) * 8
+        if inplace:
+            return _scan_inplace(vectors, base_ids, deleted, x, qseg,
+                                 starts, lens, owners, k, kp,
+                                 n_desc // block_n + 2 * lens.shape[0],
+                                 metric=metric,
+                                 block_q=block_q, block_n=block_n,
+                                 interpret=interpret, accum=accum,
+                                 impl=impl)
         y, cseg, gid_flat = assemble_flat_candidates(
             vectors, base_ids, deleted, starts, lens, owners, tail_res_ids,
             tail_res_owners, tail_ship_ids, tail_ship_owners, tail_ship_rows,
             n_desc)
         n = int(y.shape[0])
-        kp = -(-k // 8) * 8
         if impl == "xla":
             vals, idx = segmented_dense_topk(x, y, qseg[:, 0], cseg, kp,
                                              metric=metric)
@@ -373,6 +485,38 @@ def distance_topk_descriptors(vectors: jax.Array, base_ids: jax.Array,
         gids = jnp.where(idx >= 0, gid_flat[jnp.clip(idx, 0, n - 1)], -1)
         bad = (gids < 0) | ~jnp.isfinite(vals)
         return jnp.where(bad, jnp.inf, vals), jnp.where(bad, -1, gids)
+
+
+def _scan_inplace(vectors, base_ids, deleted, x, qseg, starts, lens, owners,
+                  k: int, kp: int, n_blocks: int, *, metric, block_q,
+                  block_n, interpret, accum, impl):
+    """The in-place form of ``distance_topk_descriptors``: each run's
+    first position is ``base_ids`` at its CSR start, and the scan reads
+    the table blocks of ``column_table``.  Returns (vals, positions)
+    with (+inf, -1) in every unfilled slot."""
+    nb = max(int(base_ids.shape[0]), 1)
+    first = base_ids[jnp.clip(starts, 0, nb - 1)].astype(jnp.int32)
+    cols = column_table(first, lens, owners, n_blocks, block_n)
+    if impl == "xla":
+        blk, own, lo, hi = cols
+        pos = (blk[:, None] * block_n
+               + jnp.arange(block_n, dtype=jnp.int32)).reshape(-1)
+        at = jnp.minimum(pos, int(vectors.shape[0]) - 1)
+        live = ((pos >= jnp.repeat(lo, block_n))
+                & (pos < jnp.repeat(hi, block_n)) & ~deleted[at])
+        cseg = jnp.where(live, jnp.repeat(own, block_n), -3)
+        vals, idx = segmented_dense_topk(x, vectors[at], qseg[:, 0], cseg,
+                                         kp, metric=metric)
+        idx = jnp.where(idx >= 0, pos[jnp.clip(idx, 0, pos.shape[0] - 1)],
+                        -1)
+    else:
+        vals, idx = _inplace_pallas_call(
+            vectors, deleted, x, qseg, cols, kp, metric=metric,
+            block_q=block_q, block_n=block_n, interpret=interpret,
+            accum=accum)
+    vals, idx = vals[:, :k], idx[:, :k]
+    bad = (idx < 0) | ~jnp.isfinite(vals)
+    return jnp.where(bad, jnp.inf, vals), jnp.where(bad, -1, idx)
 
 
 def assemble_flat_candidates(vectors, base_ids, deleted, starts, lens,

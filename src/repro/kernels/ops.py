@@ -218,11 +218,38 @@ def topk_segmented(x: jax.Array, y: jax.Array, qseg: jax.Array,
     return vals, idx
 
 
+def desc_tiles(key: Tuple, k: int, accum: str = "f32") -> Tuple[int, int]:
+    """``(block_q, block_n)`` of a descriptor launch with bucket key
+    ``key``: the flat candidate extent is fixed by the pre-bucketed
+    regions, so block_n must divide it; block_q divides the padded Q."""
+    qp, n_desc, tr, ts, _, d = key
+    bq, bn = select_tiles(qp, n_desc + tr + ts, d, k=_round_up(k, 8),
+                          itemsize=2 if accum == "bf16" else 4,
+                          divisor_n=max(n_desc + tr + ts, _LANE))
+    return min(bq, qp), bn
+
+
+def inplace_columns(runs, desc_lens, key: Tuple, k: int,
+                    accum: str = "f32") -> int:
+    """Candidate columns the in-place form of a descriptor launch
+    evaluates: the aligned ``block_n`` blocks that cover each
+    descriptor's run of table positions [runs[d], runs[d] +
+    desc_lens[d]), times block_n.  0 where there are no runs (``None``):
+    the launch then takes the gather form."""
+    if runs is None or not len(runs):
+        return 0
+    bn = desc_tiles(key, k, accum)[1]
+    runs = np.asarray(runs, np.int64)
+    last = runs + np.asarray(desc_lens, np.int64) - 1
+    return int(np.sum(last // bn - runs // bn + 1)) * bn
+
+
 def topk_segmented_desc(vectors: jax.Array, base_ids: jax.Array,
                         deleted: jax.Array, batch, key: Tuple, k: int, *,
                         metric: str = "l2",
                         interpret: bool | None = None,
-                        accum: str = "f32", impl: str | None = None
+                        accum: str = "f32", impl: str | None = None,
+                        inplace: bool = False
                         ) -> Tuple[jax.Array, jax.Array]:
     """Descriptor-driven segmented top-k: ONE launch serving many
     (query, id-set) pairs whose frozen-base candidates are ``(seg_start,
@@ -239,9 +266,12 @@ def topk_segmented_desc(vectors: jax.Array, base_ids: jax.Array,
 
     Every dynamic dimension is padded to a power-of-two bucket (``bucket``)
     so repeated batches of similar size reuse one compiled executable.
+    ``inplace`` takes the in-place form (``distance_topk_descriptors``),
+    for a launch whose descriptors are runs of table positions
+    (``inplace_columns``).
     Returns DEVICE arrays ``(vals, gids)`` of shape (qp, k), one row per
     padded query row (the caller keeps the first Q): ascending distances
-    + global candidate ids, (+inf, -1) padding.
+    + the candidates' rows of ``vectors``, (+inf, -1) padding.
     """
     if interpret is None:
         interpret = default_interpret()
@@ -250,17 +280,12 @@ def topk_segmented_desc(vectors: jax.Array, base_ids: jax.Array,
     kp = _round_up(k, 8)
     if kp > _LANE:
         raise ValueError(f"k={k} exceeds kernel max {_LANE}")
-    qp, n_desc, tr, ts, _, d = key
-    # the flat candidate extent is fixed by the pre-bucketed regions, so
-    # block_n must divide it; block_q likewise divides the padded Q
-    bq, bn = select_tiles(qp, n_desc + tr + ts, d, k=kp,
-                          itemsize=2 if accum == "bf16" else 4,
-                          divisor_n=max(n_desc + tr + ts, _LANE))
+    bq, bn = desc_tiles(key, k, accum)
     vals, gids = distance_topk_descriptors(
-        vectors, base_ids, deleted, *batch, k=k, n_desc=n_desc, packed=key,
-        metric=metric, block_q=min(bq, qp), block_n=bn,
-        interpret=interpret, accum=accum, impl=impl)
-    record_launch("desc_scan", key + (kp, metric, impl))
+        vectors, base_ids, deleted, *batch, k=k, n_desc=key[1], packed=key,
+        metric=metric, block_q=bq, block_n=bn, interpret=interpret,
+        accum=accum, impl=impl, inplace=inplace)
+    record_launch("desc_scan", key + (kp, metric, inplace, impl))
     return vals, gids
 
 
@@ -308,12 +333,14 @@ def descriptor_extents(q: int, desc_lens, n_res: int, n_ship: int,
 
 def scan_pairs(qseg: np.ndarray, desc_lens: np.ndarray,
                desc_owners: np.ndarray, tail_res_owners: np.ndarray,
-               tail_ship_owners: np.ndarray, chains: bool = False
-               ) -> Tuple[int, int]:
+               tail_ship_owners: np.ndarray, chains: bool = False,
+               inplace_cols: int = 0) -> Tuple[int, int]:
     """(pairs computed, pairs needed) of one descriptor launch, fp32 or
-    SQ8.  The kernels' grid evaluates every padded query row against
-    every padded candidate slot: the owner mask only discards a pair's
-    distance, and no tile is skipped.  A real query row needs the
+    SQ8.  The gather form's grid evaluates every padded query row
+    against every padded candidate slot: the owner mask only discards a
+    pair's distance, and no tile is skipped.  The in-place form
+    evaluates its ``inplace_cols`` columns (``inplace_columns``) and
+    skips its padding tiles.  A real query row needs the
     candidates its owner's descriptors and tails name (tombstones
     inside a frozen segment included: the host does not look inside
     segments)."""
@@ -324,7 +351,7 @@ def scan_pairs(qseg: np.ndarray, desc_lens: np.ndarray,
     rows = (np.bincount(desc_owners, weights=desc_lens, minlength=m)[:m]
             + np.bincount(tail_res_owners, minlength=m)[:m]
             + np.bincount(tail_ship_owners, minlength=m)[:m])
-    return qp * (n_desc + tr + ts), int(rows[qseg].sum())
+    return qp * (inplace_cols or n_desc + tr + ts), int(rows[qseg].sum())
 
 
 def pad_descriptor_batch(x, qseg, desc_starts, desc_lens, desc_owners,
@@ -588,7 +615,8 @@ def merge_topk_allgather(vals: jax.Array, gids: jax.Array, axis: str,
 
 
 __all__ = ["pairwise_sqdist", "topk", "topk_segmented",
-           "topk_segmented_desc", "topk_xla", "topk_segmented_xla",
+           "topk_segmented_desc", "inplace_columns", "topk_xla",
+           "topk_segmented_xla",
            "segmented_dense_topk", "topk_segmented_numpy", "topk_numpy",
            "merge_topk_device", "merge_topk_allgather", "bucket",
            "default_interpret", "default_impl", "select_tiles",

@@ -88,6 +88,23 @@ def test_descriptor_scan_pallas_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("d", [D, 100], ids=["sift", "glove"])
+def test_descriptor_scan_inplace_pallas_compiles(one_chip, d):
+    """The in-place form of a label wave (no tails, descriptors read
+    from the table where they lie), at a width on and off the lanes."""
+    from repro.kernels.distance_topk import distance_topk_descriptors
+    n_desc, dp = 2 ** 18, 8
+    bq, bn = select_tiles(QP, n_desc, d, k=KP, divisor_n=n_desc)
+    compiled = distance_topk_descriptors.lower(
+        _sds((N, d), jnp.float32, one_chip), _sds((N,), jnp.int32, one_chip),
+        _sds((N,), jnp.bool_, one_chip),
+        _sds((QP, d), jnp.float32, one_chip),
+        _sds((QP + 3 * dp,), jnp.int32, one_chip), k=K, n_desc=n_desc,
+        packed=(QP, n_desc, 0, 0, dp, d), block_q=min(bq, QP), block_n=bn,
+        interpret=False, impl="pallas", inplace=True).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_sq8_descriptor_scan_pallas_compiles(one_chip):
     from repro.kernels.quant import _sq8_topk_descriptors
     vecs, base_ids, deleted = _resident(one_chip)
