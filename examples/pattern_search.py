@@ -1,49 +1,37 @@
-"""Serve a small model + VectorMaton with batched pattern-constrained
-requests — the end-to-end serving driver (deliverable b).
+"""Serve VectorMaton with batched pattern-constrained requests — the
+end-to-end serving driver.
 
-Embeds a corpus with a (smoke-sized) qwen3 LM, indexes the embeddings with
-their sequences, serves a batch of mixed-pattern requests (plain CONTAINS
-plus boolean AND/OR/NOT and LIKE predicates), reports QPS and recall, then
-checkpoints and restores the engine.
+Vectors are given, as in the paper: each record of a corpus gets a seeded
+Gaussian vector beside its sequence.  The example indexes the records,
+serves a batch of mixed-pattern requests (plain CONTAINS plus boolean
+AND/OR/NOT and LIKE predicates), reports QPS and recall, serves hybrid
+attribute predicates, then checkpoints and restores the engine and
+serves again.
 
     PYTHONPATH=src python examples/pattern_search.py
 """
 
+import os
+import tempfile
 import time
 
-import jax
 import numpy as np
 
-from repro.configs import smoke_config
 from repro.core.baselines import ground_truth, recall
 from repro.core.predicate import parse_predicate, quote_literal
 from repro.core.vectormaton import VectorMatonConfig
 from repro.data.corpora import make_corpus, sample_patterns
-from repro.models.transformer import LM
-from repro.serve.engine import Request, RetrievalEngine, embed_texts
+from repro.serve.engine import Request, RetrievalEngine
 
-# --- 1. the embedder: a reduced qwen3 config ----------------------------
-cfg = smoke_config("qwen3-4b")
-model = LM(cfg)
-params = model.init(jax.random.PRNGKey(0))
-
-# --- 2. a corpus of (sequence) records; embed them ----------------------
+# --- 1. a corpus of (sequence) records ----------------------------------
 _, sequences = make_corpus("mtg", scale=0.05)
 print(f"corpus: {len(sequences)} records, "
       f"total length {sum(len(s) for s in sequences)}")
 
-
-def tokenize(s: str, width: int = 32) -> np.ndarray:
-    raw = np.frombuffer(s[:width].ljust(width).encode(), dtype=np.uint8)
-    return (raw % cfg.vocab_size).astype(np.int32)
-
-
-batches = [np.stack([tokenize(s) for s in sequences[i:i + 16]])
-           for i in range(0, len(sequences), 16)]
-t0 = time.time()
-vectors = embed_texts(model, params, batches).astype(np.float32)
-print(f"embedded {len(vectors)} records in {time.time()-t0:.1f}s "
-      f"(dim={vectors.shape[1]})")
+# --- 2. the given vectors: seeded Gaussian, one per record --------------
+vectors = np.random.default_rng(0).standard_normal(
+    (len(sequences), 64)).astype(np.float32)
+print(f"{len(vectors)} vectors (dim={vectors.shape[1]})")
 
 # --- 3. index + serve batched requests ----------------------------------
 engine = RetrievalEngine(vectors, sequences,
@@ -142,8 +130,10 @@ print(f"{len(hyb_reqs)} hybrid attribute+pattern requests in {dt:.2f}s "
       f"predicates")
 
 # --- 6. fault tolerance: checkpoint, restore, keep serving --------------
-engine.checkpoint("/tmp/vectormaton_engine")
-restored = RetrievalEngine.restore("/tmp/vectormaton_engine")
+with tempfile.TemporaryDirectory() as tmp:
+    path = os.path.join(tmp, "vectormaton_engine")
+    engine.checkpoint(path)
+    restored = RetrievalEngine.restore(path)
 r1 = engine.serve(requests[0])
 r2 = restored.serve(requests[0])
 assert np.array_equal(r1.ids, r2.ids)

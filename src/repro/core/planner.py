@@ -21,8 +21,8 @@ module is the piece that closes the loop:
   * ``CostModel`` — per-strategy cost curves ``setup + unit_cost ×
     units`` (launch setup amortization + bytes moved + expected verify
     work), where ``unit_cost`` is an EWMA per (strategy × log2 size
-    bucket) *seeded from calibration defaults* (the BENCH_PR10
-    selectivity sweep) so cold plans are sane.  Executors report
+    bucket) *seeded from calibration defaults* (from a CPU selectivity
+    sweep) so cold plans are sane.  Executors report
     observed (strategy, units, ms) triples; the pending observations
     fold into the EWMA only at wave heads (``absorb``), so a
     generation-stamped plan is immutable once compiled.
@@ -257,12 +257,12 @@ class CostModel:
     (ef slots × graphs) for filtered_graph — maintained as an EWMA per
     (strategy × log2 size bucket).
 
-    Seeds are calibration defaults measured by the BENCH_PR10
-    selectivity sweep on the CI host (single-core CPU jax), so a cold
-    planner scores sanely; measured EWMAs take over per bucket once
-    ``MIN_OBS`` waves folded in.  Observations are buffered thread-safely
-    and folded only by ``absorb()`` — the wave-head cadence that keeps
-    dispatched plans immutable (DESIGN.md §11).
+    Seeds are calibration defaults from a selectivity sweep on a
+    single-core CPU (jax backend), so a cold planner scores sanely;
+    measured EWMAs take over per bucket once ``MIN_OBS`` waves folded
+    in.  Observations are buffered thread-safely and folded only by
+    ``absorb()`` — the wave-head cadence that keeps dispatched plans
+    immutable (DESIGN.md §11).
     """
 
     ALPHA = 0.25              # EWMA smoothing per fold
@@ -271,9 +271,10 @@ class CostModel:
     NEAR_BUCKETS = 2          # nearest-bucket fallback radius
 
     # calibration defaults: ms per work unit / ms per source launch
-    # (BENCH_PR10 sweep, CPU jax; relative order is what matters cold —
-    # a beam slot costs ~an order more than a scanned row, and a graph
-    # source pays mask-upload + an extra launch class of setup)
+    # (a CPU selectivity sweep, jax backend; relative order is what
+    # matters cold — a beam slot costs ~an order more than a scanned
+    # row, and a graph source pays mask-upload + an extra launch class
+    # of setup)
     DEFAULT_UNIT = {"scan": 2.0e-4, "filtered_graph": 2.0e-3,
                     "residual": 2.0e-4, "verify": 2.0e-3}
     DEFAULT_SETUP = {"scan": 0.05, "filtered_graph": 0.40,
@@ -336,13 +337,6 @@ class CostModel:
         return (self.DEFAULT_SETUP.get(strategy, 0.1) + unit * units,
                 measured)
 
-    def snapshot(self) -> Dict[str, Dict[str, float]]:
-        """Measured state for calibration dumps (BENCH_PR10.json)."""
-        with self._lock:
-            return {f"{s}/b{b}": {"unit_ms": self._ewma[(s, b)],
-                                  "obs": self._obs[(s, b)]}
-                    for (s, b) in sorted(self._ewma)}
-
 
 class AdaptivePlanner:
     """The per-index planner: estimator + cost model + measured winners.
@@ -369,7 +363,7 @@ class AdaptivePlanner:
         # pred-cache entry mirrors this so a re-compiled predicate
         # replays its measured winner at the same delta version
         self._winners: Dict[Tuple[str, int], str] = {}
-        self.force_strategy: Optional[str] = None   # tests/benchmarks
+        self.force_strategy: Optional[str] = None   # tests
         self.counters: Dict[str, int] = {
             "scored": 0,            # conjunction sources cost-scored
             "estimates": 0,         # estimator intervals produced
@@ -411,7 +405,7 @@ class AdaptivePlanner:
     def record_estimate(self, iv: Interval, actual: int) -> None:
         """Compare an interval's point estimate against the exact count
         the compiler went on to materialize (estimates-vs-observed
-        counters; the BENCH_PR10 gate reads the within-2× ratio)."""
+        counters, among them the within-2× ratio)."""
         with self._lock:
             self.counters["estimates"] += 1
             self.counters["est_checked"] += 1

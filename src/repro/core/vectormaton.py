@@ -106,10 +106,6 @@ class _StateIndex:
     raw_ids: Optional[np.ndarray] = None
     graph: Optional[HNSW] = None
 
-    @property
-    def n_indexed(self) -> int:
-        return (len(self.raw_ids) if self.kind == _RAW else len(self.graph))
-
 
 @contextlib.contextmanager
 def _collector_paused():

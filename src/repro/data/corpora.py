@@ -102,7 +102,7 @@ def make_corpus(name: str, seed: int = 0, scale: float = 1.0
 
 
 # --------------------------------------------------------------------- #
-# real-scale streamed corpus (BENCH_PR6, DESIGN.md §6)
+# real-scale streamed corpus (DESIGN.md §6)
 #
 # The paper-shape corpora above top out at a few thousand records; the
 # scalability frontier needs 10^5–10^6 vectors at 128–768 dims without

@@ -12,13 +12,13 @@ Two checkpoint families share one on-disk format:
     therefore lands on a fresh generation — a free compaction point —
     with delta/compaction counters carried across via ``delta_meta`` so
     generation numbering keeps advancing monotonically.
-  * **Train-state checkpoints** (`CheckpointManager`): pytree of arrays
+  * **Pytree checkpoints** (`CheckpointManager`): pytree of arrays
     saved as per-host shard files + a JSON manifest; atomic rename commit;
-    optional async (background-thread) save so the train loop never blocks
+    optional async (background-thread) save so the caller never blocks
     on disk; resume-from-latest; reshard-on-load (any mesh -> any mesh,
     because shards store the *global* array and the loader re-shards with
-    the target sharding — adequate at dry-run scale; a production variant
-    writes per-device shards, same manifest schema).
+    the target sharding; a production variant writes per-device shards,
+    same manifest schema).  Nothing on the served path calls it.
 
 Atomicity: everything is written into `<dir>.tmp` then `os.replace`d, so a
 crash mid-save never corrupts the latest good checkpoint.
@@ -255,7 +255,7 @@ class CheckpointManager:
     def save(self, step: int, tree: Any, blocking: bool = True) -> None:
         flat = _flatten(tree)
         # Pull device arrays to host *before* handing off to the async
-        # thread so the train loop can donate/overwrite its buffers.
+        # thread so the caller can donate/overwrite its buffers.
         host_flat = {k: np.asarray(v) for k, v in flat.items()}
         if blocking:
             self._write(step, host_flat)

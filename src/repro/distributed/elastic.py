@@ -15,9 +15,8 @@ a device-health table, and is exercised by tests/test_fault_tolerance.py:
     (data, model) mesh that preserves the TP degree, and drives
     CheckpointManager.restore(..., sharding_tree=new) — reshard-on-load.
 
-The train loop (launch/train.py) wires these around every step, and the
-replicated serving router (serve/router.py, DESIGN.md §10) wires them
-around every wave; the checkpoint manager provides the recovery
+The replicated serving router (serve/router.py, DESIGN.md §10) wires
+them around every wave; index checkpoints provide the recovery
 substrate.  Both monitors take an injectable ``clock`` so decision logic
 never reads the wall clock directly — the serving failover tests drive
 them with a fake clock and replay identical fault schedules.

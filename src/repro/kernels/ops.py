@@ -117,10 +117,6 @@ def jit_cache_sizes() -> Dict[str, int]:
     return out
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def _pad_to(x: jax.Array, rows: int) -> jax.Array:
     if x.shape[0] == rows:
         return x
@@ -392,7 +388,7 @@ def pad_descriptor_batch(x, qseg, desc_starts, desc_lens, desc_owners,
 # natively only on TPU; everywhere else these jnp twins are what
 # "compiled kernels" means — one XLA executable per shape bucket, MXU/
 # AVX matmul + lax.top_k, the same output contract as the Pallas
-# wrappers.  BENCH_PR6.json's frontier runs on these (DESIGN.md §6).
+# wrappers (DESIGN.md §6).
 # --------------------------------------------------------------------- #
 
 @functools.partial(jax.jit, static_argnames=("k", "metric"))

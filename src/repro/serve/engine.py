@@ -1,13 +1,12 @@
 """Pattern-constrained retrieval serving engine.
 
-The end-to-end composition the paper targets: an encoder LM produces
-(vector, sequence) records; VectorMaton indexes them; queries arrive as
-(text/vector, pattern, k) triples and are answered under a latency budget.
+The end-to-end composition the paper targets: (vector, sequence) records
+are given; VectorMaton indexes them; queries arrive as (vector, pattern,
+k) triples and are answered under a latency budget.
 
 Request flow (DESIGN.md §3):
-  embed (batched, jit'd mean-pool over LM hidden states)
-    -> planner: predicate compile + automaton walks per request (µs-scale
-       host work), identical predicates coalesced into one plan entry
+  planner: predicate compile + automaton walks per request (µs-scale
+    host work), identical predicates coalesced into one plan entry
     -> device-resident executor: ONE descriptor-driven segmented
        distance+top-k launch for all brute-forced candidate sets (frozen
        covers resolve against the resident CSR — the host ships planning
@@ -15,7 +14,7 @@ Request flow (DESIGN.md §3):
        size bucket + a device-side merge; residual verification loops for
        multi-segment LIKE stay on host.  ``maintenance_stats`` exposes
        the launch/retrace counters and per-class host→device traffic the
-       serving tier watches (bench_device_exec gates on them).
+       serving tier watches.
 
 Requests accept predicate strings — ``"ab AND NOT (cd OR LIKE 'a%b_')"``
 — as well as plain CONTAINS patterns (parsed in core/predicate.py).
@@ -31,7 +30,7 @@ from __future__ import annotations
 
 import threading
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -278,17 +277,3 @@ class RetrievalEngine:
         self.pipeline_stats = {}
         return self
 
-
-def embed_texts(model, params, token_batches, dim: Optional[int] = None
-                ) -> np.ndarray:
-    """Mean-pooled LM hidden states as embeddings (batched, jit-cached)."""
-    import jax
-    import jax.numpy as jnp
-
-    @jax.jit
-    def _embed(p, toks):
-        hidden, _, _ = model.forward(p, toks)
-        return jnp.mean(hidden.astype(jnp.float32), axis=1)
-
-    outs = [np.asarray(_embed(params, t)) for t in token_batches]
-    return np.concatenate(outs, axis=0)

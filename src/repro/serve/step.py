@@ -1,10 +1,5 @@
-"""Serve-step builders: prefill / single-token decode, and the
-double-buffered host staging ring for the pipelined retrieval executor.
-
-``serve_decode`` is what the decode_32k / long_500k dry-run cells lower:
-one new token for every sequence against a seq_len-deep cache.  Greedy
-sampling keeps the artifact deterministic; the engine swaps in nucleus
-sampling at the host level when needed.
+"""The double-buffered host staging ring for the pipelined retrieval
+executor.
 
 ``StagingRing`` (DESIGN.md §7): the planner thread assembles wave N+1's
 query matrix into one of two preallocated host buffers while wave N's
@@ -20,10 +15,8 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Callable, Optional
+from typing import Optional
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 
@@ -113,27 +106,3 @@ class StagingRing:
             self._free.append(idx)
             self._cv.notify()
 
-
-def make_prefill(model, max_len: int) -> Callable:
-    """Positional signature (params, tokens[, patch_embeds]) — jit
-    in_shardings only bind positional args."""
-    def prefill(params, tokens, patch_embeds=None):
-        cache, logits = model.prefill(params, tokens, max_len,
-                                      patch_embeds=patch_embeds)
-        return cache, jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return prefill
-
-
-def make_prefill_encdec(model, max_dec: int) -> Callable:
-    def prefill(params, frames, tokens):
-        cache, logits = model.prefill(params, frames, tokens, max_dec)
-        return cache, jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return prefill
-
-
-def make_decode(model) -> Callable:
-    def decode(params, cache, token, pos):
-        logits, cache = model.decode_step(params, cache, token, pos)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
-        return nxt, cache
-    return decode

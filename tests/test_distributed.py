@@ -1,18 +1,13 @@
-"""Multi-device behaviour: sharded search, compressed psum, sharding rules.
+"""Multi-device behaviour: the sharded search executor and engine.
 
 These spawn subprocesses with XLA_FLAGS=--xla_force_host_platform_device_count=8
-so the main pytest session keeps the default single CPU device (the same
-isolation rule the dry-run uses for its 512 placeholders).
+so the main pytest session keeps the default single CPU device.
 """
 
 import os
 import subprocess
 import sys
 import textwrap
-
-import jax
-import numpy as np
-import pytest
 
 REPO_SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
@@ -289,93 +284,3 @@ def test_sharded_engine_matches_single_chip():
         print("sharded engine ok")
     """)
 
-
-def test_compressed_psum_error_bound():
-    _run_in_child("""
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import PartitionSpec as P
-        from repro.launch.mesh import make_host_mesh
-        from repro.distributed.collectives import compressed_psum
-        mesh = make_host_mesh(data=8, model=1)
-        rng = np.random.default_rng(2)
-        x = rng.standard_normal((8, 1024)).astype(np.float32)
-        fn = shard_map(lambda v: compressed_psum(v[0], "data"),
-                       mesh=mesh, in_specs=P("data", None),
-                       out_specs=P(), check_rep=False)
-        with mesh:
-            got = np.asarray(fn(jnp.asarray(x)))
-        want = x.sum(0)
-        scale = np.abs(x).max() / 127.0
-        assert np.max(np.abs(got - want)) <= 8 * scale + 1e-5
-        print("compressed_psum ok")
-    """)
-
-
-def test_sharding_rules_cover_all_archs():
-    """Every param leaf of every arch gets a spec whose sharded dims divide
-    the mesh axes (8-device 2x4 mesh)."""
-    _run_in_child("""
-        from repro.configs import arch_names, get_config
-        from repro.distributed.sharding import ShardingRules
-        from repro.models.transformer import LM
-        from repro.models.encdec import EncDec
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
-        for name in arch_names():
-            cfg = get_config(name)
-            model = EncDec(cfg) if cfg.is_encoder_decoder else LM(cfg)
-            shapes = jax.eval_shape(
-                lambda: model.init(jax.random.PRNGKey(0)))
-            specs = ShardingRules(cfg, mesh).param_specs(shapes)
-            def check(leaf, spec):
-                for dim, ax in zip(leaf.shape, tuple(spec)):
-                    if ax is None: continue
-                    sz = (mesh.shape[ax] if isinstance(ax, str) else
-                          int(np.prod([mesh.shape[a] for a in ax])))
-                    assert dim % sz == 0, (name, leaf.shape, spec)
-            jax.tree.map(check, shapes, specs,
-                         is_leaf=lambda x: hasattr(x, "shape"))
-        print("sharding rules ok")
-    """)
-
-
-def test_train_step_multidevice_matches_single():
-    """DP training on 8 devices reproduces the single-device trajectory."""
-    _run_in_child("""
-        from repro.configs import smoke_config
-        from repro.models.transformer import LM
-        from repro.train import optimizer as opt
-        from repro.train.step import make_train_step
-        from repro.data.pipeline import TokenPipeline
-        from repro.distributed.sharding import ShardingRules
-        from repro.launch.mesh import make_host_mesh
-
-        cfg = smoke_config("h2o-danube-1.8b")
-        model = LM(cfg)
-        params = model.init(jax.random.PRNGKey(0))
-        pipe = TokenPipeline(cfg, 8, 16)
-        step = jax.jit(make_train_step(model, opt.OptConfig(lr=1e-3)))
-
-        # single-device reference (devices exist but everything unsharded)
-        p1, o1 = params, opt.init(params)
-        for i in range(3):
-            p1, o1, m1 = step(p1, o1, pipe.batch_at(i))
-
-        mesh = make_host_mesh(data=8, model=1)
-        rules = ShardingRules(cfg, mesh)
-        pshard = rules.param_shardings(jax.eval_shape(lambda: params))
-        p2 = jax.tree.map(jax.device_put, params, pshard)
-        o2 = opt.init(p2)
-        with mesh:
-            jstep = jax.jit(make_train_step(model, opt.OptConfig(lr=1e-3)))
-            for i in range(3):
-                b = pipe.batch_at(i)
-                b = jax.tree.map(
-                    lambda x: jax.device_put(x, jax.sharding.NamedSharding(
-                        mesh, jax.sharding.PartitionSpec("data"))), b)
-                p2, o2, m2 = jstep(p2, o2, b)
-        for a, b_ in zip(jax.tree.leaves(p1), jax.tree.leaves(p2)):
-            np.testing.assert_allclose(np.asarray(a, np.float32),
-                                       np.asarray(b_, np.float32),
-                                       atol=5e-3, rtol=5e-3)
-        print("multidevice train ok")
-    """)

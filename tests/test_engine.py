@@ -1,9 +1,10 @@
-"""Retrieval engine integration: embed -> index -> serve -> maintain."""
+"""Retrieval engine integration: index -> serve -> maintain."""
 
 import numpy as np
 import pytest
 
 from repro.core.baselines import ground_truth, recall
+from repro.core.predicate import parse_predicate
 from repro.core.vectormaton import VectorMatonConfig
 from repro.data.corpora import SPECS, make_corpus, sample_patterns
 from repro.serve.engine import Request, RetrievalEngine
@@ -102,16 +103,33 @@ def test_engine_insert_then_query(engine):
     assert nid not in r.ids.tolist()
 
 
-def test_embed_texts_deterministic():
-    import jax
-    from repro.configs import smoke_config
-    from repro.models.transformer import LM
-    from repro.serve.engine import embed_texts
-    cfg = smoke_config("qwen3-4b")
-    model = LM(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    toks = np.arange(32, dtype=np.int32).reshape(2, 16) % cfg.vocab_size
-    e1 = embed_texts(model, params, [toks])
-    e2 = embed_texts(model, params, [toks])
-    assert e1.shape == (2, cfg.d_model)
-    np.testing.assert_array_equal(e1, e2)
+
+SMOKE_FORMS = ["ab", "cd", "a", "ab AND cd", "ab OR cd", "NOT ab",
+               "LIKE '%a%b%'"]
+
+
+@pytest.fixture(scope="module")
+def smoke_wave():
+    """One batch of plain patterns (``ab`` four times, so the plan
+    coalesces) and boolean predicates over a graph-backed index."""
+    rng = np.random.default_rng(0)
+    seqs = ["".join(rng.choice(list("abcd"), size=rng.integers(5, 14)))
+            for _ in range(120)]
+    vecs = rng.standard_normal((120, 16)).astype(np.float32)
+    eng = RetrievalEngine(vecs, seqs, VectorMatonConfig(T=20, M=8,
+                                                        ef_con=40))
+    pats = ["ab", "ab", "ab"] + SMOKE_FORMS
+    reqs = [Request(vector=rng.standard_normal(16).astype(np.float32),
+                    pattern=p, k=5) for p in pats]
+    return eng, seqs, reqs, eng.serve_batch(reqs)
+
+
+@pytest.mark.parametrize("form", SMOKE_FORMS)
+def test_batched_engine_smoke(smoke_wave, form):
+    eng, seqs, reqs, resps = smoke_wave
+    pred = parse_predicate(form)
+    cases = [(q, r) for q, r in zip(reqs, resps) if q.pattern == form]
+    assert cases
+    for req, resp in cases:
+        assert np.array_equal(eng.serve(req).ids, resp.ids)
+        assert all(pred.matches(seqs[i]) for i in resp.ids.tolist())

@@ -85,6 +85,29 @@ def test_pipeline_read_parity(backend):
     assert outs[False] == outs[True]
 
 
+def test_pipelining_keeps_launches_per_wave():
+    """Pipelining reorders waves, never what they launch: the same
+    stream of waves makes as many device launches of each kind through
+    the pipelined executor as through the synchronous loop."""
+    from repro.kernels import ops
+    rng = np.random.default_rng(4)
+    reqs = _requests(rng, 48)
+    launches = {}
+    for mode in (False, True):
+        eng = _engine("jax", plan_mode="static")
+        b = ContinuousBatcher(eng, budget=10 ** 9, max_wave=8,
+                              pipeline=mode)
+        for r in reqs:
+            b.submit(r)
+        ops.reset_launch_stats()
+        b.drain()
+        launches[mode] = {kind: n for kind, n in ops.launch_stats().items()
+                          if kind not in ("retraces", "executables")}
+        b.close()
+    assert launches[False]["launches"] >= 6
+    assert launches[False] == launches[True]
+
+
 @pytest.mark.parametrize("backend", ["numpy", "jax"])
 def test_pipeline_churn_parity(backend):
     """Inserts + deletes + compactions streamed through the pipelined
